@@ -30,12 +30,11 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 SCRIPT = TIMER_SNIPPET + r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
 DRY = %(dry)s
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,) * 1)
 rng = np.random.RandomState(0)
 N_LEAVES, LEAF = (6, 4096) if DRY else (24, 65536)
 params = {f"g{i}": jnp.asarray(rng.randn(LEAF + 128 * i).astype(np.float32))
@@ -72,10 +71,10 @@ for page_bytes in pages:
                 arena_buf=buf)
             return loss, tree, out
 
-        fb = jax.jit(compat.shard_map(
+        fb = jax.jit(jax.shard_map(
             bucket_run, mesh=mesh, in_specs=(P(), P("data")),
             out_specs=(P(), P()), check_vma=False))
-        fa = jax.jit(compat.shard_map(
+        fa = jax.jit(jax.shard_map(
             arena_run, mesh=mesh, in_specs=(P(), P("data"), P(("data",))),
             out_specs=(P(), P(), P(("data",))), check_vma=False),
             donate_argnums=(2,))
@@ -140,8 +139,8 @@ for name, wire_kw in CODECS:
         donate, flat = (2,), P(("data",))
         in_specs = (P(), P("data"), flat)
         out_specs = (P(), P(), flat)
-    fa = jax.jit(compat.shard_map(arena_run, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False),
+    fa = jax.jit(jax.shard_map(arena_run, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False),
                  donate_argnums=donate)
     bufs = [jnp.zeros((8 * lay.total_elems,), jnp.dtype(lay.dtype))]
     if quant:

@@ -19,13 +19,13 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 _BODY = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp, predicted_reduction_collectives, solve
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2))
 op = StencilOp(specs=SPECS, mass=0.5)
 
@@ -35,10 +35,10 @@ def solver_fn(comm, solver, precond, schedule, channels, tol, maxiter):
                   tol=tol, maxiter=maxiter, schedule=schedule,
                   chunks=comm.halo_chunks, channels=channels)
         return r.x, r.iters, r.rel_residual
-    return jax.jit(compat.shard_map(run, mesh=mesh,
-                                    in_specs=P("x", "y", "z", None),
-                                    out_specs=(P("x", "y", "z", None), P(), P()),
-                                    check_vma=False))
+    return jax.jit(jax.shard_map(run, mesh=mesh,
+                                 in_specs=P("x", "y", "z", None),
+                                 out_specs=(P("x", "y", "z", None), P(), P()),
+                                 check_vma=False))
 
 print("solver,precond,schedule,channels,local_vol,iters,reductions,"
       "rel_residual,us_per_solve,us_per_iter")

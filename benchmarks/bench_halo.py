@@ -8,13 +8,13 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 SCRIPT = TIMER_SNIPPET + r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec, halo_bytes
 
 # 3-D Cartesian communicator on 8 ranks (2x2x2), like the paper's 2^4 grid
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = [HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2)]
 comm = Communicator(mesh, CommConfig(data_axes=("x", "y", "z"), channels=4))
 
@@ -29,8 +29,8 @@ for L in [8, 16, 24]:
             h = comm.halo_exchange(xl, SPECS, schedule=s)
             # consume all faces so nothing is dead-code eliminated
             return sum(v.sum() for v in h.values())
-        g = jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=spec_in,
-                                     out_specs=P(), check_vma=False))
+        g = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=spec_in,
+                                  out_specs=P(), check_vma=False))
         sec = time_call(g, x)
         print(f"{sched},{L}^3,{nbytes},{sec*1e6:.1f},{nbytes/sec/1e6:.1f}")
 """

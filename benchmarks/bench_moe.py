@@ -30,8 +30,7 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 
 SCRIPT = TIMER_SNIPPET + r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
 from repro.runtime.train_step import TrainStepConfig, build_moe_comm, make_ctx
@@ -57,8 +56,9 @@ print("transport,channels,model_parallel,us_per_call,dispatch_B,total_B,"
       "msgs,vs_replicated")
 rows = {}
 for transport, channels, r in grid:
-    mesh = compat.make_mesh((1, r), ("data", "model"),
-                            devices=jax.devices()[:r])
+    mesh = jax.make_mesh((1, r), ("data", "model"),
+                         devices=jax.devices()[:r],
+                         axis_types=(AxisType.Auto,) * 2)
     tcfg = TrainStepConfig(moe_transport=transport, moe_channels=channels)
     ctx = make_ctx(mesh, tcfg)
     comm = build_moe_comm(mesh, tcfg)
@@ -71,9 +71,9 @@ for transport, channels, r in grid:
                                       compute_dtype=jnp.float32)
         return jnp.sum(y * y) + aux
 
-    step = jax.jit(compat.shard_map(jax.grad(loss), mesh=mesh,
-                                    in_specs=(pspecs, P()),
-                                    out_specs=pspecs, check_vma=False))
+    step = jax.jit(jax.shard_map(jax.grad(loss), mesh=mesh,
+                                 in_specs=(pspecs, P()),
+                                 out_specs=pspecs, check_vma=False))
     t = time_call(step, params, x, warmup=2, iters=5)
     ratio = plan.dispatch_bytes_per_device / rep.dispatch_bytes_per_device
     rows[(transport, channels, r)] = t

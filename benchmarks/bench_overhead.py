@@ -12,13 +12,12 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 SCRIPT = TIMER_SNIPPET + r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core import ring
 from repro.core.ring import RingConfig
 
-mesh = compat.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 rng = np.random.RandomState(0)
 
 def workload(total, k=32):
@@ -37,7 +36,7 @@ for total in [1<<14, 1<<20]:
     pad = cfg.flat_divisor([4, 2])
     L = (total + pad - 1) // pad * pad
     flat = jnp.zeros((L,), jnp.float32)
-    comm_only = jax.jit(compat.shard_map(
+    comm_only = jax.jit(jax.shard_map(
         lambda x: ring.hierarchical_all_reduce(x, ("data", "pod"), cfg),
         mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False))
     t_comm = time_call(comm_only, flat)

@@ -25,11 +25,10 @@ from benchmarks.common import TIMER_SNIPPET, run_on_devices
 SCRIPT = TIMER_SNIPPET + r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,) * 1)
 MICRO = 4
 D, H, LAYERS = 256, 1024, 4       # enough matmul work to overlap against
 
@@ -60,7 +59,7 @@ for channels in (1, 2, 4):
         def inner(p, b):
             return comm.reduce_scheduled(grad_fn, p, b, sched,
                                          op="all_reduce")
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             inner, mesh=mesh, in_specs=(P(), P("data")),
             out_specs=(P(), P()), check_vma=False))
         sec = time_call(fn, params, batch)

@@ -35,7 +35,7 @@ SCRIPT = TIMER_SNIPPET + r"""
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-from repro import compat
+from jax.sharding import AxisType
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.serve.engine import (PagedDecodeEngine,
@@ -51,8 +51,9 @@ model = build_model(cfg)
 params = model.init(jax.random.key(0))
 
 def make_engine(slots, page_tokens, r, max_seq_len):
-    mesh = compat.make_mesh((1, r), ("data", "model"),
-                            devices=jax.devices()[:r])
+    mesh = jax.make_mesh((1, r), ("data", "model"),
+                         devices=jax.devices()[:r],
+                         axis_types=(AxisType.Auto,) * 2)
     plan = plan_kv_arena(cfg, mesh, page_tokens=page_tokens,
                          page_bytes=4096, max_seqs=slots,
                          max_seq_len=max_seq_len)

@@ -20,6 +20,9 @@ SRC = os.path.join(REPO, "src")
 
 def run_on_devices(script: str, n_devices: int = 8, timeout: int = 1200) -> str:
     env = dict(os.environ)
+    # host devices only (CPU rows): on a machine with an accelerator the
+    # child must never take it from the parent
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", script], env=env,

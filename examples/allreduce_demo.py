@@ -10,9 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
 
@@ -28,7 +26,7 @@ def main() -> None:
               "so this measures pure bucketing overhead.  Run with\n"
               "  XLA_FLAGS=--xla_force_host_platform_device_count=8\n"
               "to see the paper's before/after (as benchmarks/run.py does).")
-    mesh = compat.make_mesh((n,), ("data",))
+    mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,) * 1)
     rng = np.random.RandomState(0)
     k = args.tensors
     sizes = np.full(k, args.elements // k)

@@ -20,9 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
-
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import (PRECONDS, SOLVERS, StencilOp,
@@ -31,7 +29,7 @@ from repro.stencil import (PRECONDS, SOLVERS, StencilOp,
 
 def main() -> None:
     n = len(jax.devices())
-    mesh = compat.make_mesh((n,), ("x",))
+    mesh = jax.make_mesh((n,), ("x",), axis_types=(AxisType.Auto,) * 1)
     L, C = 24, 12                        # local extent, spinor-ish components
     specs = (HaloSpec("x", 0),)
     op = StencilOp(specs=specs, mass=0.2)
@@ -55,7 +53,7 @@ def main() -> None:
                           maxiter=300, schedule="overlap", chunks=2,
                           channels=2)
                 return r.x, r.iters, r.rel_residual
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 run, mesh=mesh, in_specs=P("x", None, None),
                 out_specs=(P("x", None, None), P(), P()), check_vma=False))
             x, iters, rel = jax.block_until_ready(fn(b))

@@ -36,7 +36,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.comm.plan import (A2APlan, ChannelAssignment, CommPlan,
                              HaloChannel, HaloPlan, assign_channels)
 from repro.comm.registry import Transport, get_transport
@@ -987,8 +986,8 @@ class Communicator:
             return (red, new_res) if has_ef else (red,)
 
         args = (grads, ef_state) if has_ef else (grads,)
-        out = compat.shard_map(inner, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)(*args)
+        out = jax.shard_map(inner, mesh=self.mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)(*args)
         return (out[0], out[1]) if has_ef else (out[0], ef_state)
 
     def init_ef_state(self, grads_like, specs):
@@ -1003,8 +1002,8 @@ class Communicator:
             buckets, _ = self.bucketer.bucketize(g)
             return [jnp.zeros_like(b) for b in buckets]
 
-        fn = compat.shard_map(inner, mesh=self.mesh, in_specs=(specs,),
-                              out_specs=ef_spec, check_vma=False)
+        fn = jax.shard_map(inner, mesh=self.mesh, in_specs=(specs,),
+                           out_specs=ef_spec, check_vma=False)
         return jax.jit(fn)(grads_like) if not _is_abstract(grads_like) \
             else jax.eval_shape(fn, grads_like)
 

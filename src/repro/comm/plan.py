@@ -27,15 +27,48 @@ from repro.core.bucketing import BucketPlan
 # products, tiny gradient buckets) without perturbing bulk-transfer cells.
 ALPHA_S = 1.5e-6
 
+
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks the roofline prices a program against."""
+
+    bf16_flops: float        # dense bf16 matmul FLOP/s
+    hbm_bandwidth: float     # HBM bytes/s
+    link_bandwidth: float    # ICI bytes/s per link, one direction
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e (device_kind "TPU v5
+# lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# of HBM at 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over 4 links
+# (50 GB/s per link).  A device that is not listed has no prices.
+DEVICE_PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(bf16_flops=197e12, hbm_bandwidth=819e9,
+                               link_bandwidth=1600e9 / 8 / 4),
+}
+
+# The production target the dry-run's described meshes are priced for.
+V5E = DEVICE_PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; raises for a device the table lacks."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
+
+
 # Per-link one-direction bandwidth, bytes/s.  Single source for both β
 # terms: :class:`LatencyModel` here and ``repro.launch.roofline.ICI_BW``.
-LINK_BANDWIDTH = 50e9
+LINK_BANDWIDTH = V5E.link_bandwidth
 
 # Per-chip HBM bandwidth, bytes/s (v5e).  Single source for the roofline
 # memory term and the codec kernel-time pricing in
 # :meth:`CommPlan.codec_tradeoff` — the fused pack+quantize/dequant passes
 # are pure streaming kernels, so their cost is HBM bytes over this number.
-HBM_BANDWIDTH = 819e9
+HBM_BANDWIDTH = V5E.hbm_bandwidth
 
 
 @dataclass(frozen=True)

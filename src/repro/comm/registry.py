@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core import ring as ring_lib
 from repro.core.ring import RingConfig
 
@@ -273,7 +272,7 @@ class PsumTransport(Transport):
         ``2(p-1)`` replicated tax this PR's ring/native paths eliminate);
         kept as the honest fallback so the A/B cost is measurable.
         """
-        p = compat.axis_size(axis)
+        p = lax.axis_size(axis)
         if p == 1:
             return x
         n = x.shape[split_axis]
@@ -329,6 +328,6 @@ class NativeA2ATransport(Transport):
 
     def all_to_all(self, x: jax.Array, axis: str, *, split_axis: int,
                    concat_axis: int) -> jax.Array:
-        if compat.axis_size(axis) == 1:
+        if lax.axis_size(axis) == 1:
             return x
         return lax.all_to_all(x, axis, split_axis, concat_axis, tiled=True)

@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.topology import order_token, ring_perm
 
 SCHEDULES = ("sequential", "concurrent", "chunked", "overlap")
@@ -117,7 +116,7 @@ def halo_exchange(x: jax.Array, specs: Sequence[HaloSpec], *,
 
     sends = []  # (key, payloads, axis, direction)
     for s in specs:
-        p = compat.axis_size(s.axis)
+        p = lax.axis_size(s.axis)
         n_chunks = chunks if (schedule == "chunked" and p > 1) else 1
         hi = _face(x, s.dim, lo=False, width=s.halo)   # travels to +1; recv as lo-halo
         lo = _face(x, s.dim, lo=True, width=s.halo)    # travels to -1; recv as hi-halo
@@ -141,7 +140,7 @@ def halo_exchange(x: jax.Array, specs: Sequence[HaloSpec], *,
     dep = None
     rail_dep: dict[int, jax.Array] = {}
     for idx, (key, payloads, axis, direction) in enumerate(sends):
-        p = compat.axis_size(axis)
+        p = lax.axis_size(axis)
         perm = ring_perm(p, direction)
         if schedule == "sequential" and dep is not None:
             payloads = _seq_token(dep, payloads)

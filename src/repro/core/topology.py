@@ -16,7 +16,6 @@ from typing import Sequence
 import jax
 from jax import lax
 
-from repro import compat
 
 Axis = str
 
@@ -29,7 +28,7 @@ def ring_perm(size: int, direction: int = +1) -> list[tuple[int, int]]:
 
 
 def axis_size(axis: Axis) -> int:
-    return compat.axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def axis_index(axis: Axis):

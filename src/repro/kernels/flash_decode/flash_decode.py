@@ -45,7 +45,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, acc_o, m_o, l_o,
     v = v_ref[0, 0].astype(jnp.float32)              # (bk, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    ok = valid_ref[...] != 0                         # (1, bk)
+    ok = valid_ref[0] != 0                           # (1, bk)
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_s[...]                                # (1, 1)
@@ -85,7 +85,9 @@ def flash_decode_stats_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"L={sk} must tile by block_k={bk}")
     scale = 1.0 / (d ** 0.5)
     grid = (b, hq, sk // bk)
-    valid = valid.astype(jnp.int32)
+    # (B, 1, L): the mask block's trailing dims (1, bk) then tile for any
+    # batch size (a (1, bk) block of a (B, L) array does not, unless B == 1)
+    valid = valid.astype(jnp.int32)[:, None, :]
 
     kernel = functools.partial(_decode_kernel, scale=scale)
     return pl.pallas_call(
@@ -95,7 +97,7 @@ def flash_decode_stats_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, 1, d), lambda b_, h, j: (b_, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, g=group: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, j, g=group: (b_, h // g, j, 0)),
-            pl.BlockSpec((1, bk), lambda b_, h, j: (b_, j)),
+            pl.BlockSpec((1, 1, bk), lambda b_, h, j: (b_, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, d), lambda b_, h, j: (b_, h, 0, 0)),
@@ -112,8 +114,7 @@ def flash_decode_stats_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),    # running denom l
             pltpu.VMEM((1, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, valid)

@@ -226,8 +226,6 @@ def analyse(lowered, n_dev: int, model, shape_cfg,
     compile_s = time.time() - t0
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     txt = compiled.as_text()
     stats = collective_wire_bytes(txt)
 
@@ -354,15 +352,14 @@ def run_mem_cell(arch: str, page_bytes: int, bucket_mb: float, *,
       the roofline folds it via ``padding_wire_bytes_per_device``).
     """
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.comm import CommConfig
     from repro.configs import reduced_config
     from repro.runtime.train_step import _local_shapes, build_comm
 
-    mesh = compat.make_mesh((4, 1), ("data", "model"),
-                            devices=jax.devices()[:4])
+    mesh = jax.make_mesh((4, 1), ("data", "model"),
+                         devices=jax.devices()[:4],
+                         axis_types=(AxisType.Auto,) * 2)
     n_dev = 4
     model = build_model(reduced_config(arch))
     tcfg = TrainStepConfig(
@@ -400,10 +397,10 @@ def run_mem_cell(arch: str, page_bytes: int, bucket_mb: float, *,
         flat = P(tuple(mesh.axis_names))
         arena_abs = jax.ShapeDtypeStruct((n_dev * layout.total_elems,),
                                          jnp.float32)
-        fa = jax.jit(compat.shard_map(
+        fa = jax.jit(jax.shard_map(
             arena_fn, mesh=mesh, in_specs=(flat, pspecs, P()),
             out_specs=(flat, pspecs), check_vma=False), donate_argnums=(0,))
-        fb = jax.jit(compat.shard_map(
+        fb = jax.jit(jax.shard_map(
             bucket_fn, mesh=mesh, in_specs=(pspecs, P()),
             out_specs=pspecs, check_vma=False))
         t0 = time.time()
@@ -535,15 +532,14 @@ def run_mem_codec_cell(arch: str, page_bytes: int, bucket_mb: float, *,
     transpose executes (half an all-reduce) — over the *same* span layout,
     so the measured ratios must agree exactly across modes.
     """
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.comm import CommConfig
     from repro.configs import reduced_config
     from repro.runtime.train_step import _local_shapes, build_comm
 
-    mesh = compat.make_mesh((4, 1), ("data", "model"),
-                            devices=jax.devices()[:4])
+    mesh = jax.make_mesh((4, 1), ("data", "model"),
+                         devices=jax.devices()[:4],
+                         axis_types=(AxisType.Auto,) * 2)
     n_dev = 4
     model = build_model(reduced_config(arch))
     op = "all_reduce" if dp_mode == "replicated" else "reduce_scatter"
@@ -592,7 +588,7 @@ def run_mem_codec_cell(arch: str, page_bytes: int, bucket_mb: float, *,
         n_out = layout.n_spans if op != "all_reduce" else None
         out_specs = (flat, flat,
                      pspecs if op == "all_reduce" else [flat] * n_out)
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(flat, flat, pspecs, P()),
             out_specs=out_specs, check_vma=False), donate_argnums=(0, 1))
         arena_abs = jax.ShapeDtypeStruct((n_dev * layout.total_elems,),
@@ -800,7 +796,7 @@ def run_serve_cell(arch: str, page_tokens: int, model_parallel: int, *,
     latency term — decode is the α-bound regime, same as the paper's
     strong-scaled CG.
     """
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import reduced_config
     from repro.serve.engine import (build_paged_decode_step,
                                     predicted_collectives_per_token,
@@ -808,8 +804,9 @@ def run_serve_cell(arch: str, page_tokens: int, model_parallel: int, *,
     from repro.serve.kv import plan_kv_arena
 
     r = int(model_parallel)
-    mesh = compat.make_mesh((1, r), ("data", "model"),
-                            devices=jax.devices()[:r])
+    mesh = jax.make_mesh((1, r), ("data", "model"),
+                         devices=jax.devices()[:r],
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config(arch))
     plan = plan_kv_arena(model.cfg, mesh, page_tokens=page_tokens,
                          page_bytes=page_bytes, max_seqs=max_seqs,
@@ -855,8 +852,6 @@ def run_serve_cell(arch: str, page_tokens: int, model_parallel: int, *,
             f"HLO {measured}")
 
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     roof = Roofline(
         flops_per_device=float(ca.get("flops", 0.0)),
         hbm_bytes_per_device=float(ca.get("bytes accessed", 0.0)),
@@ -949,9 +944,7 @@ def run_moe_cell(arch: str, transport: str, channels: int,
       most ``1/R`` of the replicated-psum fallback's prediction for the
       same payload (the PR's headline acceptance bound).
     """
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.comm.registry import get_transport
     from repro.configs import reduced_config
     from repro.models.moe import capacity
@@ -964,8 +957,9 @@ def run_moe_cell(arch: str, transport: str, channels: int,
     rcfg = rcfg.with_(moe=replace(rcfg.moe, parallelism=parallelism))
     model = build_model(rcfg)
     cfg = model.cfg
-    mesh = compat.make_mesh((1, r), ("data", "model"),
-                            devices=jax.devices()[:r])
+    mesh = jax.make_mesh((1, r), ("data", "model"),
+                         devices=jax.devices()[:r],
+                         axis_types=(AxisType.Auto,) * 2)
     tcfg = TrainStepConfig(moe_transport=transport, moe_channels=channels)
     ctx = make_ctx(mesh, tcfg)
     comm = build_moe_comm(mesh, tcfg)
@@ -1001,8 +995,8 @@ def run_moe_cell(arch: str, transport: str, channels: int,
         def fwd(p, mb):
             return model.loss_fn(p, mb, ctx=ctx_)
 
-        sh = compat.shard_map(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
-                              out_specs=P(), check_vma=False)
+        sh = jax.shard_map(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
+                           out_specs=P(), check_vma=False)
         with mesh:
             return jax.jit(sh).lower(model.abstract_params(),
                                      batch_abs).compile()
@@ -1062,8 +1056,6 @@ def run_moe_cell(arch: str, transport: str, channels: int,
         err = 0.0
 
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     roof = Roofline(
         flops_per_device=float(ca.get("flops", 0.0)),
         hbm_bytes_per_device=float(ca.get("bytes accessed", 0.0)),
@@ -1166,17 +1158,16 @@ def run_stencil_cell(L: int, schedule: str, multi_pod: bool, *,
 
     Inner products ride ``psum`` all-reduces, so the two op kinds separate
     cleanly in the parse."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.comm import CommConfig, Communicator
     from repro.core.halo import HaloSpec
     from repro.stencil import (StencilOp, predicted_halo_exchanges,
                                predicted_reduction_collectives, solve)
 
     mesh_shape, n_dev = STENCIL_MESH["multi" if multi_pod else "single"]
-    mesh = compat.make_mesh(mesh_shape, ("x", "y", "z"),
-                            devices=jax.devices()[:n_dev])
+    mesh = jax.make_mesh(mesh_shape, ("x", "y", "z"),
+                         devices=jax.devices()[:n_dev],
+                         axis_types=(AxisType.Auto,) * len(mesh_shape))
     specs = (HaloSpec("x", 0, halo), HaloSpec("y", 1, halo),
              HaloSpec("z", 2, halo))
     local = (L, L, L, components)
@@ -1195,7 +1186,7 @@ def run_stencil_cell(L: int, schedule: str, multi_pod: bool, *,
         return r.x, r.rel_residual
 
     with mesh:
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=P("x", "y", "z", None),
             out_specs=(P("x", "y", "z", None), P()), check_vma=False))
         lowered = fn.lower(jax.ShapeDtypeStruct(gshape, jnp.float32))
@@ -1203,8 +1194,6 @@ def run_stencil_cell(L: int, schedule: str, multi_pod: bool, *,
     compiled = lowered.compile()
     compile_s = time.time() - t0
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     stats = collective_wire_bytes(compiled.as_text())
     n_exchanges = predicted_halo_exchanges(solver, precond, cg_iters,
                                            s=sstep_s)

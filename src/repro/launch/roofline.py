@@ -25,9 +25,9 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-from repro.comm.plan import ALPHA_S, HBM_BANDWIDTH, LINK_BANDWIDTH
+from repro.comm.plan import ALPHA_S, HBM_BANDWIDTH, LINK_BANDWIDTH, V5E
 
-PEAK_FLOPS = 197e12          # bf16 per chip
+PEAK_FLOPS = V5E.bf16_flops  # bf16 per chip
 HBM_BW = HBM_BANDWIDTH       # bytes/s per chip
 ICI_BW = LINK_BANDWIDTH      # bytes/s per link (one direction); single
                              # source in repro.comm.plan so the roofline and
@@ -157,6 +157,8 @@ class Roofline:
     link_bandwidth: float = ICI_BW  # β term; a tuning-DB record replaces
                                     # both constants with *measured* ones
                                     # (see Roofline.from_latency)
+    peak_flops: float = PEAK_FLOPS  # chip the program is priced for
+    hbm_bandwidth: float = HBM_BW   # (repro.comm.plan.DEVICE_PEAKS)
 
     @classmethod
     def from_latency(cls, model, **kw) -> "Roofline":
@@ -169,11 +171,11 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peak_flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_device / HBM_BW
+        return self.hbm_bytes_per_device / self.hbm_bandwidth
 
     @property
     def t_collective(self) -> float:

@@ -26,9 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from jax.sharding import AxisType
 from repro.configs import get_config, list_archs, reduced_config
 from repro.configs.base import ShapeConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.settings import settings_for
 from repro.models import build_model
@@ -52,8 +53,9 @@ def run_paged(args) -> None:
     if r > len(jax.devices()):
         raise SystemExit(f"--model-parallel {r} needs {r} devices, have "
                          f"{len(jax.devices())}")
-    mesh = compat.make_mesh((1, r), ("data", "model"),
-                            devices=jax.devices()[:r])
+    mesh = jax.make_mesh((1, r), ("data", "model"),
+                         devices=jax.devices()[:r],
+                         axis_types=(AxisType.Auto,) * 2)
     longest = args.prompt_len + max(args.long_len, args.short_len)
     plan = plan_kv_arena(cfg, mesh, page_tokens=args.page_tokens,
                          max_seqs=args.slots, max_seq_len=longest)
@@ -163,6 +165,7 @@ def main() -> None:
                     help="paged: instrument the run (JSONL events + Chrome "
                          "trace under DIR)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.paged:
         run_paged(args)

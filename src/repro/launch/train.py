@@ -18,6 +18,7 @@ from repro.comm import CommConfig, SCHEDULE_POLICIES, list_transports
 from repro.configs import get_config, list_archs, reduced_config
 from repro.configs.base import ShapeConfig
 from repro.data import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.settings import settings_for
 from repro.obs import ObsConfig
@@ -71,6 +72,7 @@ def main() -> None:
                          "DB's measured alpha/beta) and track live "
                          "predicted-vs-measured drift")
     args = ap.parse_args()
+    enable_compile_cache()
 
     st = settings_for(args.arch)
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)

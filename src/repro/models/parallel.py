@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 
 @dataclass(frozen=True)
 class ParallelCtx:
@@ -55,7 +53,7 @@ class ParallelCtx:
         return lax.pmax(x, self.model_axis) if self.model_axis else x
 
     def model_size(self) -> int:
-        return compat.axis_size(self.model_axis) if self.model_axis else 1
+        return lax.axis_size(self.model_axis) if self.model_axis else 1
 
     def model_index(self):
         return lax.axis_index(self.model_axis) if self.model_axis else 0
@@ -88,7 +86,7 @@ class ParallelCtx:
     def dp_world(self) -> int:
         n = 1
         for a in self.data_axes:
-            n *= compat.axis_size(a)
+            n *= lax.axis_size(a)
         return n
 
     def psum_data(self, x):

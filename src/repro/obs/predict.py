@@ -16,28 +16,34 @@ rest of ``repro.obs`` stays stdlib-only).
 
 from __future__ import annotations
 
-from repro.comm.plan import LatencyModel
+from repro.comm.plan import LatencyModel, device_peaks
 from repro.launch.roofline import Roofline, collective_wire_bytes
 
 
 def predict_step_time(step_fn, example_args, *, mesh,
                       overlap_fraction: float = 0.0,
-                      latency: LatencyModel | None = None) -> dict:
+                      latency: LatencyModel | None = None,
+                      device_kind: str | None = None) -> dict:
     """AOT-lower ``step_fn(*example_args)`` and price it.
 
     Returns the roofline terms plus ``t_step_s`` (the overlap-honest bound
-    the drift detector compares measured steps against).  ``latency``
-    replaces the hardcoded α/β constants with measured ones (a tuning-DB
-    record); ``overlap_fraction`` is the executed CommSchedule's.
+    the drift detector compares measured steps against).  The peaks are
+    those of ``device_kind`` (default: the mesh's devices) from
+    :data:`repro.comm.plan.DEVICE_PEAKS`; a device without published
+    peaks raises.  ``latency`` replaces the α/β constants with measured
+    ones (a tuning-DB record); ``overlap_fraction`` is the executed
+    CommSchedule's.
     """
+    kind = device_kind or mesh.devices.flat[0].device_kind
+    peaks = device_peaks(kind)
     with mesh:
         lowered = step_fn.lower(*example_args)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     stats = collective_wire_bytes(compiled.as_text())
     roof_kw = dict(
+        peak_flops=peaks.bf16_flops,
+        hbm_bandwidth=peaks.hbm_bandwidth,
         flops_per_device=float(ca.get("flops", 0.0)),
         hbm_bytes_per_device=float(ca.get("bytes accessed", 0.0)),
         wire_bytes_per_device=stats.wire_bytes,
@@ -45,7 +51,7 @@ def predict_step_time(step_fn, example_args, *, mesh,
         messages_per_device=stats.messages,
     )
     roof = (Roofline.from_latency(latency, **roof_kw) if latency is not None
-            else Roofline(**roof_kw))
+            else Roofline(link_bandwidth=peaks.link_bandwidth, **roof_kw))
     return {
         "t_step_s": roof.bound_time_overlapped,
         "t_compute_s": roof.t_compute,
@@ -58,6 +64,7 @@ def predict_step_time(step_fn, example_args, *, mesh,
         "messages_per_device": stats.messages,
         "alpha_s": roof.alpha_s,
         "link_bandwidth": roof.link_bandwidth,
+        "device_kind": kind,
         "source": "tuned" if latency is not None else "roofline",
     }
 

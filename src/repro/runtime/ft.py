@@ -199,6 +199,8 @@ def elastic_remesh(n_devices: int, *, model_parallel: int = 16,
                    want_pods: int = 1):
     shape, names = elastic_shape(n_devices, model_parallel=model_parallel,
                                  want_pods=want_pods)
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    return compat.make_mesh(shape, names)
+    return jax.make_mesh(shape, names,
+                         axis_types=(AxisType.Auto,) * len(shape))

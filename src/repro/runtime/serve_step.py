@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.model_api import Model
@@ -81,8 +80,8 @@ def build_prefill(model: Model, mesh: Mesh, shape_cfg, *,
                                  causal_skip=causal_skip)
 
     out_spec = P(batch_axes, None, vocab_ax)
-    sharded = compat.shard_map(fn, mesh=mesh, in_specs=(pspecs, bspecs),
-                               out_specs=out_spec, check_vma=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(pspecs, bspecs),
+                            out_specs=out_spec, check_vma=False)
     return jax.jit(sharded), pspecs
 
 
@@ -115,7 +114,7 @@ def build_decode_step(model: Model, mesh: Mesh, shape_cfg, *,
             return model.decode_step(params, token, state, pos, ctx=ctx,
                                      seq_len=s)
 
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspecs, P(batch_axes), state_specs, P()),
         out_specs=(P(batch_axes, vocab_ax), state_specs),

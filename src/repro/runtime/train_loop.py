@@ -96,38 +96,32 @@ class Trainer:
                                            source="explicit")
         if not (cfg.predict or cfg.tuned_db):
             return None
-        try:
-            from repro.obs import predict as obs_predict
+        from repro.obs import predict as obs_predict
 
-            latency = None
-            source = "roofline"
-            if cfg.tuned_db:
-                data_axes, _ = _mesh_axes(self.mesh)
-                ccfg = self.step_cfg.comm_config(data_axes)
-                mesh_label = "x".join(
-                    str(d) for d in self.mesh.devices.shape)
-                got = obs_predict.tuned_latency(
-                    cfg.tuned_db, transport=ccfg.transport,
-                    mesh_label=mesh_label, channels=ccfg.channels,
-                    page_bytes=ccfg.page_bytes)
-                if got is not None:
-                    latency, fit_err, key = got
-                    source = "tuned"
-                    self.obs.event("tuned_record", key=key, **fit_err)
-            sched = build_step_schedule(self.model, self.mesh, self.step_cfg)
-            pred = obs_predict.predict_step_time(
-                self.step_fn, (self.state, self.data.batch_at(0)),
-                mesh=self.mesh, overlap_fraction=sched.overlap_fraction,
-                latency=latency)
-            self.obs.event("prediction", **pred)
-            self.log(f"[obs] predicted step {pred['t_step_s']*1e3:.1f} ms "
-                     f"({pred['bottleneck']}-bound, {pred['source']})")
-            return self.obs.drift_detector(pred["t_step_s"], source=source)
-        except Exception as e:   # prediction is advisory — never kill a run
-            self.obs.event("predict_failed", error=repr(e))
-            self.log(f"[obs] step-time prediction failed ({e!r}); "
-                     f"drift detection disabled")
-            return None
+        latency = None
+        source = "roofline"
+        if cfg.tuned_db:
+            data_axes, _ = _mesh_axes(self.mesh)
+            ccfg = self.step_cfg.comm_config(data_axes)
+            mesh_label = "x".join(
+                str(d) for d in self.mesh.devices.shape)
+            got = obs_predict.tuned_latency(
+                cfg.tuned_db, transport=ccfg.transport,
+                mesh_label=mesh_label, channels=ccfg.channels,
+                page_bytes=ccfg.page_bytes)
+            if got is not None:
+                latency, fit_err, key = got
+                source = "tuned"
+                self.obs.event("tuned_record", key=key, **fit_err)
+        sched = build_step_schedule(self.model, self.mesh, self.step_cfg)
+        pred = obs_predict.predict_step_time(
+            self.step_fn, (self.state, self.data.batch_at(0)),
+            mesh=self.mesh, overlap_fraction=sched.overlap_fraction,
+            latency=latency)
+        self.obs.event("prediction", **pred)
+        self.log(f"[obs] predicted step {pred['t_step_s']*1e3:.1f} ms "
+                 f"({pred['bottleneck']}-bound, {pred['source']})")
+        return self.obs.drift_detector(pred["t_step_s"], source=source)
 
     def run(self) -> dict:
         history: list[dict] = []

@@ -66,7 +66,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import numpy as np
 
-from repro import compat
 from repro.comm import CommConfig, Communicator
 from repro.comm.schedule import CommSchedule, SCHEDULE_POLICIES, build_schedule
 from repro.core.bucketing import BucketPlan
@@ -220,8 +219,8 @@ def _slice_to_local(tree_full, specs):
             idx = jnp.zeros((), jnp.int32)
             p = 1
             for a in axes:
-                idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
-                p *= compat.axis_size(a)
+                idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
+                p *= jax.lax.axis_size(a)
             seg = leaf.shape[d] // p
             leaf = jax.lax.dynamic_slice_in_dim(leaf, idx * seg, seg, axis=d)
         return leaf
@@ -269,12 +268,27 @@ def build_span_norm_weights(layout: ArenaLayout,
     return out
 
 
+def _weighted_sq_sum(shard: jax.Array, w, axes: tuple[str, ...]
+                    ) -> jax.Array:
+    """``sum(shard² · w)`` over this rank's RS-shard of the per-bucket
+    norm weights ``w``.  A weight vector that is one value throughout
+    (every field equally model-replicated — always so without a model
+    axis) is applied as that scalar: the dense vector would be a
+    bucket-sized literal baked into the compiled step (GBs at published
+    widths, and minutes of TPU compile)."""
+    sq = jnp.square(shard.astype(jnp.float32))
+    w = np.asarray(w)
+    if w.size and (w == w.flat[0]).all():
+        return jnp.sum(sq) * w.flat[0]
+    return jnp.sum(sq * _slice_like_shard(jnp.asarray(w), axes))
+
+
 def _slice_like_shard(w: jax.Array, axes: tuple[str, ...]) -> jax.Array:
     """Slice a per-bucket weight vector down to this rank's RS-shard, using
     the same ownership layout as hierarchical reduce-scatter (inner axis
     segments first)."""
     for ax in axes:
-        p = compat.axis_size(ax)
+        p = jax.lax.axis_size(ax)
         r = jax.lax.axis_index(ax)
         seg = w.shape[0] // p
         w = jax.lax.dynamic_slice_in_dim(w, r * seg, seg)
@@ -360,7 +374,7 @@ class FsdpPlan:
         out = []
         for b in buckets:
             for ax in reversed(self.data_axes):      # outermost segment first
-                p = compat.axis_size(ax)
+                p = jax.lax.axis_size(ax)
                 r = jax.lax.axis_index(ax)
                 seg = b.shape[0] // p
                 b = jax.lax.dynamic_slice_in_dim(b, r * seg, seg)
@@ -516,8 +530,8 @@ def init_train_state(model: Model, mesh: Mesh, cfg: TrainStepConfig,
     def mk_from_data(kd):
         return mk(jax.random.wrap_key_data(kd))
 
-    fn = compat.shard_map(mk_from_data, mesh=mesh, in_specs=P(),
-                          out_specs=specs, check_vma=False)
+    fn = jax.shard_map(mk_from_data, mesh=mesh, in_specs=P(),
+                       out_specs=specs, check_vma=False)
     if abstract:
         kd_abs = jax.eval_shape(jax.random.key_data, jax.random.key(0))
         return jax.eval_shape(fn, kd_abs), specs
@@ -667,8 +681,7 @@ def build_train_step(model: Model, mesh: Mesh, cfg: TrainStepConfig,
                 ordered = comm.ordered_axes
                 sq = jnp.zeros((), jnp.float32)
                 for s, w in zip(shards, zero1_norm_weights):
-                    wl = _slice_like_shard(jnp.asarray(w), ordered)
-                    sq = sq + jnp.sum(jnp.square(s) * wl)
+                    sq = sq + _weighted_sq_sum(s, w, ordered)
                 gnorm = jnp.sqrt(ctx.psum(ctx.psum_data(sq)))
                 factor = clip_factor(gnorm, cfg.optim.clip_norm)
                 shards = [s * factor for s in shards]
@@ -758,8 +771,7 @@ def build_train_step(model: Model, mesh: Mesh, cfg: TrainStepConfig,
             sq = jnp.zeros((), jnp.float32)
             for name in sorted(plan.groups):
                 for g, w in zip(grads[name], plan.norm_weights[name]):
-                    wl = _slice_like_shard(jnp.asarray(w), ordered)
-                    sq = sq + jnp.sum(jnp.square(g.astype(jnp.float32)) * wl)
+                    sq = sq + _weighted_sq_sum(g, w, ordered)
             gnorm = jnp.sqrt(ctx.psum(ctx.psum_data(sq)))
             factor = clip_factor(gnorm, cfg.optim.clip_norm)
             lr = schedule(state["step"])
@@ -788,8 +800,8 @@ def build_train_step(model: Model, mesh: Mesh, cfg: TrainStepConfig,
                        "lr": lr, "moe_drop_fraction": ctx.pmean_data(drop)}
             return new_state, metrics
 
-    sharded = compat.shard_map(step_fn, mesh=mesh,
-                               in_specs=(state_specs, batch_pspecs),
-                               out_specs=(state_specs, metric_specs),
-                               check_vma=False)
+    sharded = jax.shard_map(step_fn, mesh=mesh,
+                            in_specs=(state_specs, batch_pspecs),
+                            out_specs=(state_specs, metric_specs),
+                            check_vma=False)
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

@@ -35,7 +35,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.comm import CommConfig, Communicator
 from repro.obs import NULL_OBS
 from repro.configs.base import ModelConfig
@@ -84,43 +83,45 @@ def _write_token_kv(pages, plan: KVArenaPlan, layer: int, table, slot_len,
                     slot_valid, k1, v1):
     """Scatter this step's K/V (B, Hkv, 1, D) into each slot's current page.
 
-    Invalid slots (or unmapped blocks) get an out-of-bounds index, which the
-    scatter drops — no branch, no shape change."""
+    The arena is viewed as rows of ``head_dim`` elements, so each update
+    is one contiguous D-wide row per (slot, kv head) rather than D scalar
+    scatters.  Invalid slots (or unmapped blocks) get an out-of-bounds
+    row, which the scatter drops — no branch, no shape change."""
     pt, d, hkv = plan.page_tokens, plan.head_dim, plan.num_kv_heads
     block = slot_len // pt
     within = slot_len % pt
     page = jnp.take_along_axis(table[:, :, layer], block[:, None],
                                axis=1)[:, 0]                       # (B,)
     ok = slot_valid & (page >= 0)
-    base = page * plan.page_stride + within * d                    # (B,)
-    idx = (base[:, None, None]
-           + (jnp.arange(hkv) * (pt * d))[None, :, None]
-           + jnp.arange(d)[None, None, :])                         # (B,Hkv,D)
-    idx = jnp.where(ok[:, None, None], idx, plan.total_elems)      # OOB drop
-    pages = pages.at[idx].set(k1[:, :, 0, :].astype(pages.dtype))
-    pages = pages.at[idx + plan.v_offset].set(v1[:, :, 0, :].astype(pages.dtype))
-    return pages
+    rows = pages.reshape(-1, d)
+    base = page * (plan.page_stride // d) + within                  # (B,)
+    ridx = base[:, None] + jnp.arange(hkv)[None, :] * pt           # (B,Hkv)
+    ridx = jnp.where(ok[:, None], ridx, rows.shape[0])             # OOB drop
+    rows = rows.at[ridx].set(k1[:, :, 0, :].astype(rows.dtype), mode="drop")
+    rows = rows.at[ridx + plan.v_offset // d].set(
+        v1[:, :, 0, :].astype(rows.dtype), mode="drop")
+    return rows.reshape(-1)
 
 
 def _gather_local_kv(pages, plan: KVArenaPlan, layer: int, table, rank):
     """This rank's chunk of the paged cache as dense (B, Hkv, L_local, D)
     K/V, plus its page-table slice (for validity).  ``rank`` is traced;
-    the chunk extent ``blocks_per_rank`` is static."""
+    the chunk extent ``blocks_per_rank`` is static.  Whole pages are
+    gathered as rows of the (pages, page_stride) view of the arena."""
     bpr, pt, d = plan.blocks_per_rank, plan.page_tokens, plan.head_dim
     hkv = plan.num_kv_heads
     tab = lax.dynamic_slice_in_dim(table[:, :, layer], rank * bpr, bpr,
                                    axis=1)                         # (B, bpr)
-    base = jnp.maximum(tab, 0) * plan.page_stride
-    off = ((jnp.arange(hkv) * (pt * d))[:, None, None]
-           + (jnp.arange(pt) * d)[None, :, None]
-           + jnp.arange(d)[None, None, :])                     # (Hkv, Pt, D)
-    idx = base[:, :, None, None, None] + off[None, None]   # (B,bpr,Hkv,Pt,D)
-    b = idx.shape[0]
-    k = jnp.take(pages, idx).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, hkv, bpr * pt, d)
-    v = jnp.take(pages, idx + plan.v_offset).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, hkv, bpr * pt, d)
-    return k, v, tab
+    stride = plan.page_stride
+    by_page = pages[:plan.n_kv_pages * stride].reshape(-1, stride)
+    blk = jnp.take(by_page, jnp.maximum(tab, 0), axis=0)   # (B, bpr, stride)
+    b, n = blk.shape[0], hkv * pt * d
+
+    def dense(start):
+        return blk[:, :, start:start + n].reshape(b, bpr, hkv, pt, d) \
+            .transpose(0, 2, 1, 3, 4).reshape(b, hkv, bpr * pt, d)
+
+    return dense(0), dense(plan.v_offset), tab
 
 
 def _local_valid(plan: KVArenaPlan, tab, slot_len, slot_valid, rank):
@@ -163,6 +164,12 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
         raise ValueError(
             f"plan was laid out for model_parallel={plan.model_parallel} "
             f"but the mesh model axis is {r_mesh}; re-plan with this mesh")
+    if plan.page_stride % plan.head_dim or plan.total_elems % plan.head_dim:
+        raise ValueError(
+            f"KV pages must hold whole head_dim={plan.head_dim} rows "
+            f"(page stride {plan.page_stride}, arena {plan.total_elems} "
+            f"elements); pick a page_bytes that is a multiple of "
+            f"head_dim * itemsize")
     r = plan.model_parallel
     comm = (Communicator(mesh, CommConfig(transport="psum",
                                           data_axes=("model",), channels=1))
@@ -241,7 +248,7 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
     sspecs = shard_rules.decode_state_specs(state_abs, cfg, mesh,
                                             plan.max_seqs)
     pspecs = jax.tree.map(lambda _: P(), model.abstract_params())
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(sspecs["pages"], pspecs, sspecs["page_table"], P(),
                   sspecs["slot_len"], sspecs["slot_valid"]),
@@ -342,10 +349,14 @@ class PagedDecodeEngine:
         Invalid slots' rows are garbage by contract."""
         for s in np.nonzero(self.slot_valid)[0]:
             self._ensure_block(int(s))
+        # the step runs asynchronously while the host goes on mutating its
+        # slot arrays, and a host-to-device transfer may alias a numpy
+        # buffer (zero-copy on the CPU): hand the step private copies
         with self.mesh:
             logits, self.pages = self.step(
-                self.pages, params, jnp.asarray(self.table.table),
+                self.pages, params, jnp.asarray(self.table.table.copy()),
                 jnp.asarray(token, jnp.int32).reshape(self.plan.max_seqs),
-                jnp.asarray(self.slot_len), jnp.asarray(self.slot_valid))
+                jnp.asarray(self.slot_len.copy()),
+                jnp.asarray(self.slot_valid.copy()))
         self.slot_len[self.slot_valid] += 1
         return logits

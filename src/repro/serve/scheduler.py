@@ -70,6 +70,14 @@ def mixed_trace(groups: int = 4, slots: int = 4, long_len: int = 64,
     return reqs
 
 
+def request_token(rid: int, i: int, vocab: int) -> int:
+    """Token fed at step ``i`` of request ``rid``: the scheduler's
+    deterministic synthetic stream (prompt, then stand-ins for the
+    generated tokens).  A reference forward of ``[request_token(rid, i)
+    for i in range(total_steps)]`` reproduces what the engine saw."""
+    return (rid * 7 + i) % vocab
+
+
 class ServeScheduler:
     """Drives a :class:`~repro.serve.engine.PagedDecodeEngine` over a
     request trace under one of the two batching policies."""
@@ -96,9 +104,13 @@ class ServeScheduler:
             fed[slot] = 0
 
     def run(self, params, requests: list[Request], *,
-            max_steps: int = 100_000) -> dict:
+            max_steps: int = 100_000, capture=()) -> dict:
         """Process every request; returns throughput stats (tokens are
-        *generated* tokens — prompt streaming is overhead, not output)."""
+        *generated* tokens — prompt streaming is overhead, not output).
+
+        ``capture``: request ids whose logits at their last step are
+        copied to the host and returned under ``final_logits`` (rid ->
+        fp32 ``(vocab,)``), for checking against a reference forward."""
         eng = self.engine
         obs = self.obs
         vocab = eng.model.cfg.vocab_size
@@ -109,6 +121,7 @@ class ServeScheduler:
         generated = np.zeros((s,), np.int64)
         steps = total_generated = total_prefill = 0
         live_sum = 0
+        final_logits: dict[int, np.ndarray] = {}
         t_run = time.time()
 
         while queue or eng.slot_valid.any():
@@ -137,9 +150,9 @@ class ServeScheduler:
             token = np.zeros((s,), np.int32)
             for sl in live:
                 r = slot_req[sl]
-                token[sl] = (r.rid * 7 + int(fed[sl])) % vocab
+                token[sl] = request_token(r.rid, int(fed[sl]), vocab)
             with obs.span("decode_step", policy=self.policy):
-                eng.decode(params, token)
+                logits = eng.decode(params, token)
             steps += 1
             live_sum += int(live.size)
             for sl in live:
@@ -151,6 +164,9 @@ class ServeScheduler:
                 else:
                     total_prefill += 1
                 if generated[sl] >= r.decode_len:
+                    if r.rid in capture:
+                        final_logits[r.rid] = np.asarray(logits[sl],
+                                                         np.float32)
                     eng.retire(int(sl))
                     slot_req[sl] = None
                     generated[sl] = 0
@@ -173,4 +189,5 @@ class ServeScheduler:
             "prefill_steps": total_prefill,
             "tokens_per_step": total_generated / max(steps, 1),
             "mean_live_slots": live_sum / max(steps, 1),
+            "final_logits": final_logits,
         }

@@ -184,14 +184,13 @@ CFG = _json.loads('__CFG_JSON__')
 _ALLREDUCE_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
 shape = tuple(CFG["mesh"])
 axes = ("pod", "data")[:len(shape)] if len(shape) <= 2 else \
     tuple(f"d{i}" for i in range(len(shape)))
-mesh = compat.make_mesh(shape, axes)
+mesh = jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 mesh_label = "x".join(str(d) for d in shape)
 rng = np.random.RandomState(0)
 
@@ -224,12 +223,11 @@ for transport in CFG["transports"]:
 _ARENA_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
 n_dev = len(jax.devices())
-mesh = compat.make_mesh((n_dev,), ("data",))
+mesh = jax.make_mesh((n_dev,), ("data",), axis_types=(AxisType.Auto,) * 1)
 rng = np.random.RandomState(0)
 batch = jnp.asarray(rng.randn(16, 8).astype(np.float32))
 
@@ -262,7 +260,7 @@ for page_bytes in CFG["pages"]:
                     arena_buf=buf)
                 return loss, tree, out
 
-            fa = jax.jit(compat.shard_map(
+            fa = jax.jit(jax.shard_map(
                 arena_run, mesh=mesh,
                 in_specs=(P(), P("data"), P(("data",))),
                 out_specs=(P(), P(), P(("data",))), check_vma=False),
@@ -285,12 +283,12 @@ for page_bytes in CFG["pages"]:
 _HALO_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = [HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2)]
 transport = CFG["transports"][0]
 for ch in CFG["channels"]:
@@ -304,9 +302,9 @@ for ch in CFG["channels"]:
         def fn(xl):
             h = comm.halo_exchange(xl, SPECS, schedule="concurrent")
             return sum(v.sum() for v in h.values())
-        g = jax.jit(compat.shard_map(fn, mesh=mesh,
-                                     in_specs=P("x", "y", "z", None),
-                                     out_specs=P(), check_vma=False))
+        g = jax.jit(jax.shard_map(fn, mesh=mesh,
+                                  in_specs=P("x", "y", "z", None),
+                                  out_specs=P(), check_vma=False))
         t = time_call(g, x, warmup=CFG["warmup"], iters=CFG["iters"])
         emit(bench="halo", arch=CFG["arch"], mesh="2x2x2",
              transport=transport, channels=ch,
@@ -319,14 +317,14 @@ for ch in CFG["channels"]:
 _CG_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import (StencilOp, predicted_halo_exchanges,
                            predicted_reduction_collectives, solve)
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 WORLD = 8
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2))
 op = StencilOp(specs=SPECS, mass=0.5)
@@ -345,7 +343,7 @@ for ch in CFG["channels"]:
                       schedule="concurrent", chunks=comm.halo_chunks,
                       channels=ch)
             return r.x, r.iters, r.rel_residual
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=P("x", "y", "z", None),
             out_specs=(P("x", "y", "z", None), P(), P()),
             check_vma=False))

@@ -29,6 +29,9 @@ def run_distributed(script: str, n_devices: int = 8, timeout: int = 560,
     import subprocess
 
     env = dict(os.environ)
+    # host devices only: on a machine with an accelerator the child must
+    # never take it from the parent
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices}"
                         + (f" {extra_flags}" if extra_flags else ""))
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")

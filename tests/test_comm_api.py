@@ -74,9 +74,10 @@ def test_comm_config_from_policy_forced_overrides():
 
 
 def _mesh1():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    return compat.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,) * 1)
 
 
 def test_unknown_transport_fails_at_construction():
@@ -164,11 +165,10 @@ def test_communicator_stripe_and_plan():
 EQUIV_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator, list_transports
 
-mesh = compat.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,) * 1)
 rng = np.random.RandomState(0)
 tree = {f"g{i}": jnp.asarray(rng.randn(3000 + 256*i).astype(np.float32))
         for i in range(4)}
@@ -178,9 +178,9 @@ def per_device(g):
     i = jax.lax.axis_index("data")
     return jax.tree.map(lambda t: t * (1.0 + i), g)
 
-gv = jax.jit(compat.shard_map(per_device, mesh=mesh, in_specs=(specs,),
-                              out_specs=specs, check_vma=False))(tree)
-ref = jax.jit(compat.shard_map(
+gv = jax.jit(jax.shard_map(per_device, mesh=mesh, in_specs=(specs,),
+                           out_specs=specs, check_vma=False))(tree)
+ref = jax.jit(jax.shard_map(
     lambda g: jax.tree.map(lambda x: jax.lax.pmean(x, "data"), g),
     mesh=mesh, in_specs=(specs,), out_specs=specs, check_vma=False))(gv)
 
@@ -349,11 +349,11 @@ def test_transport_message_counts():
     assert uni.predicted_messages_per_device([4]) == 6.0
     # message count scales with buckets through CommPlan (axis size 1 mesh:
     # no wire, so just check the field and describe key are wired through)
+    import jax
     import jax.numpy as jnp
+    from jax.sharding import AxisType
 
-    from repro import compat
-
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,) * 1)
     comm = Communicator(mesh, CommConfig(transport="psum",
                                          data_axes=("data",)))
     plan = comm.plan({"w": jnp.zeros((512,), jnp.float32)})
@@ -363,11 +363,12 @@ def test_transport_message_counts():
 
 
 def test_halo_plan_message_count_is_unit_count():
+    import jax
+    from jax.sharding import AxisType
+
     from repro.core.halo import HaloSpec
 
-    from repro import compat
-
-    mesh = compat.make_mesh((1,), ("x",))
+    mesh = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,) * 1)
     comm = Communicator(mesh, CommConfig(data_axes=("x",), channels=2))
     specs = [HaloSpec("x", 0, 1)]
     plan = comm.halo_plan((6, 5), specs, schedule="concurrent")
@@ -383,18 +384,21 @@ def test_halo_plan_message_count_is_unit_count():
 
 
 def _a2a_comm(transport="a2a", channels=0):
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,) * 1)
     return Communicator(mesh, CommConfig(transport=transport,
                                          data_axes=("model",),
                                          channels=channels))
 
 
 def test_a2a_needs_single_axis_and_capability():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    mesh2 = compat.make_mesh((1, 1), ("pod", "data"))
+    mesh2 = jax.make_mesh((1, 1), ("pod", "data"),
+                          axis_types=(AxisType.Auto,) * 2)
     comm2 = Communicator(mesh2, CommConfig(transport="a2a",
                                            data_axes=("pod", "data")))
     import jax.numpy as jnp
@@ -456,19 +460,18 @@ def test_a2a_axis_size_one_is_identity():
 A2A_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
-mesh = compat.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,) * 1)
 rng = np.random.RandomState(0)
 x = jnp.asarray(rng.randn(4, 8, 3, 12).astype(np.float32))
 
 def native(v):
     return jax.lax.all_to_all(v, "model", 1, 0, tiled=True)
 
-ref = jax.jit(compat.shard_map(native, mesh=mesh, in_specs=P(),
-                               out_specs=P("model"), check_vma=False))(x)
+ref = jax.jit(jax.shard_map(native, mesh=mesh, in_specs=P(),
+                            out_specs=P("model"), check_vma=False))(x)
 
 for transport in ("a2a", "ring", "ring_hier", "psum"):
     for channels in (0, 2, 3):
@@ -479,9 +482,9 @@ for transport in ("a2a", "ring", "ring_hier", "psum"):
         def fwd(v):
             return comm.all_to_all(v, split_axis=1, concat_axis=0)
 
-        out = jax.jit(compat.shard_map(fwd, mesh=mesh, in_specs=P(),
-                                       out_specs=P("model"),
-                                       check_vma=False))(x)
+        out = jax.jit(jax.shard_map(fwd, mesh=mesh, in_specs=P(),
+                                    out_specs=P("model"),
+                                    check_vma=False))(x)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
         print(transport, channels, "fwd ok")
 
@@ -490,7 +493,7 @@ def loss_ref(v, w_local):
     return jnp.sum(native(v) * w_local)
 
 w = jnp.asarray(rng.randn(64, 2, 3, 12).astype(np.float32))
-gref = jax.jit(compat.shard_map(
+gref = jax.jit(jax.shard_map(
     jax.grad(loss_ref), mesh=mesh, in_specs=(P(), P("model")),
     out_specs=P(), check_vma=False))(x, w)
 for transport in ("a2a", "ring", "psum"):
@@ -501,7 +504,7 @@ for transport in ("a2a", "ring", "psum"):
         return jnp.sum(comm.all_to_all(v, split_axis=1, concat_axis=0)
                        * w_local)
 
-    g = jax.jit(compat.shard_map(
+    g = jax.jit(jax.shard_map(
         jax.grad(loss_t), mesh=mesh, in_specs=(P(), P("model")),
         out_specs=P(), check_vma=False))(x, w)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gref),
@@ -519,9 +522,9 @@ def ragged(v):
                                       concat_axis=0)
     return recv, rc
 
-_, rc = jax.jit(compat.shard_map(ragged, mesh=mesh, in_specs=P(),
-                                 out_specs=(P("model"), P("model")),
-                                 check_vma=False))(x)
+_, rc = jax.jit(jax.shard_map(ragged, mesh=mesh, in_specs=P(),
+                              out_specs=(P("model"), P("model")),
+                              check_vma=False))(x)
 rc = np.asarray(rc).reshape(4, 4)
 for i in range(4):
     for j in range(4):

@@ -7,19 +7,18 @@ from conftest import run_distributed
 
 RING_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core import ring
 from repro.core.ring import RingConfig
 
-mesh = compat.make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 L = 2*4*2*4*512*2
 x = np.random.RandomState(0).randn(8, L).astype(np.float32)
 want = x.sum(0)
 
 def run(fn, cfg, axes):
-    g = jax.jit(compat.shard_map(lambda xl: fn(xl.reshape(-1), axes, cfg),
-        mesh=mesh, in_specs=P(("pod","data")), out_specs=P(), check_vma=False))
+    g = jax.jit(jax.shard_map(lambda xl: fn(xl.reshape(-1), axes, cfg),
+     mesh=mesh, in_specs=P(("pod","data")), out_specs=P(), check_vma=False))
     return np.asarray(g(x.reshape(-1)))
 
 for cfg in [RingConfig(chunks=1, bidirectional=False),
@@ -42,8 +41,8 @@ cfg = RingConfig(chunks=2, bidirectional=True)
 def rsag(xl):
     s = ring.ring_reduce_scatter(xl.reshape(-1), "data", cfg)
     return ring.ring_all_gather(s, "data", cfg)
-g = jax.jit(compat.shard_map(rsag, mesh=mesh, in_specs=P(("pod","data")),
-    out_specs=P(("pod","data")), check_vma=False))
+g = jax.jit(jax.shard_map(rsag, mesh=mesh, in_specs=P(("pod","data")),
+ out_specs=P(("pod","data")), check_vma=False))
 out = np.asarray(g(x.reshape(-1))).reshape(2, 4, L)
 per_pod = x.reshape(2,4,L).sum(1)
 for p in range(2):
@@ -54,11 +53,11 @@ print("RING_OK")
 
 REDUCER_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.reducer import GradientReducer, ReduceConfig
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 rng = np.random.RandomState(1)
 grads = {"w": jnp.asarray(rng.randn(16, 256).astype(np.float32)),
          "b": jnp.asarray(rng.randn(256).astype(np.float32)),
@@ -74,8 +73,8 @@ for policy in ["fused_ring_hierarchical", "fused_ring", "native_psum",
     def mk(x):
         i = jax.lax.axis_index("pod")*2 + jax.lax.axis_index("data")
         return jax.tree.map(lambda t: t*(1.0+i), x)
-    gv = jax.jit(compat.shard_map(mk, mesh=mesh, in_specs=(specs,),
-                                  out_specs=specs, check_vma=False))(grads)
+    gv = jax.jit(jax.shard_map(mk, mesh=mesh, in_specs=(specs,),
+                               out_specs=specs, check_vma=False))(grads)
     out = jax.jit(lambda g: red.reduce(g, specs)[0])(gv)
     scale = np.mean([1.0+i for i in range(4)])
     for k in grads:
@@ -86,18 +85,17 @@ print("REDUCER_OK")
 
 HALO_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core.halo import HaloSpec, halo_exchange
 
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,) * 1)
 Y = jnp.arange(64, dtype=jnp.float32).reshape(64, 1)
 for sched in ["concurrent", "sequential", "chunked"]:
     def hx(xl, s=sched):
         h = halo_exchange(xl, [HaloSpec("data", 0)], schedule=s, chunks=1)
         return jnp.concatenate([h[("data","-")], xl, h[("data","+")]], 0)
-    g = jax.jit(compat.shard_map(hx, mesh=mesh, in_specs=P("data"),
-                                 out_specs=P("data"), check_vma=False))
+    g = jax.jit(jax.shard_map(hx, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"), check_vma=False))
     out = np.asarray(g(Y)).reshape(8, 10)
     ys = np.asarray(Y).reshape(8, 8)
     for r in range(8):
@@ -108,8 +106,7 @@ print("HALO_OK")
 
 DPMODES_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.runtime.train_step import TrainStepConfig, build_train_step, init_train_state
@@ -117,7 +114,8 @@ from repro.core.reducer import ReduceConfig
 from repro.optim import adamw_tree_update, init_opt_state, OptimConfig, make_schedule
 from repro.optim.adamw import clip_factor
 
-mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 cfg = reduced_config("llama3.2-1b")
 m = build_model(cfg)
 B, S = 8, 32
@@ -163,14 +161,14 @@ print("DPMODES_OK")
 
 SERVE_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.configs import reduced_config, base
 from repro.models import build_model
 from repro.runtime.serve_step import build_decode_step, build_prefill
 from repro.sharding import shardings_of
 
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = reduced_config("llama3.2-1b")
 m = build_model(cfg)
 params = m.init(jax.random.key(0))
@@ -222,14 +220,13 @@ def test_serve_decode_seq_sharded_kv():
 
 EP_BITWISE_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
 from repro.models.parallel import SINGLE
 from repro.runtime.train_step import TrainStepConfig, make_ctx
 
-mesh = compat.make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",), axis_types=(AxisType.Auto,) * 1)
 cfg = MoEConfig(num_experts=4, top_k=2, expert_ff=32, capacity_factor=2.0,
                 parallelism="ep")
 d, B, S = 16, 4, 8
@@ -261,7 +258,7 @@ for transport in ("a2a", "ring", "psum"):
         # (fan_out's backward already summed the rank-partials)
         return l, y, drop, gp, gx
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         sharded, mesh=mesh, in_specs=(pspecs, P()),
         out_specs=(P(), P(), P(), pspecs, P()), check_vma=False))
     l, y, drop, gp, gx = fn(p, x)
@@ -280,14 +277,13 @@ print("EP_BITWISE_OK")
 
 EP_TOL_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_mod
 from repro.models.parallel import SINGLE
 from repro.runtime.train_step import TrainStepConfig, make_ctx
 
-mesh = compat.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,) * 1)
 d = 32
 
 cases = [
@@ -322,7 +318,7 @@ for ci, (cfg, B, S) in enumerate(cases):
         lambda pp, xx: loss(pp, xx, SINGLE), argnums=1))(p, x)
 
     ctx = make_ctx(mesh, TrainStepConfig(moe_transport="a2a"))
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda pp, xx: jax.value_and_grad(
             lambda a, b: loss(a, b, ctx), argnums=1)(pp, xx),
         mesh=mesh, in_specs=(pspecs, P()), out_specs=(P(), P()),

@@ -18,9 +18,10 @@ from repro.mem import (ArenaLayout, CommArena, PAGE_BYTES, fuse_schedule,
 
 
 def _mesh1():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    return compat.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,) * 1)
 
 
 # ---------------------------------------------------------------------------
@@ -317,11 +318,10 @@ def test_communicator_arena_plan_and_schedule():
 
 HLO_FUSE_SCRIPT = r"""
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
-mesh = compat.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,) * 1)
 comm = Communicator(mesh, CommConfig(transport="psum", data_axes=("data",),
                                      channels=2, bucket_bytes=4096,
                                      page_bytes=4096))
@@ -346,12 +346,12 @@ def arena_fn(buf, grads, b):
     return out, tree
 
 spec = {k: P() for k in tree}
-fb = jax.jit(compat.shard_map(bucket_fn, mesh=mesh, in_specs=(spec, P()),
-                              out_specs=spec, check_vma=False))
-fa = jax.jit(compat.shard_map(arena_fn, mesh=mesh,
-                              in_specs=(P(("data",)), spec, P()),
-                              out_specs=(P(("data",)), spec),
-                              check_vma=False), donate_argnums=(0,))
+fb = jax.jit(jax.shard_map(bucket_fn, mesh=mesh, in_specs=(spec, P()),
+                           out_specs=spec, check_vma=False))
+fa = jax.jit(jax.shard_map(arena_fn, mesh=mesh,
+                           in_specs=(P(("data",)), spec, P()),
+                           out_specs=(P(("data",)), spec),
+                           check_vma=False), donate_argnums=(0,))
 arena_abs = jax.ShapeDtypeStruct((4 * lay.total_elems,), jnp.float32)
 ca = fa.lower(arena_abs, tree, batch).compile()
 cb = fb.lower(tree, batch).compile()
@@ -385,11 +385,10 @@ def test_fused_spans_lower_to_fewer_collectives():
 CROSS_TRANSPORT_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 
-mesh = compat.make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",), axis_types=(AxisType.Auto,) * 1)
 rng = np.random.RandomState(3)
 tree = {f"g{i}": jnp.asarray(rng.randn(500 + 128 * i).astype(np.float32))
         for i in range(4)}
@@ -414,9 +413,9 @@ for transport in ("ring_hier", "psum"):
                                             arena_buf=buf)
         return t
     spec = {k: P() for k in tree}
-    fn = jax.jit(compat.shard_map(run, mesh=mesh,
-                                  in_specs=(spec, P("data"), P(("data",))),
-                                  out_specs=spec, check_vma=False))
+    fn = jax.jit(jax.shard_map(run, mesh=mesh,
+                               in_specs=(spec, P("data"), P(("data",))),
+                               out_specs=spec, check_vma=False))
     buf = jnp.zeros((2 * arena.layout.total_elems,), jnp.float32)
     outs[transport] = fn(tree, batch, buf)
 
@@ -443,16 +442,15 @@ def test_arena_cross_transport_bitwise_2proc():
 def test_checkpoint_roundtrip_across_use_arena(tmp_path):
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.checkpoint import restore, save
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                           init_train_state)
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config("llama3.2-1b"))
     rng = np.random.RandomState(0)
     batch = {"tokens": jnp.asarray(rng.randint(0, 500, (4, 32)), jnp.int32),
@@ -502,15 +500,15 @@ def test_checkpoint_roundtrip_across_use_arena(tmp_path):
 DP_EQUIV_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                       init_train_state)
 
-mesh = compat.make_mesh((4, 1), ("data", "model"))
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 model = build_model(reduced_config("llama3.2-1b"))
 rng = np.random.RandomState(0)
 batch = {"tokens": jnp.asarray(rng.randint(0, 500, (8, 32)), jnp.int32),

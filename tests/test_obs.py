@@ -6,7 +6,8 @@ import json
 import numpy as np
 import pytest
 
-from repro import compat
+import jax
+from jax.sharding import AxisType
 from repro.configs import reduced_config
 from repro.configs.base import ShapeConfig
 from repro.core.reducer import ReduceConfig
@@ -210,7 +211,8 @@ def test_bench_record_roundtrip_and_validation(tmp_path):
 
 
 def _mesh():
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _tiny(steps, obs_cfg):
@@ -290,12 +292,29 @@ def test_predict_step_time_prices_the_live_step():
 
     tr = _tiny(2, None)
     sched = build_step_schedule(tr.model, tr.mesh, tr.step_cfg)
+    # the CPU has no published peaks: price the program for a v5e chip
     pred = predict_step_time(tr.step_fn, (tr.state, tr.data.batch_at(0)),
                              mesh=tr.mesh,
-                             overlap_fraction=sched.overlap_fraction)
+                             overlap_fraction=sched.overlap_fraction,
+                             device_kind="TPU v5 lite")
     assert pred["t_step_s"] > 0 and pred["source"] == "roofline"
     assert pred["bottleneck"] in ("compute", "memory", "collective")
     assert pred["t_step_s"] >= pred["t_exposed_collective_s"]
+    assert pred["device_kind"] == "TPU v5 lite"
+
+
+def test_predict_step_time_raises_for_a_device_without_peaks():
+    """No silent v5e default: the live device (here the CPU) must be in
+    the peaks table, and a requested prediction that fails stops the
+    Trainer instead of being logged and skipped."""
+    from repro.obs.predict import predict_step_time
+
+    tr = _tiny(2, None)
+    with pytest.raises(ValueError, match="no published peaks"):
+        predict_step_time(tr.step_fn, (tr.state, tr.data.batch_at(0)),
+                          mesh=tr.mesh)
+    with pytest.raises(ValueError, match="no published peaks"):
+        _tiny(2, ObsConfig(predict=True))
 
 
 # ---------------------------------------------------------------------------

@@ -23,9 +23,10 @@ from repro.mem.layout import SCALE_BYTES
 
 
 def _mesh1():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    return compat.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,) * 1)
 
 
 # ---------------------------------------------------------------------------
@@ -175,16 +176,15 @@ def test_quant_config_rejections():
 def test_checkpoint_roundtrip_across_wire_codec(tmp_path):
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.checkpoint import restore, save
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                           init_train_state)
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config("llama3.2-1b"))
     rng = np.random.RandomState(0)
     batch = {"tokens": jnp.asarray(rng.randint(0, 500, (4, 32)), jnp.int32),
@@ -271,15 +271,15 @@ def test_checkpoint_roundtrip_across_wire_codec(tmp_path):
 QUANT_DP_EQUIV_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                       init_train_state)
 
-mesh = compat.make_mesh((4, 1), ("data", "model"))
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 model = build_model(reduced_config("llama3.2-1b"))
 rng = np.random.RandomState(0)
 batch = {"tokens": jnp.asarray(rng.randint(0, 500, (8, 32)), jnp.int32),
@@ -345,8 +345,7 @@ QUANT_CONVERGENCE_SCRIPT = r"""
 import os
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig
 from repro.configs import reduced_config
 from repro.models import build_model
@@ -354,7 +353,8 @@ from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                       init_train_state)
 
 STEPS = int(os.environ.get("QUANT_EQ_STEPS", "30"))
-mesh = compat.make_mesh((4, 1), ("data", "model"))
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 model = build_model(reduced_config("llama3.2-1b"))
 bspecs = {"tokens": P("data", None), "labels": P("data", None)}
 

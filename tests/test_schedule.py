@@ -113,9 +113,10 @@ def test_roofline_exposed_collective_bounds():
 
 
 def _comm(transport="ring_hier", **kw):
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,) * 1)
     return Communicator(mesh, CommConfig(transport=transport,
                                          data_axes=("data",), **kw))
 
@@ -159,15 +160,15 @@ def test_reduce_scheduled_detects_bucket_mismatch():
 EQUIV_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.configs import reduced_config
 from repro.core.reducer import ReduceConfig
 from repro.models import build_model
 from repro.runtime.train_step import (TrainStepConfig, build_train_step,
                                       init_train_state)
 
-mesh = compat.make_mesh((4, 1), ("data", "model"))   # 1xN data parallel
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)   # 1xN data parallel
 cfg = reduced_config("llama3.2-1b")
 model = build_model(cfg)
 B, S = 8, 32
@@ -211,15 +212,15 @@ print("SCHED_EQUIV_OK")
 HLO_SCRIPT = r"""
 import re
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.runtime.train_step import (TrainStepConfig, build_step_schedule,
                                       build_train_step, init_train_state)
 
-mesh = compat.make_mesh((4, 1), ("data", "model"))
+mesh = jax.make_mesh((4, 1), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 cfg = reduced_config("llama3.2-1b")
 model = build_model(cfg)
 bspecs = {"tokens": P("data", None), "labels": P("data", None)}

@@ -63,9 +63,11 @@ def test_kv_plan_arithmetic():
 def test_kv_plan_pads_blocks_to_model_axis():
     from types import SimpleNamespace
 
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     _, p1 = _plan(page_tokens=8, max_seqs=2, max_seq_len=24, mesh=mesh)
     assert p1.model_parallel == 1 and p1.max_blocks == 3
     # a 4-wide model axis forces max_blocks up to a multiple of 4 so every
@@ -220,13 +222,13 @@ def test_flash_decode_output_wrapper(rng):
 
 def _engine(attn_impl="ref", **plan_kw):
     import jax
-
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.serve import PagedDecodeEngine, plan_kv_arena
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config("llama3.2-1b"))
     params = model.init(jax.random.PRNGKey(0))
     plan_kw.setdefault("page_tokens", 8)
@@ -287,13 +289,12 @@ def test_decode_state_specs_replicate_paged_state():
     otherwise slot_len/page_table get scattered over data ranks."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.configs import reduced_config
     from repro.sharding import rules
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = reduced_config("llama3.2-1b")
     state = {
         "pages": jax.ShapeDtypeStruct((1024,), jnp.bfloat16),
@@ -334,7 +335,7 @@ def test_single_rank_step_lowers_to_zero_collectives():
 
 SERVE_HLO_R2_SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
+from jax.sharding import AxisType
 from repro.configs import reduced_config
 from repro.models import build_model
 from repro.launch.roofline import collective_wire_bytes
@@ -343,7 +344,8 @@ from repro.serve.engine import (build_paged_decode_step,
                                 predicted_collectives_per_token,
                                 predicted_wire_bytes_per_token)
 
-mesh = compat.make_mesh((1, 2), ("data", "model"))
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 model = build_model(reduced_config("llama3.2-1b"))
 plan = plan_kv_arena(model.cfg, mesh, page_tokens=8, page_bytes=4096,
                      max_seqs=4, max_seq_len=64)
@@ -367,8 +369,9 @@ want_b = predicted_wire_bytes_per_token(plan, model.cfg, plan.max_seqs)
 assert got_b == want_b, (got_b, want_b)           # zero tolerance
 
 # numeric equivalence R=2 vs R=1: same params, same tokens, same logits
-mesh1 = compat.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1],
+                      axis_types=(AxisType.Auto,) * 2)
 plan1 = plan_kv_arena(model.cfg, mesh1, page_tokens=8, page_bytes=4096,
                       max_seqs=4, max_seq_len=64)
 from repro.serve import PagedDecodeEngine
@@ -402,13 +405,15 @@ def test_model_parallel_collective_count_and_equivalence():
 def test_gathered_serving_rejects_non_decoder_only(arch):
     """ssm / hybrid / audio-frontend families must refuse gathered serving
     at BUILD time (the old check only caught encdec, only in prefill)."""
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.runtime.serve_step import build_decode_step, build_prefill
     from repro.configs.base import ShapeConfig
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config(arch))
     shp = ShapeConfig("serve_test", 16, 2, "decode")
     with pytest.raises(NotImplementedError, match="decoder-only"):
@@ -418,13 +423,15 @@ def test_gathered_serving_rejects_non_decoder_only(arch):
 
 
 def test_gathered_serving_still_builds_for_decoder_only():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.runtime.serve_step import build_decode_step
     from repro.configs.base import ShapeConfig
 
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = build_model(reduced_config("llama3.2-1b"))
     shp = ShapeConfig("serve_test", 16, 2, "decode")
     step, pspecs, sspecs = build_decode_step(model, mesh, shp,

@@ -251,15 +251,15 @@ def test_predicted_collective_counts():
 COUNTS_SCRIPT = r"""
 import math
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.launch.roofline import collective_wire_bytes
 from repro.stencil import (StencilOp, predicted_halo_exchanges,
                            predicted_reduction_collectives, solve)
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2))
 op = StencilOp(specs=SPECS, mass=0.8)
 comm = Communicator(mesh, CommConfig(transport="psum",
@@ -276,10 +276,10 @@ for solver in ("cg", "pipelined", "sstep"):
                       maxiter=ITERS, schedule="concurrent",
                       chunks=comm.halo_chunks, channels=2)
             return r.x, r.rel_residual
-        fn = jax.jit(compat.shard_map(run, mesh=mesh,
-                                      in_specs=P("x", "y", "z", None),
-                                      out_specs=(P("x", "y", "z", None), P()),
-                                      check_vma=False))
+        fn = jax.jit(jax.shard_map(run, mesh=mesh,
+                                   in_specs=P("x", "y", "z", None),
+                                   out_specs=(P("x", "y", "z", None), P()),
+                                   check_vma=False))
         txt = fn.lower(jax.ShapeDtypeStruct(gshape, jnp.float32)) \
                 .compile().as_text()
         stats = collective_wire_bytes(txt)
@@ -320,13 +320,12 @@ OVERLAP_SCRIPT = r"""
 import re
 import sys
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp, solve
 
-mesh = compat.make_mesh((2, 2), ("x", "y"))
+mesh = jax.make_mesh((2, 2), ("x", "y"), axis_types=(AxisType.Auto,) * 2)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1))
 op = StencilOp(specs=SPECS, mass=0.5)
 comm = Communicator(mesh, CommConfig(transport="psum", data_axes=("x", "y"),
@@ -340,9 +339,9 @@ def compiled_text(solver):
         r = solve(op, b, comm, solver=solver, tol=None, maxiter=ITERS,
                   schedule="concurrent", chunks=2, channels=0)
         return r.x, r.rel_residual
-    fn = jax.jit(compat.shard_map(run, mesh=mesh, in_specs=P("x", "y", None),
-                                  out_specs=(P("x", "y", None), P()),
-                                  check_vma=False))
+    fn = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P("x", "y", None),
+                               out_specs=(P("x", "y", None), P()),
+                               check_vma=False))
     return fn.lower(jax.ShapeDtypeStruct(gshape, jnp.float32)) \
              .compile().as_text()
 
@@ -413,8 +412,7 @@ HISTORY_SCRIPT = r"""
 import math
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp, solve
@@ -425,8 +423,9 @@ for mesh_shape, names in [((2,), ("x",)), ((2, 2), ("x", "y"))]:
     nproc = 1
     for p in mesh_shape:
         nproc *= p
-    mesh = compat.make_mesh(mesh_shape, names,
-                            devices=jax.devices()[:nproc])
+    mesh = jax.make_mesh(mesh_shape, names,
+                         devices=jax.devices()[:nproc],
+                         axis_types=(AxisType.Auto,) * len(mesh_shape))
     specs = tuple(HaloSpec(a, d, 1) for d, a in enumerate(names))
     op = StencilOp(specs=specs, mass=0.3)
     gshape = tuple(6 * p for p in mesh_shape) + (3,)
@@ -443,9 +442,9 @@ for mesh_shape, names in [((2,), ("x",)), ((2, 2), ("x", "y"))]:
                           maxiter=MAXITER, schedule="concurrent", chunks=2,
                           channels=2)
                 return r.x, r.history
-            fn = jax.jit(compat.shard_map(run, mesh=mesh, in_specs=pspec,
-                                          out_specs=(pspec, P()),
-                                          check_vma=False))
+            fn = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=pspec,
+                                       out_specs=(pspec, P()),
+                                       check_vma=False))
             x, h = fn(b)
             results[(transport, solver)] = (np.asarray(x), np.asarray(h))
 
@@ -481,7 +480,7 @@ for mesh_shape, names in [((2,), ("x",)), ((2, 2), ("x", "y"))]:
 
 # 3) halo schedules move exact ppermute data: bitwise-identical iterates
 #    for the new solvers too (fusion off, psum, 4-proc mesh)
-mesh = compat.make_mesh((2, 2), ("x", "y"))
+mesh = jax.make_mesh((2, 2), ("x", "y"), axis_types=(AxisType.Auto,) * 2)
 specs = (HaloSpec("x", 0, 1), HaloSpec("y", 1, 1))
 op = StencilOp(specs=specs, mass=0.3)
 rng = np.random.RandomState(7)
@@ -495,10 +494,10 @@ for solver in ("pipelined", "sstep"):
             r = solve(op, bl, comm, solver=sv, s=S, tol=None,
                       maxiter=MAXITER, schedule=sc, chunks=2, channels=2)
             return r.x
-        fn = jax.jit(compat.shard_map(run, mesh=mesh,
-                                      in_specs=P("x", "y", None),
-                                      out_specs=P("x", "y", None),
-                                      check_vma=False))
+        fn = jax.jit(jax.shard_map(run, mesh=mesh,
+                                   in_specs=P("x", "y", None),
+                                   out_specs=P("x", "y", None),
+                                   check_vma=False))
         sols[sched] = np.asarray(fn(b))
     for sched in ("concurrent", "overlap"):
         assert np.array_equal(sols["sequential"], sols[sched]), \
@@ -521,13 +520,13 @@ def test_solver_histories_distributed_and_cross_transport():
 EO_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp, solve
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2))
 op = StencilOp(specs=SPECS, mass=0.2)
 rng = np.random.RandomState(3)
@@ -541,7 +540,7 @@ def run_solver(solver, precond):
                   tol=1e-5, maxiter=300, schedule="overlap", chunks=2,
                   channels=2)
         return r.x, r.iters, r.rel_residual
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         run, mesh=mesh, in_specs=P("x", "y", "z", None),
         out_specs=(P("x", "y", "z", None), P(), P()), check_vma=False))
     x, iters, rel = fn(b)

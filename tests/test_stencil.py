@@ -111,18 +111,16 @@ def test_split_chunks_uneven_roundtrip():
 def test_operator_matches_periodic_reference_all_schedules():
     import jax
     import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.stencil import StencilOp
 
     op = StencilOp(specs=(HaloSpec("x", 0), HaloSpec("y", 1)), mass=0.7)
     x = jnp.asarray(np.random.RandomState(0).randn(6, 5).astype(np.float32))
     ref = np.asarray(op.apply_reference(x))
-    mesh = compat.make_mesh((1, 1), ("x", "y"))
+    mesh = jax.make_mesh((1, 1), ("x", "y"), axis_types=(AxisType.Auto,) * 2)
     outs = {}
     for sched in HALO_SCHEDULES:
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v, s=sched: op.apply(v, schedule=s, channels=2),
             mesh=mesh, in_specs=P("x", "y"), out_specs=P("x", "y"),
             check_vma=False))
@@ -163,9 +161,10 @@ def test_cg_fixed_iteration_mode_is_nan_free_past_convergence():
 
 
 def test_halo_plan_bytes_and_describe():
-    from repro import compat
+    import jax
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh((1,), ("x",))
+    mesh = jax.make_mesh((1,), ("x",), axis_types=(AxisType.Auto,) * 1)
     comm = Communicator(mesh, CommConfig(data_axes=("x",), channels=2))
     specs = [HaloSpec("x", 0, 2)]
     plan = comm.halo_plan((6, 5), specs, schedule="concurrent")
@@ -189,8 +188,7 @@ def test_halo_plan_bytes_and_describe():
 MESH_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import HALO_SCHEDULES
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp
@@ -198,7 +196,8 @@ from repro.stencil import StencilOp
 rng = np.random.RandomState(3)
 CASES = [((8,), ("x",)), ((4, 2), ("x", "y")), ((2, 2, 2), ("x", "y", "z"))]
 for mesh_shape, names in CASES:
-    mesh = compat.make_mesh(mesh_shape, names)
+    mesh = jax.make_mesh(mesh_shape, names,
+                         axis_types=(AxisType.Auto,) * len(mesh_shape))
     nd = len(names)
     for halo in (1, 2):
         specs = tuple(HaloSpec(a, d, halo) for d, a in enumerate(names))
@@ -209,7 +208,7 @@ for mesh_shape, names in CASES:
         pspec = P(*names, None)
         outs = {}
         for sched in HALO_SCHEDULES:
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 lambda v, s=sched: op.apply(v, schedule=s, chunks=2,
                                             channels=2),
                 mesh=mesh, in_specs=pspec, out_specs=pspec,
@@ -239,11 +238,10 @@ def test_operator_bitwise_identical_across_schedules_and_meshes():
 HLO_SCRIPT = r"""
 import re
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.core.halo import HaloSpec, halo_exchange
 
-mesh = compat.make_mesh((2, 2), ("x", "y"))
+mesh = jax.make_mesh((2, 2), ("x", "y"), axis_types=(AxisType.Auto,) * 2)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1))
 N_DIMS = 2
 
@@ -252,8 +250,8 @@ def lowered(sched, channels=0):
         h = halo_exchange(xl, SPECS, schedule=sched, chunks=2,
                           channels=channels)
         return sum(v.sum() for v in h.values())
-    g = jax.jit(compat.shard_map(hx, mesh=mesh, in_specs=P("x", "y"),
-                                 out_specs=P(), check_vma=False))
+    g = jax.jit(jax.shard_map(hx, mesh=mesh, in_specs=P("x", "y"),
+                              out_specs=P(), check_vma=False))
     return g.lower(jnp.zeros((8, 8), jnp.float32)).as_text()
 
 VAR = re.compile(r"%[\w.#]+")
@@ -318,13 +316,12 @@ def test_overlap_lowers_independent_permutes_sequential_chains():
 
 BYTES_SCRIPT = r"""
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.core.halo import HaloSpec, halo_exchange
 from repro.launch.roofline import collective_wire_bytes
 
-mesh = compat.make_mesh((4, 2), ("x", "y"))
+mesh = jax.make_mesh((4, 2), ("x", "y"), axis_types=(AxisType.Auto,) * 2)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1))
 comm = Communicator(mesh, CommConfig(data_axes=("x", "y"), channels=3))
 local = (5, 7, 3)                  # odd everywhere: every face splits unevenly
@@ -334,8 +331,8 @@ for sched in ("chunked", "concurrent", "overlap", "sequential"):
     def hx(xl, s=sched):
         h = comm.halo_exchange(xl, SPECS, schedule=s)
         return sum(v.sum() for v in h.values())
-    g = jax.jit(compat.shard_map(hx, mesh=mesh, in_specs=P("x", "y", None),
-                                 out_specs=P(), check_vma=False))
+    g = jax.jit(jax.shard_map(hx, mesh=mesh, in_specs=P("x", "y", None),
+                              out_specs=P(), check_vma=False))
     txt = g.lower(jnp.zeros(gshape, jnp.float32)).compile().as_text()
     stats = collective_wire_bytes(txt)
     plan = comm.halo_plan(local, SPECS, schedule=sched)
@@ -363,13 +360,13 @@ def test_predicted_halo_bytes_match_lowered_hlo_odd_shapes():
 CG_SCRIPT = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.comm import CommConfig, Communicator, HALO_SCHEDULES
 from repro.core.halo import HaloSpec
 from repro.stencil import StencilOp, cg_solve
 
-mesh = compat.make_mesh((2, 2, 2), ("x", "y", "z"))
+mesh = jax.make_mesh((2, 2, 2), ("x", "y", "z"),
+                     axis_types=(AxisType.Auto,) * 3)
 SPECS = (HaloSpec("x", 0), HaloSpec("y", 1), HaloSpec("z", 2))
 op = StencilOp(specs=SPECS, mass=0.5)
 rng = np.random.RandomState(3)
@@ -385,7 +382,7 @@ for transport in ("psum", "ring_hier"):
             r = cg_solve(op, bl, comm, tol=1e-6, maxiter=200, schedule=s,
                          chunks=2, channels=2)
             return r.x, r.iters, r.rel_residual
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             run, mesh=mesh, in_specs=P("x", "y", "z", None),
             out_specs=(P("x", "y", "z", None), P(), P()), check_vma=False))
         x, iters, rel = fn(b)

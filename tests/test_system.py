@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
+from jax.sharding import AxisType
 from repro.configs import reduced_config
 from repro.core.reducer import ReduceConfig
 from repro.data import DataConfig, SyntheticTokens
@@ -17,7 +17,8 @@ from repro.runtime.train_step import TrainStepConfig
 
 def _mesh():
     # feature-detects AxisType / axis_types support for the installed jax
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _setup(tmp_path, steps=24, ckpt_every=8):

@@ -1,0 +1,121 @@
+"""The main path's Pallas kernels compile for a v5e chip at real shapes.
+
+Interpret mode (every other kernel test) never meets the Mosaic compiler,
+which refuses block shapes and memory use the interpreter accepts.  These
+tests compile each kernel of the serving and gradient-arena paths for a
+*described* v5e:2x2 topology — no chip is attached, nothing runs — and
+check the compiled program holds the kernel (``tpu_custom_call``), i.e.
+that the main-path shapes take the kernel and not the jnp fallback.  One
+more test holds the ring gradient reduction to a compile time in seconds
+at a published-width parameter shape.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and the test workers all import
+this file.
+"""
+
+import os
+import time
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.core.ring import RingConfig, ring_all_gather, ring_reduce_scatter
+from repro.kernels.flash_decode import ops as fd_ops
+from repro.kernels.pack import ops as pack_ops
+from repro.kernels.pack_quant import ops as pq_ops
+
+PAGE = 2 * 2**20 // 4          # one 2 MiB arena page of fp32 elements
+BUCKET = 2**20                 # 1 MiB-element quantized bucket
+QBLOCK = 512                   # the int8 wire codec's block
+
+
+@pytest.fixture(scope="module")
+def topo():
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler here
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _flash_decode(sds):
+    # the paged engine's call: B=8 slots, 32 q heads with the 8 GQA kv
+    # heads expanded per q head, 1024 key positions, head_dim 64, bf16
+    b, hq, length, d = 8, 32, 1024, 64
+    args = (sds((b, hq, 1, d), jnp.bfloat16),
+            sds((b, hq, length, d), jnp.bfloat16),
+            sds((b, hq, length, d), jnp.bfloat16),
+            sds((b, length), jnp.bool_))
+    return (lambda q, k, v, m: fd_ops.flash_decode_stats(
+        q, k, v, m, interpret=False)), args
+
+
+def _write_flat(sds):
+    return (lambda a, s: pack_ops.write_flat(a, s, PAGE, interpret=False),
+            (sds((4 * PAGE,), jnp.float32), sds((PAGE,), jnp.float32)))
+
+
+def _read_flat(sds):
+    return (lambda a: pack_ops.read_flat(a, PAGE, PAGE, interpret=False),
+            (sds((4 * PAGE,), jnp.float32),))
+
+
+def _write_quant_flat(sds):
+    return (lambda a, s: pq_ops.write_quant_flat(
+        a, s, BUCKET, 3 * BUCKET, QBLOCK, interpret=False),
+        (sds((4 * BUCKET,), jnp.int8), sds((BUCKET,), jnp.float32)))
+
+
+def _read_dequant_flat(sds):
+    return (lambda a: pq_ops.read_dequant_flat(
+        a, BUCKET, BUCKET, 3 * BUCKET, QBLOCK, interpret=False),
+        (sds((4 * BUCKET,), jnp.int8),))
+
+
+@pytest.mark.parametrize("case", [_flash_decode, _write_flat, _read_flat,
+                                  _write_quant_flat, _read_dequant_flat],
+                         ids=lambda c: c.__name__.lstrip("_"))
+def test_kernel_compiles_for_v5e(case, one_chip):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, args = case(sds)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ring_reduce_of_a_tiled_param_compiles_in_seconds(topo):
+    """ZeRO-1's gradient path at llama3.2-1b's embedding shape: flatten a
+    128256x2048 fp32 array, ring reduce-scatter and all-gather it over
+    four v5e chips, reshape it back.  With the reshapes fused into the
+    ring's channel slicing the TPU compiler took about 400 s; with the
+    flat buffer materialised, about 2 s."""
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,),
+                         devices=topo.devices[:4])
+    cfg = RingConfig(chunks=2, bidirectional=True)
+
+    def reduce(w):
+        shard = ring_reduce_scatter(w.reshape(-1), "data", cfg)
+        return w + ring_all_gather(shard, "data", cfg).reshape(w.shape)
+
+    fn = jax.jit(jax.shard_map(reduce, mesh=mesh, in_specs=P(),
+                               out_specs=P(), check_vma=False))
+    arg = jax.ShapeDtypeStruct((128256, 2048), jnp.float32,
+                               sharding=NamedSharding(mesh, P()))
+    t0 = time.perf_counter()
+    fn.lower(arg).compile()
+    assert time.perf_counter() - t0 < 60
