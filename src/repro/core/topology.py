@@ -14,7 +14,6 @@ from dataclasses import dataclass
 from typing import Sequence
 
 import jax
-from jax import lax
 
 
 Axis = str
@@ -25,14 +24,6 @@ def ring_perm(size: int, direction: int = +1) -> list[tuple[int, int]]:
     if direction not in (+1, -1):
         raise ValueError(f"ring direction must be +-1, got {direction}")
     return [(i, (i + direction) % size) for i in range(size)]
-
-
-def axis_size(axis: Axis) -> int:
-    return lax.axis_size(axis)
-
-
-def axis_index(axis: Axis):
-    return lax.axis_index(axis)
 
 
 def mesh_axis_size(mesh: jax.sharding.Mesh, axis: Axis) -> int:
