@@ -25,9 +25,11 @@ Four chips (``--chips 4``):
 
 * ``dp`` — data parallelism over 4 chips: the arch's ZeRO-1 over
   ``ring_hier`` against replicated parameters reduced by ``psum``, from the
-  same initial state and batch.  Step-0 losses must be equal; the grad
-  norms, the loss of the updated parameters and the parameters themselves
-  must agree (see ``FLIP_SHARE`` for the elements a rounding may flip).
+  same initial state and batches, two steps.  The two transports on the
+  step's bucket sizes must agree to fp32 rounding; step-0 losses must be
+  equal; Adam's first moments (linear in the reduced gradients) must
+  agree leaf by leaf to the precision of the bf16 local gradients; each
+  run's updated parameters must be the AdamW update of its own moments.
 * ``halo`` — ``solve(..., solver="cg")`` on a 2x2 decomposition of a
   lattice of 64^3 sites per chip against the single-device reference
   solve (``comm=None``) of the same problem.
@@ -43,6 +45,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -57,6 +60,7 @@ if SRC not in sys.path:
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax import lax  # noqa: E402
 from jax.sharding import AxisType, NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -72,7 +76,8 @@ from repro.models import build_model  # noqa: E402
 from repro.optim import OptimConfig  # noqa: E402
 from repro.runtime.train_loop import Trainer, TrainerConfig  # noqa: E402
 from repro.runtime.train_step import (TrainStepConfig,  # noqa: E402
-                                      build_train_step, init_train_state)
+                                      build_comm, build_train_step,
+                                      init_train_state)
 from repro.serve.engine import PagedDecodeEngine  # noqa: E402
 from repro.serve.kv import kv_page_payload_elems, plan_kv_arena  # noqa: E402
 from repro.serve.scheduler import (Request, ServeScheduler,  # noqa: E402
@@ -88,15 +93,22 @@ LOSS_RTOL = BF16_EPS      # train: step-0 loss vs model.loss_fn outside it
 # largest logit.  The two paths round the bf16 residual stream at different
 # points over 16 layers (2.2 eps measured at d_model 512 on the CPU).
 LOGIT_EPS = 4
-PARAM_ATOL_LR = 1e-2      # dp: |ring - psum| params, in units of the lr
-# dp: share of params whose first Adam update may take the other sign.  Its
-# size is about +-lr whatever the gradient's, so where a gradient is within
-# rounding of zero (fp32 sums in another order; bf16 local grads fused
-# differently) the sign is a coin toss: an estimated few in a thousand
-# elements.  A misrouted bucket would move a tenth or more
-FLIP_SHARE = 1e-2
+# dp: the arch transport's reduce-scatter + all-gather against psum's
+# all-reduce on the same fp32 buckets: |difference| <= (world - 1) *
+# eps32 * sum_i |x_i|, the rounding bound of two orders of one sum
+FP32_EPS = 2.0 ** -23
+# dp: per leaf, |mu_ring - mu_psum| over |mu_psum|, in the L2 norm and in
+# the largest element.  The two train steps are different programs, so
+# their bf16 local gradients are not bitwise equal (the reduction alone is
+# held to fp32 rounding above): on a v5e 2x2 at 6 layers the moments were
+# 2.9e-3 (L2) and 4.7e-3 (max) apart, 0.09% of the elements equal.  A
+# misrouted bucket segment moves a leaf by more than 0.1
+MOMENT_RTOL = 2 * BF16_EPS
+# dp: |params after the update - AdamW of the run's own moments, computed
+# outside the step|, in units of the lr, beyond 4 ulp of the parameter.
+# The two differ by a few ulp of lr*update; a misrouted update is O(lr)
+UPDATE_ATOL_LR = 1e-3
 GNORM_RTOL = 1e-5         # dp: grad norms, fp32 sums in another order
-LOSS1_RTOL = 1e-6         # dp: loss of the updated params (8 ulp at 11.9)
 HALO_RTOL = 1e-5          # halo: |x - x_ref| / |x_ref|
 # dp depth: the replicated psum reference holds 16 B per parameter on
 # every chip plus its all-reduce buffers.  memory_analysis on a described
@@ -350,13 +362,127 @@ def serve_phase(devices, cfg: ModelConfig, *, slots: int = 8,
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
+def _leaf_gaps(a, b):
+    """Per leaf of ``a`` and ``b``: ``|a-b|_2, |b|_2, max|a-b|, max|b|``,
+    and the count of bitwise-equal elements."""
+    gaps, equal = [], []
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        d = jnp.abs(x - y).ravel()
+        gaps.append(jnp.stack([jnp.linalg.norm(d), jnp.linalg.norm(y.ravel()),
+                               d.max(), jnp.abs(y).max()]))
+        equal.append(jnp.sum(x == y))
+    return gaps, equal
+
+
+def moment_mismatch(a, b) -> dict:
+    """``a`` against ``b`` (trees of like arrays), leaf by leaf: the largest
+    ``|a - b|_2 / |b|_2`` (and the leaf that has it) and ``max|a - b| /
+    max|b|`` over the leaves, and the share of elements bitwise equal."""
+    gaps, equal = jax.device_get(_leaf_gaps(a, b))
+    paths = [jax.tree_util.keystr(p)
+             for p, _ in jax.tree_util.tree_leaves_with_path(a)]
+    l2 = [_ratio(g[0], g[1]) for g in gaps]
+    worst = int(np.argmax(l2))
+    n = sum(x.size for x in jax.tree.leaves(a))
+    return {"l2": l2[worst], "l2_leaf": paths[worst],
+            "max": max(_ratio(g[2], g[3]) for g in gaps),
+            "equal_share": sum(int(e) for e in equal) / n}
+
+
+def _ratio(num, den) -> float:
+    num, den = float(num), float(den)
+    return num / den if den else (math.inf if num else 0.0)
+
+
+@functools.partial(jax.jit, static_argnames=("t", "optim"))
+def _adamw_gap(p0, p1, mu, nu, lr, *, t: int, optim: OptimConfig):
+    f32 = jnp.float32
+    decay = f32(1) - lr * f32(optim.weight_decay)
+    c1, c2 = f32(1 - optim.b1 ** t), f32(1 - optim.b2 ** t)
+    worst = []
+    for a0, a1, m, v in zip(*(jax.tree.leaves(x) for x in (p0, p1, mu, nu)),
+                            strict=True):
+        upd = (m / c1) / (jnp.sqrt(v / c2) + f32(optim.eps))
+        mag = jnp.abs(a0)
+        ulp = jnp.nextafter(mag, f32(jnp.inf)) - mag
+        worst.append(jnp.max(jnp.abs(a1 - (a0 * decay - lr * upd)) - 4 * ulp))
+    return jnp.max(jnp.stack(worst)) / lr
+
+
+def adamw_mismatch(p0, p1, mu, nu, *, lr: float, t: int,
+                   optim: OptimConfig) -> float:
+    """Largest ``|p1 - AdamW(p0; mu, nu)|`` over the elements, beyond 4 ulp
+    of ``p0``, in units of ``lr``: how far the train step's update of
+    ``p0`` is from the AdamW step of its own moments ``mu``/``nu`` (the
+    moments after step ``t``), computed elementwise outside the step."""
+    return float(_adamw_gap(p0, p1, mu, nu, jnp.float32(lr), t=t,
+                            optim=optim))
+
+
+def zero1_tree(shards, model, mesh, step_cfg: TrainStepConfig):
+    """ZeRO-1 optimizer shards (one flat bucket each, split over the data
+    axes) as a tree shaped like the parameters, by the step's own bucket
+    plan.  The mesh has no model axis, so local shapes are global."""
+    bucketer = build_comm(mesh, step_cfg).bucketer
+    plan = bucketer.plan(model.abstract_params())
+    return jax.jit(lambda bs: bucketer.debucketize(bs, plan))(list(shards))
+
+
+def transport_mismatch(mesh, step_cfg: TrainStepConfig, sizes,
+                       seed: int) -> float:
+    """The step's transport against psum on random fp32 buckets, one per
+    chip and size: the reduce-scatter's shards and their all-gather
+    against psum's all-reduce.  Returns the largest ``|difference| / (eps32
+    * sum_i |x_i|)`` over the elements (a sum in another order differs by
+    at most ``world - 1`` of these)."""
+    comm = build_comm(mesh, step_cfg)
+    ref = Communicator(mesh, dataclasses.replace(comm.cfg, transport="psum"))
+    (axis,) = comm.ordered_axes
+
+    def gap(key, n):
+        r = lax.axis_index(axis)
+        x = jax.random.normal(jax.random.fold_in(key, r), (n,), jnp.float32)
+        (shard,) = comm.reduce_scatter([x])
+        (full,) = comm.all_gather([shard])
+        (want,) = ref.all_reduce([x])
+        (bound,) = ref.all_reduce([jnp.abs(x)])
+        bound = FP32_EPS * bound + jnp.finfo(jnp.float32).tiny
+        seg = n // comm.world
+        mine = [lax.dynamic_slice_in_dim(v, r * seg, seg)
+                for v in (want, bound)]
+        worst = jnp.maximum(jnp.max(jnp.abs(full - want) / bound),
+                            jnp.max(jnp.abs(shard - mine[0]) / mine[1]))
+        return lax.pmax(worst, axis)
+
+    worst = 0.0
+    key = jax.random.key(seed)
+    for n in sorted(set(sizes)):
+        f = jax.jit(jax.shard_map(functools.partial(gap, n=n), mesh=mesh,
+                                  in_specs=P(), out_specs=P(),
+                                  check_vma=False))
+        worst = max(worst, float(f(key)))
+    return worst
+
+
 def dp_phase(devices, cfg: ModelConfig, *, layers: int, seq: int = 2048,
              batch_per_chip: int = 1, seed: int = 0, log=print) -> dict:
     """ZeRO-1/ring_hier vs replicated/psum from the same state and data,
-    at depth ``layers``.
+    at depth ``layers``, two steps each.
 
-    Two steps: the repo's schedules all give lr(0) = 0, so step 1 is the
-    first update that moves the parameters."""
+    First the transports alone: ring_hier's reduce-scatter and all-gather
+    of random fp32 buckets of the step's sizes must give psum's all-reduce
+    to the rounding bound of a sum in another order.  Then the train
+    steps.  The repo's schedules give lr(0) = 0, so step 0 leaves the
+    parameters as they were, and both runs take step 1's gradient at the
+    initial parameters too.  Adam's first moment after step 1 is then
+    linear in the two steps' clipped, reduced gradients and must agree
+    leaf by leaf (``MOMENT_RTOL``).  Each run's parameters after step 1
+    must be the AdamW update of its own moments, which checks the ZeRO-1
+    all-gather that routes the updates back.  The two runs' updated
+    parameters are not compared: an early Adam update is about +-lr
+    whatever the gradient's size, so where a reduced gradient is within
+    rounding of zero its sign is a coin toss."""
     n = len(devices)
     mesh = _mesh(devices, (n, 1), ("data", "model"))
     batch = batch_per_chip * n
@@ -370,9 +496,18 @@ def dp_phase(devices, cfg: ModelConfig, *, layers: int, seq: int = 2048,
     data = SyntheticTokens(DataConfig(vocab_size=model.cfg.vocab_size,
                                       seq_len=seq, global_batch=batch,
                                       seed=seed), model_cfg=cut)
+    t0 = time.time()
+    sizes = build_comm(mesh, arch_cfg).bucketer.plan(
+        model.abstract_params()).bucket_sizes
+    transport_err = transport_mismatch(mesh, arch_cfg, sizes, seed)
+    transport_s = time.time() - t0
+    log(f"[dp] {arch_cfg.comm.transport} vs psum on {len(set(sizes))} "
+        f"bucket sizes: {transport_err:.3g} eps32 of sum|x| "
+        f"({transport_s:.1f} s)")
     runs = {}
     # the replicated reference first: it has the larger peak, and takes the
-    # chips before anything of the other run is held
+    # chips before anything of the other run is held.  What is kept from
+    # it waits on the host while the other run holds the chips
     for name, scfg in (("psum", psum_cfg), ("ring_hier", arch_cfg)):
         t0 = time.time()
         tr = Trainer(model, mesh, scfg, data, shape,
@@ -380,61 +515,59 @@ def dp_phase(devices, cfg: ModelConfig, *, layers: int, seq: int = 2048,
                      log=log)
         p0 = jax.device_get(tr.state["params"])
         hist = tr.run()["history"]
-        runs[name] = {"p0": p0, "p1": jax.device_get(tr.state["params"]),
-                      "loss": hist[0]["loss"], "loss1": hist[1]["loss"],
-                      "grad_norms": [h["grad_norm"] for h in hist],
-                      "lr": hist[1]["lr"], "s": time.time() - t0,
-                      "dp_mode": scfg.dp_mode}
+        s = time.time() - t0
+        p1, mu, nu = (tr.state["params"], tr.state["opt"]["mu"],
+                      tr.state["opt"]["nu"])
         del tr
+        if scfg.dp_mode == "zero1":
+            mu, nu = (zero1_tree(m, model, mesh, scfg) for m in (mu, nu))
+        err = adamw_mismatch(p0, p1, mu, nu, lr=hist[1]["lr"], t=2,
+                             optim=scfg.optim)
+        runs[name] = {"dp_mode": scfg.dp_mode, "s": s, "update_err_lr": err,
+                      "losses": [h["loss"] for h in hist],
+                      "grad_norms": [h["grad_norm"] for h in hist],
+                      "lrs": [h["lr"] for h in hist], "p0": p0,
+                      "mu": jax.device_get(mu) if name == "psum" else mu}
+        del p1, mu, nu
     a, b = runs["ring_hier"], runs["psum"]
-
-    lr = a["lr"]
-    atol = PARAM_ATOL_LR * lr
-    same_init, diff, moved, n_apart, n_elems = True, 0.0, 0.0, 0, 0
-    for a0, a1, b0, b1 in zip(*(jax.tree.leaves(r[k]) for r in (a, b)
-                                for k in ("p0", "p1"))):
-        d = np.abs(a1 - b1)
-        same_init &= bool(np.array_equal(a0, b0))
-        diff = max(diff, float(d.max()))
-        n_apart += int((d > atol).sum())
-        n_elems += d.size
-        moved = max(moved, float(np.abs(a1 - a0).max()),
-                    float(np.abs(b1 - b0).max()))
+    moments = moment_mismatch(a["mu"], b["mu"])
+    same_init = all(np.array_equal(x, y) for x, y in
+                    zip(jax.tree.leaves(a["p0"]), jax.tree.leaves(b["p0"])))
+    n_params = sum(x.size for x in jax.tree.leaves(a["p0"]))
 
     def rel(u, v):
         return abs(u - v) / abs(v)
 
     checks = {
+        "transports_agree": transport_err <= n - 1,
         "same_initial_params": same_init,
-        "finite": _finite([a["loss"], b["loss"], a["loss1"], b["loss1"]]
-                          + a["grad_norms"] + b["grad_norms"]),
+        "finite": _finite(a["losses"] + b["losses"] + a["grad_norms"]
+                          + b["grad_norms"]),
+        "lr0_zero_lr1_positive": a["lrs"][0] == b["lrs"][0] == 0
+        and a["lrs"][1] == b["lrs"][1] > 0,
         # the loss is computed before any reduction: equal up to one fp32
         # ulp (the two programs may fuse the forward differently)
-        "step0_losses_equal": abs(a["loss"] - b["loss"])
-        <= float(np.spacing(np.float32(abs(a["loss"])))),
-        # the reduced gradients: their global norms, and the loss of the
-        # parameters they updated
+        "step0_losses_equal": abs(a["losses"][0] - b["losses"][0])
+        <= float(np.spacing(np.float32(abs(a["losses"][0])))),
         "grad_norms_agree": all(rel(u, v) <= GNORM_RTOL for u, v in
                                 zip(a["grad_norms"], b["grad_norms"])),
-        "step1_losses_agree": rel(a["loss1"], b["loss1"]) <= LOSS1_RTOL,
-        # elementwise: at most FLIP_SHARE of the elements differ by more
-        # than atol, and none by more than two updates (one flipped sign)
-        "params_agree_after_step": n_apart <= FLIP_SHARE * n_elems
-        and diff <= 2 * moved + atol,
+        "moments_agree": moments["l2"] <= MOMENT_RTOL
+        and moments["max"] <= MOMENT_RTOL,
+        "updates_match_adamw": max(a["update_err_lr"], b["update_err_lr"])
+        <= UPDATE_ATOL_LR,
     }
     return {
         "phase": "dp", "arch": cfg.name, "ok": all(checks.values()),
         "checks": checks, "chips": n, "layers_kept": layers,
         "layers_total": cfg.num_layers, "widths": _widths(cfg), "seq": seq,
-        "global_batch": batch,
-        "runs": {k: {kk: v[kk] for kk in ("dp_mode", "loss", "loss1",
-                                          "grad_norms", "lr", "s")}
+        "global_batch": batch, "params": n_params,
+        "bucket_sizes": list(sizes),
+        "transport_err_eps": transport_err, "transport_s": transport_s,
+        "runs": {k: {kk: v[kk] for kk in ("dp_mode", "losses", "grad_norms",
+                                          "lrs", "s", "update_err_lr")}
                  for k, v in runs.items()},
-        "loss_diff": abs(a["loss"] - b["loss"]),
-        "loss1_rel_diff": rel(a["loss1"], b["loss1"]),
-        "param_max_abs_diff": diff, "param_max_update": moved,
-        "param_atol": atol, "params": n_elems,
-        "params_apart": n_apart,
+        "moment_mismatch": moments, "moment_rtol": MOMENT_RTOL,
+        "update_atol_lr": UPDATE_ATOL_LR,
     }
 
 

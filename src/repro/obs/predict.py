@@ -22,19 +22,17 @@ from repro.launch.roofline import Roofline, collective_wire_bytes
 
 def predict_step_time(step_fn, example_args, *, mesh,
                       overlap_fraction: float = 0.0,
-                      latency: LatencyModel | None = None,
-                      device_kind: str | None = None) -> dict:
+                      latency: LatencyModel | None = None) -> dict:
     """AOT-lower ``step_fn(*example_args)`` and price it.
 
     Returns the roofline terms plus ``t_step_s`` (the overlap-honest bound
     the drift detector compares measured steps against).  The peaks are
-    those of ``device_kind`` (default: the mesh's devices) from
-    :data:`repro.comm.plan.DEVICE_PEAKS`; a device without published
-    peaks raises.  ``latency`` replaces the α/β constants with measured
+    those of the mesh's devices from :data:`repro.comm.plan.DEVICE_PEAKS`;
+    a device without published peaks raises.  ``latency`` replaces the α/β constants with measured
     ones (a tuning-DB record); ``overlap_fraction`` is the executed
     CommSchedule's.
     """
-    kind = device_kind or mesh.devices.flat[0].device_kind
+    kind = mesh.devices.flat[0].device_kind
     peaks = device_peaks(kind)
     with mesh:
         lowered = step_fn.lower(*example_args)

@@ -116,6 +116,59 @@ def test_cross_chip_phases_on_host_devices():
     assert dp["ok"], dp["checks"]
     assert dp["runs"]["ring_hier"]["dp_mode"] == "zero1"
     assert dp["runs"]["psum"]["dp_mode"] == "replicated"
-    assert dp["runs"]["ring_hier"]["lr"] > 0
+    assert dp["checks"]["lr0_zero_lr1_positive"]
     assert halo["ok"], halo["checks"]
     assert halo["decomposition"] == "2x2x1"
+
+
+def _swap_segments(bucketer, plan, tree, bucket, world=4):
+    """``tree`` with ranks 1 and 2's segments of one bucket exchanged: a
+    collective that delivers a reduce-scatter or all-gather segment to the
+    wrong rank."""
+    import jax
+    import numpy as np
+
+    flat = [np.array(b) for b in bucketer.bucketize(tree, plan)[0]]
+    seg = flat[bucket].size // world
+    b = flat[bucket]
+    b[seg:2 * seg], b[2 * seg:3 * seg] = (b[2 * seg:3 * seg].copy(),
+                                          b[seg:2 * seg].copy())
+    return jax.device_get(bucketer.debucketize(flat, plan))
+
+
+@pytest.mark.parametrize("bucket", [0, -1])
+def test_dp_checks_catch_a_misrouted_bucket_segment(bucket):
+    import jax
+    import numpy as np
+
+    from repro.core.bucketing import GradientBucketer
+    from repro.models import build_model
+    from repro.optim import OptimConfig
+
+    model = build_model(reduced_config("llama3.2-1b"))
+    p0 = jax.device_get(model.init(jax.random.key(0)))
+    bucketer = GradientBucketer(bucket_bytes=64 * 2**10)
+    plan = bucketer.plan(p0)
+    assert plan.n_buckets > 2
+    rng = np.random.default_rng(0)
+    mu = jax.tree.map(
+        lambda p: 1e-3 * rng.standard_normal(p.shape).astype(np.float32), p0)
+    nu = jax.tree.map(lambda m: 0.5 * m * m, mu)
+    optim, lr = OptimConfig(), 3e-4
+    f32 = np.float32
+    c1, c2 = f32(1 - optim.b1 ** 2), f32(1 - optim.b2 ** 2)
+    decay = f32(1) - f32(lr) * f32(optim.weight_decay)
+    p1 = jax.tree.map(lambda p, m, v: p * decay - f32(lr) * (
+        (m / c1) / (np.sqrt(v / c2) + f32(optim.eps))), p0, mu, nu)
+
+    assert chip_smoke.moment_mismatch(mu, mu)["l2"] == 0
+    assert chip_smoke.adamw_mismatch(p0, p1, mu, nu, lr=lr, t=2,
+                                     optim=optim) <= chip_smoke.UPDATE_ATOL_LR
+    # reduce-scatter side: the moments of a misrouted segment
+    bad = chip_smoke.moment_mismatch(
+        _swap_segments(bucketer, plan, mu, bucket), mu)
+    assert bad["l2"] > 0.1 and bad["max"] > 0.1
+    # all-gather side: the updates of a misrouted segment
+    assert chip_smoke.adamw_mismatch(
+        p0, _swap_segments(bucketer, plan, p1, bucket), mu, nu, lr=lr, t=2,
+        optim=optim) > 1e2 * chip_smoke.UPDATE_ATOL_LR

@@ -286,21 +286,23 @@ def test_obs_off_lowers_to_identical_hlo(tmp_path):
     assert texts[0] == texts[1] == texts[2]
 
 
-def test_predict_step_time_prices_the_live_step():
+def test_predict_step_time_prices_the_live_step(monkeypatch):
+    from repro.comm import plan
     from repro.obs.predict import predict_step_time
     from repro.runtime.train_step import build_step_schedule
 
     tr = _tiny(2, None)
     sched = build_step_schedule(tr.model, tr.mesh, tr.step_cfg)
-    # the CPU has no published peaks: price the program for a v5e chip
+    # the CPU has no published peaks: price it as a v5e for this test
+    kind = tr.mesh.devices.flat[0].device_kind
+    monkeypatch.setitem(plan.DEVICE_PEAKS, kind, plan.V5E)
     pred = predict_step_time(tr.step_fn, (tr.state, tr.data.batch_at(0)),
                              mesh=tr.mesh,
-                             overlap_fraction=sched.overlap_fraction,
-                             device_kind="TPU v5 lite")
+                             overlap_fraction=sched.overlap_fraction)
     assert pred["t_step_s"] > 0 and pred["source"] == "roofline"
     assert pred["bottleneck"] in ("compute", "memory", "collective")
     assert pred["t_step_s"] >= pred["t_exposed_collective_s"]
-    assert pred["device_kind"] == "TPU v5 lite"
+    assert pred["device_kind"] == kind
 
 
 def test_predict_step_time_raises_for_a_device_without_peaks():
