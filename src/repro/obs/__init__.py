@@ -6,25 +6,30 @@ this package *measures* live runs through one spine —
 
 * :class:`~repro.obs.bus.MetricsBus` — counters/gauges/histograms with
   labels, JSONL sink (``events.jsonl``);
-* :class:`~repro.obs.trace.Tracer` — host wall-clock phase spans with
-  optional ``block_until_ready`` fencing, exported as Chrome
-  ``trace_event`` JSON (Perfetto-loadable ``trace.json``);
+* :class:`~repro.obs.trace.Tracer` — host phase spans with optional
+  ``block_until_ready`` fencing; each also enters the ``jax.profiler``
+  trace as ``repro.<name>`` (on the device's clock) and is exported as
+  Chrome ``trace_event`` JSON (Perfetto-loadable ``trace.json``);
+* the ``compiles`` counter — every executable the process obtains while
+  an :class:`Obs` is live (a backend compile or a persistent-cache load);
 * :class:`~repro.obs.drift.DriftDetector` — per-step measured-vs-predicted
   comparison emitting ``model_error`` gauges and ``drift_alarm`` events;
 * :mod:`repro.obs.schema` — the shared ``BENCH_<name>.json`` row schema;
 * ``python -m repro.obs.report <run_dir>`` — the offline summarizer.
 
-Everything importable here is stdlib-only (jax is touched lazily, inside
-span fencing and the ``repro.obs.predict`` bridge), so the report CLI and
-the bench harness stay light.  ``ObsConfig(enabled=False)`` — or simply a
-``None`` config — resolves to :data:`NULL_OBS`, whose every operation is a
-no-op: an uninstrumented step and an obs-disabled step lower to the
-identical HLO (pinned in ``tests/test_obs.py``).
+Everything importable here is stdlib-only (jax is touched lazily: in
+spans, in the compile listener an :class:`Obs` registers, and in the
+``repro.obs.predict`` bridge), so the report CLI stays light.
+``ObsConfig(enabled=False)`` — or simply a ``None`` config — resolves to
+:data:`NULL_OBS`, whose every operation is a no-op: an uninstrumented step
+and an obs-disabled step lower to the identical HLO (pinned in
+``tests/test_obs.py``).
 """
 
 from __future__ import annotations
 
 import os
+import weakref
 from dataclasses import dataclass
 
 from repro.obs.bus import MetricsBus, NULL_BUS
@@ -79,6 +84,7 @@ class Obs:
         self.cfg = cfg
         self.bus = MetricsBus(cfg.run_dir, flush_every=cfg.flush_every)
         self.tracer = Tracer(self.bus, enabled=cfg.trace)
+        _count_compiles(self)
 
     # -- delegates -----------------------------------------------------------
 
@@ -115,6 +121,7 @@ class Obs:
     def finish(self) -> dict:
         """Flush the sink and (when a run_dir is bound) export the Chrome
         trace; returns the artifact paths."""
+        _COUNTING.discard(self)
         trace_path = None
         if (self.cfg.run_dir is not None and self.tracer.enabled
                 and self.tracer.events):
@@ -122,6 +129,30 @@ class Obs:
                 os.path.join(self.cfg.run_dir, "trace.json"))
         self.bus.close()
         return {"events": self.bus.path, "trace": trace_path}
+
+
+# Every live Obs counts the executables the process obtains.  JAX times
+# ``compile_or_get_cached`` under this event, so it fires once per backend
+# compile and once per persistent-cache load, never on an in-memory hit.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COUNTING: "weakref.WeakSet[Obs]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        for obs in list(_COUNTING):
+            obs.bus.counter("compiles", fun=kw.get("fun_name", ""))
+
+
+def _count_compiles(obs: "Obs") -> None:
+    global _listening
+    _COUNTING.add(obs)
+    if not _listening:
+        import jax.monitoring  # lazy: the package stays jax-free
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
 
 
 class _NullObs:

@@ -1,13 +1,18 @@
-"""Phase-span tracing: host wall-clock spans exportable as Chrome trace JSON.
+"""Phase-span tracing: host spans on the profiler's clock, also kept as
+wall-clock durations exportable as Chrome trace JSON.
 
 A :class:`Span` brackets one phase of a step (data, dispatch, collective
-wait, checkpoint, decode...) with ``time.perf_counter`` stamps.  Because
+wait, checkpoint, decode...).  It opens a ``jax.profiler.TraceAnnotation``
+named ``repro.<name>`` with its labels as arguments, so under
+``jax.profiler.start_trace`` the span lands on the ``/host:CPU`` plane of
+the trace, on the clock of the device's ``XLA Ops`` lines, nested in any
+enclosing annotation; it also takes ``time.perf_counter`` stamps.  Because
 jax dispatch is asynchronous, a span that should account for *device* work
 must fence: ``sp.fence(tree)`` registers a pytree that the span
 ``jax.block_until_ready``-s on exit, so the recorded duration covers the
 device execution the phase launched, not just the Python that enqueued it.
 
-Spans export two ways:
+Besides the profiler trace, spans export two ways:
 
 * mirrored onto the :class:`~repro.obs.bus.MetricsBus` as ``span`` JSONL
   records (what the report CLI aggregates), and
@@ -15,8 +20,9 @@ Spans export two ways:
   timestamps) via :meth:`Tracer.export_chrome` — the resulting
   ``trace.json`` loads directly in Perfetto / ``chrome://tracing``.
 
-The disabled tracer hands out a shared no-op span: no clock reads, no
-allocation, no fencing — the opt-out leaves the step loop untouched.
+The disabled tracer hands out a shared no-op span: no annotation, no clock
+reads, no allocation, no fencing — the opt-out leaves the step loop
+untouched.
 """
 
 from __future__ import annotations
@@ -27,17 +33,21 @@ import time
 
 from repro.obs.bus import NULL_BUS, _jsonable
 
+PROFILER_PREFIX = "repro."   # a span's name in the profiler trace
+
 
 class Span:
     """One phase; use as a context manager (see :meth:`Tracer.span`)."""
 
-    __slots__ = ("_tracer", "name", "labels", "_fence", "t0", "dur_s")
+    __slots__ = ("_tracer", "name", "labels", "_fence", "_note", "t0",
+                 "dur_s")
 
     def __init__(self, tracer: "Tracer", name: str, labels: dict):
         self._tracer = tracer
         self.name = name
         self.labels = labels
         self._fence = None
+        self._note = None
         self.t0 = None
         self.dur_s = None
 
@@ -49,6 +59,11 @@ class Span:
         return tree
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation  # lazy, as in the fence
+
+        self._note = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                     **self.labels)
+        self._note.__enter__()
         self.t0 = self._tracer._clock()
         return self
 
@@ -59,6 +74,8 @@ class Span:
             jax.block_until_ready(self._fence)
             self._fence = None
         self.dur_s = self._tracer._clock() - self.t0
+        self._note.__exit__(exc_type, exc, tb)
+        self._note = None
         self._tracer._record(self.name, self.t0, self.dur_s, self.labels)
         return False
 

@@ -25,15 +25,24 @@ KV cache.  The design commitments, in paper terms:
 
 Admission, eviction and page recycling are host-side (numpy) and change no
 traced shape, so the step compiles once per ``(plan, arch)``.
+
+Each part of the step runs under a ``jax.named_scope`` of
+:data:`STEP_SCOPES` (no layer index), which lands in the compiled
+instructions' ``op_name`` metadata; :meth:`PagedDecodeEngine.op_scopes`
+maps the instruction names a profiler trace shows to those scopes.  The
+host side runs under the ``serve.*`` spans of its ``obs``.
 """
 
 from __future__ import annotations
+
+import collections
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comm import CommConfig, Communicator
 from repro.obs import NULL_OBS
@@ -72,6 +81,80 @@ def predicted_wire_bytes_per_token(plan: KVArenaPlan, cfg: ModelConfig,
     hops = 2.0 * (r - 1) / r
     per_layer = (batch * hq + batch * hq * (plan.head_dim + 1)) * 4
     return plan.n_layers * per_layer * hops
+
+
+# The named scopes of the decode step, in the order a layer runs them.
+# ``attn_merge`` exists only with a model axis of more than one rank.
+STEP_SCOPES = ("embed", "qkv_proj", "kv_write", "kv_gather", "gqa_expand",
+               "flash_decode", "attn_merge", "o_proj", "mlp", "lm_head")
+_HEADER = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_REF = re.compile(r"%([\w.\-]+)")
+
+
+def _named_scope(op_name: str) -> str | None:
+    parts = [p for p in op_name.split("/") if p in STEP_SCOPES]
+    return parts[-1] if parts else None
+
+
+def instruction_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: scope}`` of a compiled module's text.
+
+    An instruction's scope is the innermost :data:`STEP_SCOPES` name among
+    the ``/``-separated parts of its ``op_name``.  The compiler makes some
+    instructions with no such name (fusions of a gather's pieces, layout
+    copies, prefetches of weights); each of these takes, in this order,
+    the scope most of its fused instructions carry, the scope of its
+    nearest operand that has one, or that of its nearest user."""
+    own: dict[str, str | None] = {}
+    refs: dict[str, list[str]] = {}
+    calls: dict[str, str] = {}
+    by_comp: dict[str, list[str]] = collections.defaultdict(list)
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line) if " = " in line else None
+        if m is None:
+            h = _HEADER.match(line)
+            comp = h.group(1) if h else comp
+            continue
+        name, rest = m.groups()
+        body = rest.split(", metadata=", 1)[0]
+        op = _OP_NAME.search(rest)
+        own[name] = _named_scope(op.group(1)) if op else None
+        refs[name] = _REF.findall(body)
+        c = _CALLS.search(body)
+        if c:
+            calls[name] = c.group(1)
+        if own[name]:
+            by_comp[comp].append(own[name])
+    operands = {n: [r for r in rs if r in own] for n, rs in refs.items()}
+    users: dict[str, list[str]] = collections.defaultdict(list)
+    for n, rs in operands.items():
+        for r in rs:
+            users[r].append(n)
+
+    def nearest(start, edges):
+        seen, queue = {start}, collections.deque(edges.get(start, ()))
+        while queue:
+            n = queue.popleft()
+            if n in seen:
+                continue
+            if own[n]:
+                return own[n]
+            seen.add(n)
+            queue.extend(edges.get(n, ()))
+        return None
+
+    out = {}
+    for n, sc in own.items():
+        if sc is None and by_comp.get(calls.get(n)):
+            sc = collections.Counter(by_comp[calls[n]]).most_common(1)[0][0]
+        sc = sc or nearest(n, operands) or nearest(n, users)
+        if sc:
+            out[n] = sc
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -179,64 +262,79 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
     true_group = max(cfg.attn.num_heads // hkv, 1)
 
     def attend(q, pages, layer, table, slot_len, slot_valid):
-        k, v, tab = _gather_local_kv(pages, plan, layer, table,
-                                     ctx.model_index())
-        # true-group GQA map (padded q heads clip to the last kv head) —
-        # expand kv per q head so the kernel runs group-free; the uniform
-        # h//group map inside the kernel would mis-pair padded head counts.
-        kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0, hkv - 1)
-        k = jnp.take(k, kv_idx, axis=1)
-        v = jnp.take(v, kv_idx, axis=1)
-        valid = _local_valid(plan, tab, slot_len, slot_valid,
-                             ctx.model_index())
-        if attn_impl == "kernel":
-            acc, m, l = fd_ops.flash_decode_stats(q, k, v, valid,
-                                                  interpret=interpret)
-        else:
-            acc, m, l = fd_ref.decode_stats(q, k, v, valid)
-        if r == 1:
-            return fd_ref.combine([(acc, m, l)]).astype(q.dtype)
-        m_g = ctx.pmax(m)
-        w = jnp.exp(m - m_g)
-        n_num = acc.size
-        buf = jnp.concatenate([(acc * w).reshape(-1), (l * w).reshape(-1)])
-        red = comm.all_reduce([buf])[0]
-        num = red[:n_num].reshape(acc.shape)
-        den = red[n_num:].reshape(l.shape)
-        return (num / jnp.maximum(den, 1e-30)).astype(q.dtype)
+        with jax.named_scope("kv_gather"):
+            k, v, tab = _gather_local_kv(pages, plan, layer, table,
+                                         ctx.model_index())
+            valid = _local_valid(plan, tab, slot_len, slot_valid,
+                                 ctx.model_index())
+        with jax.named_scope("gqa_expand"):
+            # true-group GQA map (padded q heads clip to the last kv head)
+            # — expand kv per q head so the kernel runs group-free; the
+            # uniform h//group map inside the kernel would mis-pair padded
+            # head counts.
+            kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0,
+                              hkv - 1)
+            k = jnp.take(k, kv_idx, axis=1)
+            v = jnp.take(v, kv_idx, axis=1)
+        with jax.named_scope("flash_decode"):
+            if attn_impl == "kernel":
+                acc, m, l = fd_ops.flash_decode_stats(q, k, v, valid,
+                                                      interpret=interpret)
+            else:
+                acc, m, l = fd_ref.decode_stats(q, k, v, valid)
+            if r == 1:
+                return fd_ref.combine([(acc, m, l)]).astype(q.dtype)
+        with jax.named_scope("attn_merge"):
+            m_g = ctx.pmax(m)
+            w = jnp.exp(m - m_g)
+            n_num = acc.size
+            buf = jnp.concatenate([(acc * w).reshape(-1),
+                                   (l * w).reshape(-1)])
+            red = comm.all_reduce([buf])[0]
+            num = red[:n_num].reshape(acc.shape)
+            den = red[n_num:].reshape(l.shape)
+            return (num / jnp.maximum(den, 1e-30)).astype(q.dtype)
 
     def fn(pages, params, table, token, slot_len, slot_valid):
-        x = embed(params["embed"], token[:, None], cdt, ctx, cfg.vocab_size)
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], token[:, None], cdt, ctx,
+                      cfg.vocab_size)
         posb = slot_len[:, None]                       # per-slot position
         for i, bp in enumerate(params["blocks"]):
             kind = cfg.layer_kind(i)
-            h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
             pa = bp["attn"]
-            n_hq = pa["wq"]["w"].shape[1] // hd
-            q = _split_heads(dense(pa["wq"], h, cdt), n_hq)
-            k1 = _split_heads(dense(pa["wk"], h, cdt), hkv)
-            v1 = _split_heads(dense(pa["wv"], h, cdt), hkv)
-            q = apply_rope(q, posb, cfg.attn.rope_theta)
-            k1 = apply_rope(k1, posb, cfg.attn.rope_theta)
-            pages = _write_token_kv(pages, plan, i, table, slot_len,
-                                    slot_valid, k1, v1)
+            with jax.named_scope("qkv_proj"):
+                h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
+                n_hq = pa["wq"]["w"].shape[1] // hd
+                q = _split_heads(dense(pa["wq"], h, cdt), n_hq)
+                k1 = _split_heads(dense(pa["wk"], h, cdt), hkv)
+                v1 = _split_heads(dense(pa["wv"], h, cdt), hkv)
+                q = apply_rope(q, posb, cfg.attn.rope_theta)
+                k1 = apply_rope(k1, posb, cfg.attn.rope_theta)
+            with jax.named_scope("kv_write"):
+                pages = _write_token_kv(pages, plan, i, table, slot_len,
+                                        slot_valid, k1, v1)
             o = attend(q, pages, i, table, slot_len, slot_valid)
-            x = x + dense(pa["wo"], _merge_heads(o), cdt).astype(x.dtype)
+            with jax.named_scope("o_proj"):
+                x = x + dense(pa["wo"], _merge_heads(o), cdt).astype(x.dtype)
             if "moe" in bp or "mlp" in bp:
-                h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
-                if kind["mlp"] == "moe":
-                    y, _, _ = moe_mod.moe_apply(bp["moe"], h2, cfg.moe,
-                                                cfg.act, ctx=ctx,
-                                                compute_dtype=cdt)
-                else:
-                    y = glu_mlp(bp["mlp"], h2, cfg.act, cdt, ctx, cfg.d_ff)
-                x = x + y.astype(x.dtype)
-        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = unembed(params["embed"], x, cdt)
-        else:
-            logits = dense(params["lm_head"], x, cdt)
-        return logits[:, 0], pages
+                with jax.named_scope("mlp"):
+                    h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
+                    if kind["mlp"] == "moe":
+                        y, _, _ = moe_mod.moe_apply(bp["moe"], h2, cfg.moe,
+                                                    cfg.act, ctx=ctx,
+                                                    compute_dtype=cdt)
+                    else:
+                        y = glu_mlp(bp["mlp"], h2, cfg.act, cdt, ctx,
+                                    cfg.d_ff)
+                    x = x + y.astype(x.dtype)
+        with jax.named_scope("lm_head"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = unembed(params["embed"], x, cdt)
+            else:
+                logits = dense(params["lm_head"], x, cdt)
+            return logits[:, 0], pages
 
     state_abs = {
         "pages": jax.ShapeDtypeStruct((plan.total_elems,), plan.layout.dtype),
@@ -262,6 +360,12 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
 # ---------------------------------------------------------------------------
 
 
+def _abstract(a):
+    """A step argument's shape, dtype and placement (as lowering sees it)."""
+    sharding = a.sharding if getattr(a, "committed", False) else None
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+
 class PagedDecodeEngine:
     """Slot-indexed decode over the page arena.
 
@@ -282,7 +386,13 @@ class PagedDecodeEngine:
         self.table = PageTable(plan.max_seqs, plan.max_blocks, plan.n_layers)
         self.slot_len = np.zeros((plan.max_seqs,), np.int32)
         self.slot_valid = np.zeros((plan.max_seqs,), bool)
-        self.pages = plan.zeros()
+        # placed as the step returns it, so the first step and every later
+        # one (fed the donated output) run one executable
+        self.pages = jnp.zeros(
+            (plan.total_elems,), plan.layout.dtype,
+            device=NamedSharding(mesh, self.state_specs["pages"]))
+        self.steps = 0
+        self._args = None     # the step's abstract arguments, for op_scopes
 
     # -- slot management (host side) ----------------------------------------
 
@@ -302,38 +412,39 @@ class PagedDecodeEngine:
     def admit(self, slot: int) -> None:
         if self.slot_valid[slot]:
             raise ValueError(f"slot {slot} is already live")
-        self.slot_len[slot] = 0
-        self.slot_valid[slot] = True
-        self._ensure_block(slot)
-        self.obs.counter("admits")
-        self.obs.event("admit", slot=slot,
-                       pages_free=self.allocator.n_free)
-        self._kv_gauges()
+        with self.obs.span("serve.admit", slot=slot):
+            self.slot_len[slot] = 0
+            self.slot_valid[slot] = True
+            self._ensure_block(slot)
+            self.obs.counter("admits")
+            self.obs.event("admit", slot=slot,
+                           pages_free=self.allocator.n_free)
+            self._kv_gauges()
 
     def retire(self, slot: int) -> None:
-        tokens = int(self.slot_len[slot])
-        self.allocator.free(self.table.clear_slot(slot))
-        self.slot_valid[slot] = False
-        self.slot_len[slot] = 0
-        self.obs.counter("retires")
-        self.obs.event("retire", slot=slot, tokens=tokens,
-                       pages_free=self.allocator.n_free)
-        self._kv_gauges()
+        with self.obs.span("serve.retire", slot=slot):
+            tokens = int(self.slot_len[slot])
+            self.allocator.free(self.table.clear_slot(slot))
+            self.slot_valid[slot] = False
+            self.slot_len[slot] = 0
+            self.obs.counter("retires")
+            self.obs.event("retire", slot=slot, tokens=tokens,
+                           pages_free=self.allocator.n_free)
+            self._kv_gauges()
 
     def _kv_gauges(self) -> None:
         """Arena health after a slot transition: page occupancy (fraction of
         arena pages mapped) and page waste (fraction of mapped capacity not
         yet holding a token — the partial last page of every live slot)."""
+        if not self.obs.enabled:
+            return
         alloc, plan = self.allocator, self.plan
         used = alloc.n_total - alloc.n_free
-        self.obs.gauge("kv_pages_used", used)
-        self.obs.gauge("kv_pages_free", alloc.n_free)
         self.obs.gauge("kv_page_occupancy", used / max(alloc.n_total, 1))
         cap_tokens = (used // plan.n_layers) * plan.page_tokens
         held = int(self.slot_len[self.slot_valid].sum())
         waste = 1.0 - held / cap_tokens if cap_tokens else 0.0
         self.obs.gauge("kv_page_waste", waste)
-        self.obs.gauge("live_slots", int(self.slot_valid.sum()))
 
     def _ensure_block(self, slot: int) -> None:
         blk = int(self.slot_len[slot]) // self.plan.page_tokens
@@ -347,16 +458,38 @@ class PagedDecodeEngine:
         """One decode step: write ``token[slot]`` at each live slot's
         position, attend over its pages, return logits (B, vocab).
         Invalid slots' rows are garbage by contract."""
-        for s in np.nonzero(self.slot_valid)[0]:
-            self._ensure_block(int(s))
-        # the step runs asynchronously while the host goes on mutating its
-        # slot arrays, and a host-to-device transfer may alias a numpy
-        # buffer (zero-copy on the CPU): hand the step private copies
-        with self.mesh:
-            logits, self.pages = self.step(
-                self.pages, params, jnp.asarray(self.table.table.copy()),
-                jnp.asarray(token, jnp.int32).reshape(self.plan.max_seqs),
-                jnp.asarray(self.slot_len.copy()),
-                jnp.asarray(self.slot_valid.copy()))
-        self.slot_len[self.slot_valid] += 1
+        obs = self.obs
+        with obs.span("serve.decode", step=self.steps):
+            with obs.span("serve.pages"):
+                for s in np.nonzero(self.slot_valid)[0]:
+                    self._ensure_block(int(s))
+            # the step runs asynchronously while the host goes on mutating
+            # its slot arrays, and a host-to-device transfer may alias a
+            # numpy buffer (zero-copy on the CPU): hand the step copies
+            with obs.span("serve.stage"):
+                args = (self.pages, params,
+                        jnp.asarray(self.table.table.copy()),
+                        jnp.asarray(token, jnp.int32)
+                        .reshape(self.plan.max_seqs),
+                        jnp.asarray(self.slot_len.copy()),
+                        jnp.asarray(self.slot_valid.copy()))
+                if self._args is None:
+                    self._args = jax.tree.map(_abstract, args)
+            with obs.span("serve.dispatch"), self.mesh:
+                logits, self.pages = self.step(*args)
+            self.slot_len[self.slot_valid] += 1
+        self.steps += 1
         return logits
+
+    def op_scopes(self) -> dict[str, str]:
+        """``{instruction name: scope}`` of the compiled decode step (see
+        :func:`instruction_scopes`): the names a profiler trace gives the
+        step's device operations, mapped to :data:`STEP_SCOPES`.  Lowers
+        the step with the arguments of the first :meth:`decode`, so it
+        reads the executable that step ran (from the in-memory or the
+        persistent compile cache)."""
+        if self._args is None:
+            raise RuntimeError("op_scopes needs one decode step first")
+        with self.mesh:
+            text = self.step.lower(*self._args).compile().as_text()
+        return instruction_scopes(text)

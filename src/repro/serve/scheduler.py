@@ -151,8 +151,7 @@ class ServeScheduler:
             for sl in live:
                 r = slot_req[sl]
                 token[sl] = request_token(r.rid, int(fed[sl]), vocab)
-            with obs.span("decode_step", policy=self.policy):
-                logits = eng.decode(params, token)
+            logits = eng.decode(params, token)
             steps += 1
             live_sum += int(live.size)
             for sl in live:

@@ -42,3 +42,27 @@ def run_distributed(script: str, n_devices: int = 8, timeout: int = 560,
             f"distributed script failed (rc={proc.returncode}):\n"
             f"--- stdout ---\n{proc.stdout}\n--- stderr ---\n{proc.stderr[-4000:]}")
     return proc.stdout
+
+
+def host_trace_events(fn, trace_dir) -> list:
+    """Run ``fn()`` under ``jax.profiler`` and return the events of the
+    trace's ``/host:CPU`` plane as ``(name, start_ns, end_ns, stats)``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)) for e in line.events]
+    return out

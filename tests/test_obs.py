@@ -121,6 +121,54 @@ def test_span_fence_blocks_on_device_work():
     assert bus.spans["wait"][0] > 0
 
 
+def test_span_enters_the_profiler_trace_as_repro_name(tmp_path):
+    """Under ``jax.profiler`` a span is a ``repro.<name>`` event on the
+    host plane (the device ops' clock), nested in the annotation around
+    it and carrying its labels; NULL_OBS emits nothing."""
+    from conftest import host_trace_events
+
+    obs = make_obs(ObsConfig(run_dir=None))
+
+    def run():
+        with jax.profiler.TraceAnnotation("outer"):
+            with obs.span("serve.stage", step=3, slot="a"):
+                with NULL_OBS.span("silent", step=4):
+                    pass
+
+    evs = host_trace_events(run, tmp_path / "trace")
+    (outer,) = [e for e in evs if e[0] == "outer"]
+    (stage,) = [e for e in evs if e[0] == "repro.serve.stage"]
+    assert outer[1] <= stage[1] < stage[2] <= outer[2]
+    assert stage[3]["step"] == 3 and stage[3]["slot"] == "a"
+    assert not [e for e in evs if "silent" in e[0]]
+    # the bus mirror stays, under the span's own name
+    assert len(obs.bus.spans["serve.stage"]) == 1
+
+
+def test_obs_counts_each_new_executable():
+    """``compiles`` counts every executable obtained while the Obs is
+    live: a new program counts once, a cached call never, and nothing
+    counts after ``finish`` or under NULL_OBS."""
+    import jax.numpy as jnp
+
+    obs = make_obs(ObsConfig(run_dir=None))
+    f = jax.jit(lambda x: jnp.cos(x) * 3.0 + 1.0)
+    x = jnp.arange(5.0)
+    f(x).block_until_ready()
+    n = obs.bus.counter_value("compiles", fun="jit(<lambda>)")
+    assert n == 1
+    f(x).block_until_ready()
+    f.lower(x).compile()                       # the same executable
+    assert obs.bus.counter_value("compiles", fun="jit(<lambda>)") == n
+    f(jnp.arange(6.0)).block_until_ready()     # a new shape compiles
+    assert obs.bus.counter_value("compiles", fun="jit(<lambda>)") == n + 1
+    total = obs.bus.counter_total("compiles")
+    obs.finish()
+    jax.jit(lambda x: x - 2.0)(x).block_until_ready()
+    assert obs.bus.counter_total("compiles") == total
+    assert NULL_OBS.bus.counter_total("compiles") == 0.0
+
+
 # ---------------------------------------------------------------------------
 # drift detection
 # ---------------------------------------------------------------------------

@@ -284,6 +284,123 @@ def test_engine_slot_lifecycle_and_page_recycling(rng):
     assert eng.can_admit(16)
 
 
+# ---------------------------------------------------------------------------
+# named scopes and host spans of the engine
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("attn_impl", ["ref", "kernel"])
+def test_op_scopes_name_every_part_of_the_step(attn_impl):
+    """``op_scopes`` maps the compiled step's instructions to every scope
+    a single-rank step runs, and the kernel's ops (in interpret mode on
+    the CPU, the loop it lowers to) to ``flash_decode``."""
+    import re
+
+    from repro.obs import ObsConfig, make_obs
+    from repro.serve.engine import STEP_SCOPES
+
+    _, params, eng = _engine(attn_impl=attn_impl)
+    with pytest.raises(RuntimeError, match="decode step first"):
+        eng.op_scopes()
+    eng.obs = make_obs(ObsConfig(run_dir=None))
+    eng.admit(0)
+    eng.decode(params, np.zeros((4,), np.int32))
+    ran = eng.obs.bus.counter_total("compiles")
+    scopes = eng.op_scopes()
+    assert set(scopes.values()) == set(STEP_SCOPES) - {"attn_merge"}
+    # the map reads the executable the step ran: nothing compiled again
+    assert eng.obs.bus.counter_total("compiles") == ran
+    text = eng.step.lower(*eng._args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    loops = re.findall(r"%(while[\w.\-]*) = ", entry)
+    kernel = {scopes[w] for w in loops}
+    assert kernel == ({"flash_decode"} if attn_impl == "kernel" else set())
+
+
+def test_instruction_scopes_follow_data_to_compiler_made_instructions():
+    """An instruction with no scoped ``op_name`` takes its fused
+    instructions' scope, else its nearest operand's, else its nearest
+    user's; the innermost named part wins."""
+    from repro.serve.engine import instruction_scopes
+
+    hlo = """HloModule m, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
+
+%fused_computation.1 (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %negate.1 = f32[8]{0} negate(%param_0), metadata={op_name="jit(fn)/gqa_expand/jit(_take)/neg"}
+}
+
+ENTRY %main.9 (w.1: f32[8]) -> f32[8] {
+  %w.1 = f32[8]{0} parameter(0), metadata={op_name="params['w']"}
+  %copy-start.2 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%w.1)
+  %copy-done.2 = f32[8]{0} copy-done(%copy-start.2)
+  %dot.3 = f32[8]{0} multiply(%copy-done.2, %copy-done.2), metadata={op_name="jit(fn)/qkv_proj/dot_general"}
+  %reshape.4 = f32[8]{0} reshape(%dot.3), metadata={op_name="jit(fn)/kv_gather/mlp/reshape"}
+  %dus_fusion.5 = f32[8]{0} fusion(%reshape.4), kind=kLoop, calls=%fused_computation.1
+  %copy.6 = f32[8]{0} copy(%reshape.4)
+  ROOT %add.7 = f32[8]{0} add(%copy.6, %dus_fusion.5), metadata={op_name="jit(fn)/add"}
+}
+"""
+    got = instruction_scopes(hlo)
+    assert got["dot.3"] == "qkv_proj"
+    assert got["reshape.4"] == "mlp"              # the innermost part
+    assert got["dus_fusion.5"] == "gqa_expand"    # its fused instructions
+    assert got["copy.6"] == "mlp"                 # its operand
+    assert got["copy-done.2"] == "qkv_proj"       # its user
+    assert got["add.7"] == "mlp"                  # unscoped op_name
+    assert "main.9" not in got and "fused_computation.1" not in got
+
+
+def test_engine_host_spans_nest_on_the_profiler_clock(tmp_path):
+    """With an Obs, a decode step is a ``repro.serve.decode`` span holding
+    ``serve.pages``, ``serve.stage`` and ``serve.dispatch`` in turn, its
+    step number a label; admit and retire are spans of their own."""
+    from conftest import host_trace_events
+    from repro.obs import ObsConfig, make_obs
+
+    _, params, eng = _engine()
+    eng.obs = make_obs(ObsConfig(run_dir=None))
+    eng.admit(0)
+    eng.decode(params, np.zeros((4,), np.int32))       # compiled before
+
+    def run():
+        eng.admit(1)
+        eng.decode(params, np.zeros((4,), np.int32))
+        eng.retire(0)
+
+    evs = [e for e in host_trace_events(run, tmp_path / "trace")
+           if e[0].startswith("repro.serve.")]
+    by = {}
+    for e in evs:
+        by.setdefault(e[0][len("repro."):], []).append(e)
+    assert set(by) == {"serve.admit", "serve.decode", "serve.pages",
+                       "serve.stage", "serve.dispatch", "serve.retire"}
+    (dec,) = by["serve.decode"]
+    assert dec[3]["step"] == 1
+    inner = [by[k][0] for k in ("serve.pages", "serve.stage",
+                                "serve.dispatch")]
+    assert dec[1] <= inner[0][1]
+    for a, b in zip(inner, inner[1:]):
+        assert a[2] <= b[1]
+    assert inner[-1][2] <= dec[2]
+    assert by["serve.admit"][0][2] <= dec[1] <= dec[2] <= by["serve.retire"][0][1]
+    assert by["serve.retire"][0][3]["slot"] == 0
+
+
+def test_kv_gauges_are_the_documented_two_and_skipped_without_obs():
+    from repro.obs import ObsConfig, make_obs
+
+    _, params, eng = _engine()
+    eng.allocator, real = None, eng.allocator
+    eng._kv_gauges()                 # NULL_OBS: returns before reading
+    eng.allocator = real
+    eng.obs = make_obs(ObsConfig(run_dir=None))
+    eng.admit(0)
+    eng.retire(0)
+    assert {n for n, _ in eng.obs.bus.gauges} == {"kv_page_occupancy",
+                                                  "kv_page_waste"}
+
+
 def test_decode_state_specs_replicate_paged_state():
     """The paged names must dodge the shape[0]==global_batch fallback —
     otherwise slot_len/page_table get scattered over data ranks."""
@@ -393,6 +510,42 @@ print("SERVE_HLO_R2_OK")
 def test_model_parallel_collective_count_and_equivalence():
     out = run_distributed(SERVE_HLO_R2_SCRIPT, n_devices=2)
     assert "SERVE_HLO_R2_OK" in out
+
+
+SERVE_SCOPES_R2_SCRIPT = r"""
+import re
+import jax
+from jax.sharding import AxisType
+from repro.configs import reduced_config
+from repro.models import build_model
+from repro.serve import PagedDecodeEngine, plan_kv_arena
+from repro.serve.engine import STEP_SCOPES
+import numpy as np
+
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
+model = build_model(reduced_config("llama3.2-1b"))
+plan = plan_kv_arena(model.cfg, mesh, page_tokens=8, page_bytes=4096,
+                     max_seqs=4, max_seq_len=64)
+eng = PagedDecodeEngine(model, mesh, plan, attn_impl="ref")
+eng.admit(0)
+eng.decode(model.init(jax.random.PRNGKey(0)), np.zeros((4,), np.int32))
+scopes = eng.op_scopes()
+assert set(scopes.values()) == set(STEP_SCOPES), set(scopes.values())
+with mesh:
+    txt = eng.step.lower(*eng._args).compile().as_text()
+ars = re.findall(r"%([\w.\-]+) = \S+ all-reduce\(", txt)
+assert len(ars) == 2 * plan.n_layers, ars
+assert {scopes[a] for a in ars} == {"attn_merge"}
+print("SERVE_SCOPES_R2_OK")
+"""
+
+
+def test_model_parallel_merge_runs_under_attn_merge():
+    """With a model axis of two ranks the step runs every scope, and its
+    cross-rank all-reduces map to ``attn_merge``."""
+    out = run_distributed(SERVE_SCOPES_R2_SCRIPT, n_devices=2)
+    assert "SERVE_SCOPES_R2_OK" in out
 
 
 # ---------------------------------------------------------------------------
@@ -565,3 +718,20 @@ def test_scheduler_policies_agree_on_the_real_engine(rng):
         assert out["generated_tokens"] == sum(r.decode_len for r in reqs)
         assert eng.allocator.n_free == eng.allocator.n_total
         assert not eng.slot_valid.any()
+
+
+def test_scheduler_steps_are_the_engine_decode_spans():
+    """The scheduler adds no span of its own around a step: the engine's
+    ``serve.decode`` spans (one per step) and the ``compiles`` counter
+    (one compile for the whole run) are what its obs records."""
+    from repro.obs import ObsConfig, make_obs
+    from repro.serve import ServeScheduler, mixed_trace
+
+    reqs = mixed_trace(groups=1, slots=3, long_len=6, short_len=2)
+    _, params, eng = _engine(max_seqs=3, max_seq_len=16)
+    eng.obs = make_obs(ObsConfig(run_dir=None))
+    out = ServeScheduler(eng).run(params, reqs)
+    spans = eng.obs.bus.spans
+    assert "decode_step" not in spans
+    assert len(spans["serve.decode"]) == out["steps"]
+    assert eng.obs.bus.counter_value("compiles", fun="jit(fn)") == 1

@@ -119,3 +119,46 @@ def test_ring_reduce_of_a_tiled_param_compiles_in_seconds(topo):
     t0 = time.perf_counter()
     fn.lower(arg).compile()
     assert time.perf_counter() - t0 < 60
+
+
+def test_paged_step_kernel_maps_to_the_flash_decode_scope(topo):
+    """The paged decode step compiled for a v5e keeps the kernel, and the
+    kernel's ``tpu_custom_call`` maps to the ``flash_decode`` scope: the
+    name a chip trace gives the kernel finds its scope."""
+    import dataclasses
+    import re
+
+    from jax.sharding import Mesh
+
+    from repro.configs import reduced_config
+    from repro.models import build_model
+    from repro.serve import plan_kv_arena
+    from repro.serve.engine import build_paged_decode_step, instruction_scopes
+
+    base = reduced_config("llama3.2-1b")
+    cfg = base.with_(d_model=256, dtype="bfloat16",
+                     attn=dataclasses.replace(base.attn, num_heads=4,
+                                              num_kv_heads=2, head_dim=128))
+    model = build_model(cfg)
+    mesh = Mesh([[topo.devices[0]]], ("data", "model"))
+    plan = plan_kv_arena(cfg, mesh, page_tokens=128, page_bytes=2**16,
+                         max_seqs=8, max_seq_len=512)
+    step, _, _ = build_paged_decode_step(model, mesh, plan,
+                                         attn_impl="kernel", interpret=False)
+    rep = NamedSharding(mesh, P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    s = plan.max_seqs
+    args = (sds((plan.total_elems,), plan.layout.dtype),
+            jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                         model.abstract_params()),
+            sds((s, plan.max_blocks, plan.n_layers), jnp.int32),
+            sds((s,), jnp.int32), sds((s,), jnp.int32), sds((s,), jnp.bool_))
+    text = step.lower(*args).compile().as_text()
+    kernels = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"', text)
+    assert len(kernels) == plan.n_layers
+    scopes = instruction_scopes(text)
+    assert {scopes[k] for k in kernels} == {"flash_decode"}
