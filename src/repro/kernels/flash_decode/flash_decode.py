@@ -1,18 +1,21 @@
-"""Split-KV decode attention statistics — Pallas TPU kernel.
+"""Split-KV decode attention statistics — Pallas TPU kernels.
 
 Decode is the α-bound regime: one query token against a long KV history.
-The kernel tiles the key positions (grid ``(batch, q_heads, k_blocks)``,
-trailing dim sequential) and emits **unnormalised** partial statistics
-``(acc, m, l)`` instead of the finished output, so callers can merge
-shards — per-device KV pages, per-page splits — with a log-sum-exp
+Both kernels tile the key positions and emit **unnormalised** partial
+statistics ``(acc, m, l)`` instead of the finished output, so callers can
+merge shards — per-device KV pages, per-page splits — with a log-sum-exp
 combine (:func:`repro.kernels.flash_decode.ref.combine`).  That combine is
 what the paged engine turns into a single fused ``Communicator.all_reduce``
 across the model axis.
 
-GQA is folded into the index maps (q head ``h`` reads kv head
-``h // group``), same as the prefill flash kernel.  A ``valid`` mask (not
-causality) gates key positions: paged KV holds many sequences at different
-lengths in one fixed-shape buffer.
+:func:`paged_decode_stats_fwd` (the paged engine's) reads the KV page
+arena in place: one grid step per slot walks the slot's live pages by
+scalar-prefetched page ids, double-buffering each page's DMA of K and V
+rows for every kv head, and each q head scores its own kv head's rows of
+that tile; blocks past a slot's position are neither fetched nor scored.
+:func:`flash_decode_stats_fwd` scores one dense KV shard (grid
+``(batch, q_heads, k_blocks)``, GQA folded into the index maps as q head
+``h`` reading kv head ``h // group``) under a ``valid`` mask.
 """
 
 from __future__ import annotations
@@ -118,3 +121,216 @@ def flash_decode_stats_fwd(q: jax.Array, k: jax.Array, v: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, valid)
+
+
+def _pack(d: int) -> int:
+    """K/V rows of ``d`` lanes held in one 128-lane row of the arena."""
+    return 128 // d if d < 128 else 1
+
+
+def check_paged_tiling(num_kv_heads: int, page_tokens: int, head_dim: int,
+                       page_elems: int) -> None:
+    """Raise unless :func:`paged_decode_stats_fwd` compiles for the chip
+    over pages of ``page_elems`` elements: a page's K (and V) rows,
+    ``num_kv_heads * page_tokens`` of ``head_dim``, fill whole 8-row tiles
+    of 128-lane rows (``head_dim`` divides 128 or is a multiple of it; the
+    DMA moves whole tiles), and the page holds K and V.  Interpret mode
+    takes any shape."""
+    d, rows = head_dim, num_kv_heads * page_tokens
+    lanes = d * _pack(d)
+    if (128 % d if d < 128 else d % 128) or (rows * d) % (8 * lanes) \
+            or page_elems % lanes or page_elems < 2 * rows * d:
+        raise ValueError(
+            f"the paged kernel reads a page's K and V as num_kv_heads * "
+            f"page_tokens = {rows} rows of head_dim={d} (in 8-row tiles of "
+            f"128 lanes) from pages of {page_elems} elements; pick a "
+            f"page_tokens that tiles")
+
+
+def _paged_decode_kernel(page_ref, blk_ref, nlive_ref, off_ref, nxt_ref,
+                         lens_ref, q_ref, pages_hbm, acc_o, m_o, l_o, kbuf,
+                         vbuf, sem, m_s, l_s, acc_s, *, scale: float,
+                         group: int, hkv: int, pt: int, bpr: int, pack: int):
+    b = pl.program_id(0)
+    prow = kbuf.shape[1]                   # lane rows of one page's K (or V)
+
+    def copies(slot, i, buf):
+        page = page_ref[slot * bpr + i]
+        return (pltpu.make_async_copy(pages_hbm.at[page, pl.ds(0, prow)],
+                                      kbuf.at[buf], sem.at[0, buf]),
+                pltpu.make_async_copy(pages_hbm.at[page, pl.ds(prow, prow)],
+                                      vbuf.at[buf], sem.at[1, buf]))
+
+    n, off = nlive_ref[b], off_ref[b]
+
+    @pl.when((off == 0) & (n > 0))         # the first live page of the call
+    def _first():
+        for c in copies(b, 0, 0):
+            c.start()
+
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+    hq, lanes = acc_s.shape
+    d = lanes // pack
+    # q head h reads kv head clip(h // group, 0, hkv - 1): rows
+    # [lo(h), lo(h) + pt) of the page's K and V
+    h = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
+    lo = jnp.zeros_like(h)
+    for g in range(1, hkv):
+        lo = lo + jnp.where(h >= g * group, pt, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (hq, prow), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+
+    def body(i, carry):
+        buf = (off + i) % 2
+
+        @pl.when(i + 1 < n)                # prefetch this slot's next page
+        def _next_block():
+            for c in copies(b, i + 1, 1 - buf):
+                c.start()
+
+        @pl.when((i + 1 == n) & (nxt_ref[b] >= 0))   # or the next slot's first
+        def _next_slot():
+            for c in copies(nxt_ref[b], 0, 1 - buf):
+                c.start()
+
+        ck, cv = copies(b, i, buf)
+        last = lens_ref[b] - blk_ref[b * bpr + i] * pt   # last live offset
+        ck.wait()
+        k = kbuf[buf].astype(jnp.float32)               # (prow, lanes)
+        # a lane row holds ``pack`` consecutive K rows of d lanes: piece c
+        # scores row pack * col + c against q placed in lanes [c*d, (c+1)*d)
+        s = []
+        for c in range(pack):
+            sc = jax.lax.dot_general(
+                q_ref[0, c].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            t = pack * col + c - lo
+            s.append(jnp.where((t >= 0) & (t < pt) & (t <= last), sc,
+                               NEG_INF))
+        m_prev = m_s[...]                                # (hq, 1)
+        m_new = m_prev
+        for sc in s:
+            m_new = jnp.maximum(m_new, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = [jnp.exp(sc - m_new) for sc in s]
+        l_s[...] = alpha * l_s[...] + sum(
+            jnp.sum(pc, axis=-1, keepdims=True) for pc in p)
+        cv.wait()
+        v = vbuf[buf].astype(jnp.float32)
+        pv = [jax.lax.dot(pc, v, preferred_element_type=jnp.float32)
+              for pc in p]
+        if pack > 1:                       # piece c's values: lanes of c
+            pv = [jnp.where((lane >= c * d) & (lane < (c + 1) * d), x, 0.0)
+                  for c, x in enumerate(pv)]
+        acc_s[...] = acc_s[...] * alpha + sum(pv)
+        m_s[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+    acc_o[0] = acc_s[...]
+    m_o[0] = m_s[...]
+    l_o[0] = l_s[...]
+
+
+def paged_decode_stats_fwd(q: jax.Array, pages: jax.Array, tab: jax.Array,
+                           slot_len: jax.Array, slot_valid: jax.Array,
+                           first_block, *, num_kv_heads: int,
+                           page_tokens: int, group: int,
+                           interpret: bool = False
+                           ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Split-KV decode statistics read in place from a paged KV arena.
+
+    q: (B, Hq, 1, D).  pages: (n_pages, page_rows, D), a page's K for every
+    kv head in rows ``[0, Hkv·pt)`` and its V in the next ``Hkv·pt`` rows.
+    tab: (B, bpr) int32 page ids of the blocks ``first_block + [0, bpr)``
+    (``-1`` unmapped).  Position ``p`` of slot ``b`` counts when
+    ``p <= slot_len[b]``, its block is mapped and ``slot_valid[b]``.  Query
+    head ``h`` reads kv head ``clip(h // group, 0, Hkv - 1)``.
+
+    One grid step per slot loops over the slot's live pages only; each
+    page's K and V arrive by DMA (double-buffered, the next page — or the
+    next live slot's first — in flight while this one is scored), and
+    every q head scores its own kv head's rows of them.  Rows narrower
+    than 128 lanes are read ``128 // D`` to a lane row, as the arena
+    holds them.  Returns fp32 ``(acc (B,Hq,1,D), m (B,Hq,1,1),
+    l (B,Hq,1,1))`` like :func:`flash_decode_stats_fwd`; a slot with no
+    live position returns ``m = NEG_INF``, ``l = 0``, ``acc = 0``, which
+    merges with weight 0.
+    """
+    b, hq, sq, d = q.shape
+    hkv, pt = num_kv_heads, page_tokens
+    n_pages, page_rows, _ = pages.shape
+    bpr = tab.shape[1]
+    pack = _pack(d)
+    lanes, prow = d * pack, hkv * pt // pack
+    if sq != 1:
+        raise ValueError(f"decode kernel takes a single query token, got S={sq}")
+    if (hkv * pt) % pack or (page_rows * d) % lanes \
+            or page_rows < 2 * hkv * pt:
+        raise ValueError(f"pages {pages.shape} do not hold K and V of "
+                         f"{hkv * pt} rows in rows of {lanes} lanes")
+    tab = tab.astype(jnp.int32)
+    lens = slot_len.astype(jnp.int32)
+    blk = jnp.asarray(first_block, jnp.int32) + jnp.arange(bpr,
+                                                           dtype=jnp.int32)
+    live = (tab >= 0) & slot_valid[:, None] & (blk[None, :] * pt
+                                               <= lens[:, None])
+    # each slot's live blocks first, in order; the kernel reads n_live of
+    # them, the DMA buffer of the i-th is (off[b] + i) % 2, and after a
+    # slot's last it prefetches the next slot with a live page (nxt)
+    order = jnp.argsort(~live, axis=1, stable=True)
+    page = jnp.maximum(jnp.take_along_axis(tab, order, axis=1), 0)
+    nlive = live.sum(axis=1, dtype=jnp.int32)
+    off = jnp.cumsum(nlive, dtype=jnp.int32) - nlive
+    slots = jnp.arange(b, dtype=jnp.int32)
+    ahead = jax.lax.cummin(jnp.where(nlive > 0, slots, b), reverse=True)
+    nxt = jnp.concatenate([ahead[1:], jnp.full((1,), b, jnp.int32)])
+    nxt = jnp.where(nxt < b, nxt, -1)
+    # q in lanes [c*d, (c+1)*d) of piece c, zeros elsewhere
+    qp = (q[:, None, :, 0, None, :]
+          * jnp.eye(pack, dtype=q.dtype)[None, :, None, :, None])
+
+    kernel = functools.partial(_paged_decode_kernel, scale=1.0 / (d ** 0.5),
+                               group=group, hkv=hkv, pt=pt, bpr=bpr,
+                               pack=pack)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, pack, hq, lanes), lambda b_, *_: (b_, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hq, lanes), lambda b_, *_: (b_, 0, 0)),
+            pl.BlockSpec((1, hq, 1), lambda b_, *_: (b_, 0, 0)),
+            pl.BlockSpec((1, hq, 1), lambda b_, *_: (b_, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, prow, lanes), pages.dtype),   # K, double-buffered
+            pltpu.VMEM((2, prow, lanes), pages.dtype),   # V
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((hq, 1), jnp.float32),            # running max m
+            pltpu.VMEM((hq, 1), jnp.float32),            # running denom l
+            pltpu.VMEM((hq, lanes), jnp.float32),        # output accumulator
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hq, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
+        ],
+        # the slots run in order: each prefetches the next one's first page
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page.reshape(-1), blk[order].reshape(-1), nlive, off, nxt, lens,
+      qp.reshape(b, pack, hq, lanes),
+      pages.reshape(n_pages, page_rows * d // lanes, lanes))
+    acc = acc.reshape(b, hq, pack, d).sum(axis=2)
+    return (acc.reshape(b, hq, 1, d), m.reshape(b, hq, 1, 1),
+            l.reshape(b, hq, 1, 1))

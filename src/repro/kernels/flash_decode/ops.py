@@ -1,10 +1,11 @@
 """Jit'd wrappers for split-KV decode attention with oracle fallback.
 
-``flash_decode_stats`` is the building block the paged engine consumes:
-partial softmax statistics over one KV shard, mergeable across shards or
-ranks with :func:`ref.combine`.  ``flash_decode`` closes the loop locally
-(single shard → normalised output).  Shapes that do not tile by the key
-block fall back to the one-shot oracle.
+``paged_decode_stats`` is what the paged engine calls: partial softmax
+statistics read in place from the KV page arena through the page table,
+mergeable across ranks with :func:`ref.combine`.  ``flash_decode_stats``
+is the same over one dense KV shard, and ``flash_decode`` closes the loop
+locally (single shard → normalised output); shapes that do not tile by
+the key block fall back to the one-shot oracle.
 """
 
 from __future__ import annotations
@@ -14,7 +15,9 @@ import jax.numpy as jnp
 
 from repro.kernels import default_interpret
 from repro.kernels.flash_decode import ref
-from repro.kernels.flash_decode.flash_decode import flash_decode_stats_fwd
+from repro.kernels.flash_decode.flash_decode import (check_paged_tiling,
+                                                     flash_decode_stats_fwd,
+                                                     paged_decode_stats_fwd)
 
 
 def _expand_gqa(q, k, v):
@@ -48,3 +51,18 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     stats = flash_decode_stats(q, k, v, valid, block_k=block_k,
                                interpret=interpret)
     return ref.combine([stats]).astype(q.dtype)
+
+
+def paged_decode_stats(q: jax.Array, pages: jax.Array, tab: jax.Array,
+                       slot_len: jax.Array, slot_valid: jax.Array,
+                       first_block, *, num_kv_heads: int, page_tokens: int,
+                       group: int, interpret: bool | None = None
+                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Partial stats (acc, m, l) for q (B,Hq,1,D) over the live pages of
+    ``tab`` in ``pages`` (n_pages, page_rows, D); see
+    :func:`paged_decode_stats_fwd`."""
+    interpret = default_interpret() if interpret is None else interpret
+    return paged_decode_stats_fwd(q, pages, tab, slot_len, slot_valid,
+                                  first_block, num_kv_heads=num_kv_heads,
+                                  page_tokens=page_tokens, group=group,
+                                  interpret=interpret)

@@ -11,9 +11,11 @@ KV cache.  The design commitments, in paper terms:
 * **Page-parallel decode on the model axis.**  Weights replicate across the
   model axis (decode is α-bound, not FLOP-bound; head-sharding would force
   a collective per projection) and the axis is spent where the memory is:
-  each rank gathers and scores a static ``blocks_per_rank`` chunk of the
-  page-table columns with the split-KV flash-decode kernel, then the
-  partial softmax statistics merge across ranks.
+  each rank scores a static ``blocks_per_rank`` chunk of the page-table
+  columns with the paged flash-decode kernel, which reads the live pages
+  in place through the table, then the partial softmax statistics merge
+  across ranks.  The ``ref`` path gathers the chunk dense, copies it per
+  query head and scores it with the jnp oracle.
 * **Two collectives per layer per token, fused.**  The cross-rank merge is
   one ``pmax`` of the running max plus ONE fused
   :meth:`Communicator.all_reduce` carrying the rescaled numerator and
@@ -47,6 +49,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.comm import CommConfig, Communicator
 from repro.obs import NULL_OBS
 from repro.configs.base import ModelConfig
+from repro.kernels import default_interpret
 from repro.kernels.flash_decode import ops as fd_ops
 from repro.kernels.flash_decode import ref as fd_ref
 from repro.models import moe as moe_mod
@@ -84,7 +87,9 @@ def predicted_wire_bytes_per_token(plan: KVArenaPlan, cfg: ModelConfig,
 
 
 # The named scopes of the decode step, in the order a layer runs them.
-# ``attn_merge`` exists only with a model axis of more than one rank.
+# ``kv_gather`` and ``gqa_expand`` exist only on the ``ref`` path (the
+# kernel reads the pages in place), ``attn_merge`` only with a model axis
+# of more than one rank.
 STEP_SCOPES = ("embed", "qkv_proj", "kv_write", "kv_gather", "gqa_expand",
                "flash_decode", "attn_merge", "o_proj", "mlp", "lm_head")
 _HEADER = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
@@ -233,9 +238,10 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
 
     ``params`` must be the full (un-sharded) tree — the engine replicates
     weights over the model axis by design (see module docstring).
-    ``attn_impl``: "kernel" scores pages with the Pallas flash-decode
-    kernel, "ref" with the jnp oracle (same math and identical collective
-    footprint; the dry-run uses "ref" to keep compile times sane).
+    ``attn_impl``: "kernel" scores the live pages in place with the Pallas
+    paged flash-decode kernel, "ref" gathers them dense and scores them
+    with the jnp oracle (same math and identical collective footprint; the
+    dry-run uses "ref" to keep compile times sane).
     """
     if attn_impl not in ("kernel", "ref"):
         raise ValueError(f"attn_impl must be kernel|ref, got {attn_impl!r}")
@@ -260,29 +266,38 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
     cdt = jnp.dtype(cfg.dtype)
     hkv, hd = cfg.attn.num_kv_heads, cfg.attn.head_dim
     true_group = max(cfg.attn.num_heads // hkv, 1)
+    bpr, page_rows = plan.blocks_per_rank, plan.page_stride // hd
+    interpret = default_interpret() if interpret is None else interpret
+    if attn_impl == "kernel" and not interpret:
+        fd_ops.check_paged_tiling(hkv, plan.page_tokens, hd,
+                                  plan.page_stride)
 
     def attend(q, pages, layer, table, slot_len, slot_valid):
-        with jax.named_scope("kv_gather"):
-            k, v, tab = _gather_local_kv(pages, plan, layer, table,
-                                         ctx.model_index())
-            valid = _local_valid(plan, tab, slot_len, slot_valid,
-                                 ctx.model_index())
-        with jax.named_scope("gqa_expand"):
-            # true-group GQA map (padded q heads clip to the last kv head)
-            # — expand kv per q head so the kernel runs group-free; the
-            # uniform h//group map inside the kernel would mis-pair padded
-            # head counts.
-            kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0,
-                              hkv - 1)
-            k = jnp.take(k, kv_idx, axis=1)
-            v = jnp.take(v, kv_idx, axis=1)
-        with jax.named_scope("flash_decode"):
-            if attn_impl == "kernel":
-                acc, m, l = fd_ops.flash_decode_stats(q, k, v, valid,
-                                                      interpret=interpret)
-            else:
+        rank = ctx.model_index()
+        if attn_impl == "kernel":
+            with jax.named_scope("flash_decode"):
+                tab = lax.dynamic_slice_in_dim(table[:, :, layer], rank * bpr,
+                                               bpr, axis=1)       # (B, bpr)
+                acc, m, l = fd_ops.paged_decode_stats(
+                    q, pages.reshape(plan.n_kv_pages, page_rows, hd), tab,
+                    slot_len, slot_valid, rank * bpr, num_kv_heads=hkv,
+                    page_tokens=plan.page_tokens, group=true_group,
+                    interpret=interpret)
+        else:
+            with jax.named_scope("kv_gather"):
+                k, v, tab = _gather_local_kv(pages, plan, layer, table, rank)
+                valid = _local_valid(plan, tab, slot_len, slot_valid, rank)
+            with jax.named_scope("gqa_expand"):
+                # true-group GQA map (padded q heads clip to the last kv
+                # head) — expand kv per q head so the oracle runs group-free
+                kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0,
+                                  hkv - 1)
+                k = jnp.take(k, kv_idx, axis=1)
+                v = jnp.take(v, kv_idx, axis=1)
+            with jax.named_scope("flash_decode"):
                 acc, m, l = fd_ref.decode_stats(q, k, v, valid)
-            if r == 1:
+        if r == 1:
+            with jax.named_scope("flash_decode"):
                 return fd_ref.combine([(acc, m, l)]).astype(q.dtype)
         with jax.named_scope("attn_merge"):
             m_g = ctx.pmax(m)
@@ -446,6 +461,21 @@ class PagedDecodeEngine:
         waste = 1.0 - held / cap_tokens if cap_tokens else 0.0
         self.obs.gauge("kv_page_waste", waste)
 
+    def _kv_block_counters(self) -> None:
+        """What the step's paged kernel reads of the arena: ``kv_blocks_read``
+        counts the live (slot, block, layer) pages it fetches (mapped, of a
+        live slot, at or before the slot's position), ``kv_blocks_total``
+        every page-table entry it walks (``B × max_blocks × layers``: each
+        rank walks ``B × blocks_per_rank × layers``)."""
+        if not self.obs.enabled:
+            return
+        tab, pt = self.table.table, self.plan.page_tokens
+        first = np.arange(tab.shape[1])[None, :, None] * pt
+        live = ((tab >= 0) & (first <= self.slot_len[:, None, None])
+                & self.slot_valid[:, None, None])
+        self.obs.counter("kv_blocks_read", int(live.sum()))
+        self.obs.counter("kv_blocks_total", tab.size)
+
     def _ensure_block(self, slot: int) -> None:
         blk = int(self.slot_len[slot]) // self.plan.page_tokens
         if self.table.table[slot, blk, 0] < 0:
@@ -463,6 +493,7 @@ class PagedDecodeEngine:
             with obs.span("serve.pages"):
                 for s in np.nonzero(self.slot_valid)[0]:
                     self._ensure_block(int(s))
+                self._kv_block_counters()
             # the step runs asynchronously while the host goes on mutating
             # its slot arrays, and a host-to-device transfer may alias a
             # numpy buffer (zero-copy on the CPU): hand the step copies

@@ -216,6 +216,134 @@ def test_flash_decode_output_wrapper(rng):
 
 
 # ---------------------------------------------------------------------------
+# paged flash-decode kernel vs the engine's gather + expand + oracle path
+# ---------------------------------------------------------------------------
+
+
+def _paged_case(rng, *, hq, hkv, d, rank=0, pt=8, blocks=4):
+    """A filled arena, page table and slots of every kind: lengths 0, 1,
+    pt-1, pt and the whole chunk (on rank 1: 0, 1 and either side of the
+    chunk's first position), a dead slot, and a slot with unmapped blocks
+    (one inside its live span); pages padded to 4 KiB where their K and V
+    do not fill it.  Returns the plan, the arena, the table (one layer),
+    lengths, validity and q (Hq padded to 16)."""
+    import dataclasses
+    from types import SimpleNamespace
+
+    import jax.numpy as jnp
+    from repro.configs import reduced_config
+    from repro.models.attention import padded_heads
+    from repro.serve import plan_kv_arena
+
+    base = reduced_config("llama3.2-1b")
+    cfg = base.with_(num_layers=1, attn=dataclasses.replace(
+        base.attn, num_heads=hq, num_kv_heads=hkv, head_dim=d))
+    mp = rank + 1
+    mesh = SimpleNamespace(axis_names=("data", "model"),
+                           devices=np.zeros((1, mp)))
+    plan = plan_kv_arena(cfg, mesh, page_tokens=pt, page_bytes=4096,
+                         max_seqs=7, max_seq_len=blocks * mp * pt)
+    bpr, first = plan.blocks_per_rank, rank * plan.blocks_per_rank
+    full = (first + bpr) * pt - 1
+    edge = first * pt or pt            # a block boundary inside the span
+    lens = np.array([0, 1, edge - 1, edge, full, full, full], np.int32)
+    valid = np.array([1, 1, 1, 1, 1, 0, 1], bool)
+    ids = rng.permutation(plan.n_kv_pages)[:7 * plan.max_blocks]
+    table = ids.reshape(7, plan.max_blocks, 1).astype(np.int32)
+    table[6, first + 1] = table[6, -1] = -1
+    pages = jnp.asarray(rng.randn(plan.total_elems), jnp.bfloat16)
+    q = jnp.asarray(rng.randn(7, padded_heads(hq), 1, d), jnp.bfloat16)
+    return plan, pages, table, lens, valid, q
+
+
+def _paged_kernel(plan, pages, table, lens, valid, q, rank, group):
+    from repro.kernels.flash_decode import paged_decode_stats
+
+    bpr, d = plan.blocks_per_rank, plan.head_dim
+    tab = table[:, rank * bpr:(rank + 1) * bpr, 0]
+    return paged_decode_stats(
+        q, pages.reshape(plan.n_kv_pages, plan.page_stride // d, d), tab,
+        lens, valid, rank * bpr, num_kv_heads=plan.num_kv_heads,
+        page_tokens=plan.page_tokens, group=group, interpret=True)
+
+
+@pytest.mark.parametrize("hq,hkv,d,rank", [
+    (10, 5, 64, 0),      # GQA, group 2, q heads padded 10 -> 16
+    (10, 5, 128, 0),
+    (12, 12, 64, 0),     # MHA, padded 12 -> 16
+    (8, 2, 128, 0),      # group 4, padded 8 -> 16
+    (10, 5, 64, 1),      # rank 1's block chunk
+    (12, 12, 128, 1),
+], ids=["gqa-pad-d64", "gqa-pad-d128", "mha-pad-d64", "gqa4-pad-d128",
+        "rank1-gqa-d64", "rank1-mha-d128"])
+def test_paged_decode_matches_gather_expand_oracle(rng, hq, hkv, d, rank):
+    """The paged kernel reads each live page in place, once per kv head,
+    and gives the statistics of the engine's ``ref`` path (dense gather,
+    per-q-head copy, one-shot oracle) at every slot with a live position.
+    A slot with none (dead, or short of this rank's chunk) gives
+    ``m = NEG_INF``, ``l = 0``, ``acc = 0``: weight 0 in any merge."""
+    import jax.numpy as jnp
+    from repro.kernels.flash_decode import ref
+    from repro.serve.engine import _gather_local_kv, _local_valid
+
+    plan, pages, table, lens, valid, q = _paged_case(rng, hq=hq, hkv=hkv,
+                                                     d=d, rank=rank)
+    group = hq // hkv
+    got = _paged_kernel(plan, pages, table, lens, valid, q, rank, group)
+
+    k, v, tab = _gather_local_kv(pages, plan, 0, jnp.asarray(table), rank)
+    ok = _local_valid(plan, tab, jnp.asarray(lens), jnp.asarray(valid), rank)
+    kv_idx = jnp.clip(jnp.arange(q.shape[1]) // group, 0, hkv - 1)
+    want = ref.decode_stats(q, jnp.take(k, kv_idx, axis=1),
+                            jnp.take(v, kv_idx, axis=1), ok)
+    live = np.asarray(ok).any(axis=1)
+    assert live.sum() == (6 if rank == 0 else 3)
+    for g, w, name in zip(got, want, ("acc", "m", "l")):
+        g, w = np.asarray(g), np.asarray(w)
+        np.testing.assert_allclose(g[live], w[live], rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
+    acc, m, l = (np.asarray(x)[~live] for x in got)
+    assert (m == ref.NEG_INF).all() and (l == 0).all() and (acc == 0).all()
+    np.testing.assert_allclose(
+        np.asarray(ref.combine([got]))[live],
+        np.asarray(ref.combine([want]))[live], rtol=1e-5, atol=1e-6)
+
+
+def test_paged_decode_deterministic_bitwise(rng):
+    """The paged kernel, like the dense one: same input → same bits."""
+    case = _paged_case(rng, hq=10, hkv=5, d=64)
+    a = _paged_kernel(*case, rank=0, group=2)
+    b = _paged_kernel(*case, rank=0, group=2)
+    for x, y in zip(a, b):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_kernel_step_refuses_pages_the_chip_cannot_tile():
+    """Compiled for the chip, the paged kernel DMAs whole 8-row tiles of
+    128 lanes: a plan whose page K rows do not fill them is refused when
+    the step is built; interpret mode takes it."""
+    import jax
+    from jax.sharding import AxisType
+    from repro.configs import reduced_config
+    from repro.models import build_model
+    from repro.serve import plan_kv_arena
+    from repro.serve.engine import build_paged_decode_step
+
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+    model = build_model(reduced_config("llama3.2-1b"))    # 2 kv heads of 16
+    for pt, tiles in ((8, False), (32, True)):
+        plan = plan_kv_arena(model.cfg, mesh, page_tokens=pt,
+                             page_bytes=4096, max_seqs=4, max_seq_len=64)
+        build_paged_decode_step(model, mesh, plan, interpret=True)
+        if tiles:
+            build_paged_decode_step(model, mesh, plan, interpret=False)
+        else:
+            with pytest.raises(ValueError, match="tiles"):
+                build_paged_decode_step(model, mesh, plan, interpret=False)
+
+
+# ---------------------------------------------------------------------------
 # paged engine vs the contiguous decode oracle
 # ---------------------------------------------------------------------------
 
@@ -293,7 +421,8 @@ def test_engine_slot_lifecycle_and_page_recycling(rng):
 def test_op_scopes_name_every_part_of_the_step(attn_impl):
     """``op_scopes`` maps the compiled step's instructions to every scope
     a single-rank step runs, and the kernel's ops (in interpret mode on
-    the CPU, the loop it lowers to) to ``flash_decode``."""
+    the CPU, the loop it lowers to) to ``flash_decode``.  The kernel path
+    reads the pages in place: it has no ``kv_gather`` or ``gqa_expand``."""
     import re
 
     from repro.obs import ObsConfig, make_obs
@@ -307,7 +436,10 @@ def test_op_scopes_name_every_part_of_the_step(attn_impl):
     eng.decode(params, np.zeros((4,), np.int32))
     ran = eng.obs.bus.counter_total("compiles")
     scopes = eng.op_scopes()
-    assert set(scopes.values()) == set(STEP_SCOPES) - {"attn_merge"}
+    ran_scopes = set(STEP_SCOPES) - {"attn_merge"}
+    if attn_impl == "kernel":
+        ran_scopes -= {"kv_gather", "gqa_expand"}
+    assert set(scopes.values()) == ran_scopes
     # the map reads the executable the step ran: nothing compiled again
     assert eng.obs.bus.counter_total("compiles") == ran
     text = eng.step.lower(*eng._args).compile().as_text()
@@ -399,6 +531,33 @@ def test_kv_gauges_are_the_documented_two_and_skipped_without_obs():
     eng.retire(0)
     assert {n for n, _ in eng.obs.bus.gauges} == {"kv_page_occupancy",
                                                   "kv_page_waste"}
+
+
+def test_kv_block_counters_count_live_pages_and_skip_without_obs():
+    """Per step, ``kv_blocks_read`` adds the live (slot, block, layer)
+    pages the kernel fetches and ``kv_blocks_total`` every entry of the
+    page table; under ``NULL_OBS`` the engine does no such work."""
+    from repro.obs import ObsConfig, make_obs
+
+    _, params, eng = _engine(attn_impl="kernel")
+    eng.table, table = None, eng.table
+    eng._kv_block_counters()          # NULL_OBS: returns before reading
+    eng.table = table
+    eng.obs = make_obs(ObsConfig(run_dir=None))
+    plan = eng.plan
+    eng.admit(0)
+    eng.admit(2)
+    for t in range(10):
+        if t == 4:
+            eng.admit(3)
+        eng.decode(params, np.zeros((4,), np.int32))
+    # slots 0 and 2 read one page a layer at positions 0..7 and two at 8
+    # and 9; slot 3 one page at positions 0..5
+    bus = eng.obs.bus
+    assert bus.counter_total("kv_blocks_read") == \
+        (2 * (8 * 1 + 2 * 2) + 6 * 1) * plan.n_layers
+    assert bus.counter_total("kv_blocks_total") == \
+        10 * plan.max_seqs * plan.max_blocks * plan.n_layers
 
 
 def test_decode_state_specs_replicate_paged_state():
@@ -546,6 +705,49 @@ def test_model_parallel_merge_runs_under_attn_merge():
     cross-rank all-reduces map to ``attn_merge``."""
     out = run_distributed(SERVE_SCOPES_R2_SCRIPT, n_devices=2)
     assert "SERVE_SCOPES_R2_OK" in out
+
+
+SERVE_KERNEL_R2_SCRIPT = r"""
+import re
+import jax
+import numpy as np
+from jax.sharding import AxisType
+from repro.configs import reduced_config
+from repro.models import build_model
+from repro.serve import PagedDecodeEngine, plan_kv_arena
+
+mesh = jax.make_mesh((1, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
+model = build_model(reduced_config("llama3.2-1b"))
+plan = plan_kv_arena(model.cfg, mesh, page_tokens=8, page_bytes=4096,
+                     max_seqs=4, max_seq_len=64)
+params = model.init(jax.random.PRNGKey(0))
+eng = {i: PagedDecodeEngine(model, mesh, plan, attn_impl=i, interpret=True)
+       for i in ("ref", "kernel")}
+for e in eng.values():
+    for s in (0, 1, 3):
+        e.admit(s)
+rng = np.random.RandomState(0)
+first = plan.blocks_per_rank * plan.page_tokens     # rank 1's first position
+for t in range(first + 3):
+    tok = rng.randint(0, model.cfg.vocab_size, (4,)).astype(np.int32)
+    out = {i: np.asarray(e.decode(params, tok), np.float32)[[0, 1, 3]]
+           for i, e in eng.items()}
+    assert np.allclose(out["kernel"], out["ref"], rtol=1e-4, atol=1e-5), \
+        (t, np.abs(out["kernel"] - out["ref"]).max())
+with mesh:
+    txt = eng["kernel"].step.lower(*eng["kernel"]._args).compile().as_text()
+assert len(re.findall(r" all-reduce\(", txt)) == 2 * plan.n_layers
+print("SERVE_KERNEL_R2_OK")
+"""
+
+
+def test_model_parallel_kernel_reads_each_ranks_chunk():
+    """On two ranks the paged kernel scores each rank's own block chunk:
+    logits match the ``ref`` path's at positions on both ranks, with the
+    same two collectives a layer."""
+    out = run_distributed(SERVE_KERNEL_R2_SCRIPT, n_devices=2)
+    assert "SERVE_KERNEL_R2_OK" in out
 
 
 # ---------------------------------------------------------------------------

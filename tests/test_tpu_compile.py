@@ -53,8 +53,8 @@ def one_chip(topo):
 
 
 def _flash_decode(sds):
-    # the paged engine's call: B=8 slots, 32 q heads with the 8 GQA kv
-    # heads expanded per q head, 1024 key positions, head_dim 64, bf16
+    # the dense single-shard kernel: B=8 slots, 32 q heads with the 8 GQA
+    # kv heads expanded per q head, 1024 key positions, head_dim 64, bf16
     b, hq, length, d = 8, 32, 1024, 64
     args = (sds((b, hq, 1, d), jnp.bfloat16),
             sds((b, hq, length, d), jnp.bfloat16),
@@ -62,6 +62,20 @@ def _flash_decode(sds):
             sds((b, length), jnp.bool_))
     return (lambda q, k, v, m: fd_ops.flash_decode_stats(
         q, k, v, m, interpret=False)), args
+
+
+def _paged_decode(sds):
+    # the paged engine's call at the Phi-3-medium serving cell: 48 slots,
+    # 48 q heads (40 padded) over 10 kv heads, head_dim 128, 128-token
+    # pages of 14 blocks over 5 layers' arena, read in place
+    b, hq, hkv, d, pt, blocks = 48, 48, 10, 128, 128, 14
+    n_pages, page_rows = b * blocks * 5, 2 * hkv * pt
+    args = (sds((b, hq, 1, d), jnp.bfloat16),
+            sds((n_pages, page_rows, d), jnp.bfloat16),
+            sds((b, blocks), jnp.int32), sds((b,), jnp.int32),
+            sds((b,), jnp.bool_), sds((), jnp.int32))
+    return (lambda *a: fd_ops.paged_decode_stats(
+        *a, num_kv_heads=hkv, page_tokens=pt, group=4, interpret=False)), args
 
 
 def _write_flat(sds):
@@ -86,7 +100,8 @@ def _read_dequant_flat(sds):
         (sds((4 * BUCKET,), jnp.int8),))
 
 
-@pytest.mark.parametrize("case", [_flash_decode, _write_flat, _read_flat,
+@pytest.mark.parametrize("case", [_flash_decode, _paged_decode, _write_flat,
+                                  _read_flat,
                                   _write_quant_flat, _read_dequant_flat],
                          ids=lambda c: c.__name__.lstrip("_"))
 def test_kernel_compiles_for_v5e(case, one_chip):
