@@ -17,7 +17,7 @@ from repro.configs.base import (SHAPES, AttnConfig, ModelConfig, MoEConfig,
 _ARCH_MODULES = [
     "llava_next_34b", "hymba_1p5b", "phi3_medium_14b", "minicpm_2b",
     "llama3p2_1b", "qwen2_7b", "llama4_maverick", "mixtral_8x7b",
-    "whisper_base", "falcon_mamba_7b",
+    "whisper_base", "falcon_mamba_7b", "moonlight_16b_a3b",
 ]
 
 
@@ -63,6 +63,9 @@ def reduced_config(name: str) -> ModelConfig:
             chunk=None if attn.chunk is None else 32,
             global_layers=tuple(i for i in attn.global_layers if i < 2),
         )
+        if attn.is_mla:
+            attn = dataclasses.replace(attn, num_kv_heads=4, kv_lora_rank=32,
+                                       qk_rope_head_dim=16, v_head_dim=16)
     moe = cfg.moe
     if moe is not None:
         moe = dataclasses.replace(moe, num_experts=4,

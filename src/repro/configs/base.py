@@ -22,6 +22,18 @@ class AttnConfig:
     chunk: int | None = None           # llama4-style chunked-local attention
     global_every: int = 0              # every Nth layer is global (0 = per window/chunk only)
     global_layers: tuple[int, ...] = ()  # explicit global-attention layer ids
+    # multi-head latent attention (DeepSeek-V2/V3): ``kv_lora_rank > 0``
+    # makes ``head_dim`` the no-RoPE part of a query/key head
+    # (qk_nope_head_dim); every head's keys and values come from one shared
+    # latent row of ``kv_lora_rank`` plus one shared RoPE key of
+    # ``qk_rope_head_dim``
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
 
 @dataclass(frozen=True)
@@ -33,6 +45,15 @@ class MoEConfig:
     interleave_step: int = 1           # every Nth layer is MoE (1 = all)
     capacity_factor: float = 1.25
     parallelism: str = "ep"            # "ep" (experts over model) | "tp" (ffn over model)
+    # "softmax" (top-k of the softmax, renormalised; top-1 a sigmoid gate) |
+    # "sigmoid" (DeepSeek-V3's noaux_tc: top-k of sigmoid + score_bias, the
+    # chosen sigmoids renormalised and times routed_scaling)
+    scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    # the experts this layer holds, [first_expert, first_expert +
+    # experts_held) of num_experts (None: all); routing stays over all
+    experts_held: int | None = None
+    first_expert: int = 0
 
 
 @dataclass(frozen=True)
@@ -60,6 +81,7 @@ class ModelConfig:
     frontend: str | None = None        # "audio_stub" | "vision_stub"
     frontend_seq: int = 0              # patch/frame tokens prepended (vlm)
     # numerics / structure
+    first_k_dense: int = 0             # leading layers with a dense MLP (moe archs)
     norm_eps: float = 1e-5
     act: str = "silu"
     tie_embeddings: bool = False
@@ -83,7 +105,7 @@ class ModelConfig:
             step = max(self.moe.interleave_step, 1)
             # hf llama4 convention: layers (step-1, 2*step-1, ...) are MoE when
             # interleaved; step == 1 -> every layer.
-            if (i + 1) % step == 0:
+            if (i + 1) % step == 0 and i >= self.first_k_dense:
                 kind["mlp"] = "moe"
         if self.attn is not None:
             a = self.attn

@@ -5,7 +5,8 @@ statistics read in place from the KV page arena through the page table,
 mergeable across ranks with :func:`ref.combine`.  ``flash_decode_stats``
 is the same over one dense KV shard, and ``flash_decode`` closes the loop
 locally (single shard → normalised output); shapes that do not tile by
-the key block fall back to the one-shot oracle.
+the key block fall back to the one-shot oracle.  ``mla_decode_stats`` is
+the paged engine's kernel for latent pages (multi-head latent attention).
 """
 
 from __future__ import annotations
@@ -15,8 +16,10 @@ import jax.numpy as jnp
 
 from repro.kernels import default_interpret
 from repro.kernels.flash_decode import ref
-from repro.kernels.flash_decode.flash_decode import (check_paged_tiling,
+from repro.kernels.flash_decode.flash_decode import (check_latent_tiling,
+                                                     check_paged_tiling,
                                                      flash_decode_stats_fwd,
+                                                     mla_decode_stats_fwd,
                                                      paged_decode_stats_fwd)
 
 
@@ -66,3 +69,18 @@ def paged_decode_stats(q: jax.Array, pages: jax.Array, tab: jax.Array,
                                   first_block, num_kv_heads=num_kv_heads,
                                   page_tokens=page_tokens, group=group,
                                   interpret=interpret)
+
+
+def mla_decode_stats(q_c: jax.Array, q_pe: jax.Array, pages: jax.Array,
+                     tab: jax.Array, slot_len: jax.Array,
+                     slot_valid: jax.Array, first_block, *, page_tokens: int,
+                     rope_pack: int, scale: float,
+                     interpret: bool | None = None
+                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Partial stats (acc (B,H,r), m, l) of absorbed latent attention over
+    the live latent pages of ``tab``; see :func:`mla_decode_stats_fwd`."""
+    interpret = default_interpret() if interpret is None else interpret
+    return mla_decode_stats_fwd(q_c, q_pe, pages, tab, slot_len, slot_valid,
+                                first_block, page_tokens=page_tokens,
+                                rope_pack=rope_pack, scale=scale,
+                                interpret=interpret)

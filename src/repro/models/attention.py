@@ -95,8 +95,10 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, window: int | None = None,
                         chunk: int | None = None, block_q: int = 2048,
                         block_k: int = 2048, causal_skip: bool = False) -> jax.Array:
-    """q: (B,Hq,S,D), k/v: (B,Hkv,S,D).  Online-softmax over kv blocks."""
+    """q/k: (B,Hq|Hkv,S,D), v: (B,Hkv,S,Dv).  Online-softmax over kv
+    blocks; the output is (B,Hq,S,Dv)."""
     b, hq, sq, d = q.shape
+    dv = v.shape[-1]
     hkv, sk = k.shape[1], k.shape[2]
     group = hq // hkv
     scale = 1.0 / math.sqrt(d)
@@ -111,7 +113,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qi = q[:, :, q0:q1].astype(jnp.float32) * scale
         m = jnp.full((b, hq, q1 - q0, 1), NEG_INF, jnp.float32)
         l = jnp.zeros((b, hq, q1 - q0, 1), jnp.float32)
-        acc = jnp.zeros((b, hq, q1 - q0, d), jnp.float32)
+        acc = jnp.zeros((b, hq, q1 - q0, dv), jnp.float32)
         for j in range(nk):
             k0, k1_ = j * bk, min((j + 1) * bk, sk)
             if causal_skip and causal and k0 > q1 - 1:

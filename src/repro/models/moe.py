@@ -18,6 +18,18 @@ Parallelism modes (applied by ``sharding.rules``):
   does not divide the EP axis (e.g. decode micro-batches) the honest
   replicated-psum fallback below is used.
 * ``tp`` — expert ffn dim sharded over "model" (for E smaller than the axis).
+
+A layer told which experts it holds (``MoEConfig.experts_held``, from
+``first_expert``) is one chip's share of an expert-parallel deployment:
+it routes over all ``num_experts`` at the router's published width and
+adds, for each token, the weighted outputs of the chosen experts it holds
+(:func:`held_experts`), plus the shared experts every chip computes alike.
+The weights still sum over all ``top_k`` chosen experts, held or not.
+Every token passes through every held expert, weighted by a gate that is
+zero where the expert was not chosen: exact, nothing dropped, and at
+decode sizes (a token a slot) as memory-bound as a dispatch.  The chips
+that hold the other experts, and the exchange with them, are not part of
+it.
 """
 
 from __future__ import annotations
@@ -33,10 +45,10 @@ from repro.models.common import activation, dense_init, trunc_normal
 
 def moe_init(key, cfg: MoEConfig, d_model: int, *, dtype=jnp.float32) -> dict:
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-    e, f = cfg.num_experts, cfg.expert_ff
+    e, f = held_count(cfg), cfg.expert_ff
     std = 1.0 / math.sqrt(d_model)
     p = {
-        "router": dense_init(k1, d_model, e, dtype=dtype),
+        "router": dense_init(k1, d_model, cfg.num_experts, dtype=dtype),
         "w_gate": trunc_normal(k2, (e, d_model, f), std, dtype),
         "w_up": trunc_normal(k3, (e, d_model, f), std, dtype),
         "w_down": trunc_normal(k4, (e, f, d_model), 1.0 / math.sqrt(f), dtype),
@@ -45,7 +57,68 @@ def moe_init(key, cfg: MoEConfig, d_model: int, *, dtype=jnp.float32) -> dict:
         from repro.models.common import glu_mlp_init
 
         p["shared"] = glu_mlp_init(k5, d_model, cfg.shared_expert_ff, dtype=dtype)
+    if cfg.scoring == "sigmoid":
+        p["score_bias"] = jnp.zeros((cfg.num_experts,), dtype)
     return p
+
+
+def held_count(cfg: MoEConfig) -> int:
+    """Experts whose weights the layer holds."""
+    return cfg.num_experts if cfg.experts_held is None else cfg.experts_held
+
+
+def route(p: dict, x: jax.Array, cfg: MoEConfig, compute_dtype
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``(weights, expert_ids, probs)``: each token's ``top_k`` experts
+    among all ``num_experts``, their fp32 gate weights, and every expert's
+    routing probability (for the balance loss).
+
+    ``softmax``: the top-k logits, their softmax renormalised (top-1: a
+    sigmoid gate).  ``sigmoid`` (DeepSeek-V3 ``noaux_tc``, one group):
+    ``s = sigmoid(x W_r)`` in fp32, the top-k of ``s + score_bias`` (the
+    bias only selects), ``w = s / sum(s) * routed_scaling`` over the
+    chosen."""
+    if cfg.scoring == "sigmoid":
+        logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                            p["router"]["w"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(s + p["score_bias"].astype(jnp.float32),
+                               cfg.top_k)
+        w = jnp.take_along_axis(s, ids, axis=-1)
+        w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scaling
+        return w, ids, s / jnp.sum(s, axis=-1, keepdims=True)
+    logits = jnp.einsum("...d,de->...e", x.astype(compute_dtype),
+                        p["router"]["w"].astype(compute_dtype))
+    logits = logits.astype(jnp.float32)
+    gate_vals, ids = jax.lax.top_k(logits, cfg.top_k)
+    if cfg.top_k == 1:
+        # llama4-style: sigmoid gate (renorm-softmax of one logit is a
+        # constant 1 and would starve the router of gradient)
+        w = jax.nn.sigmoid(gate_vals)
+    else:
+        w = jax.nn.softmax(gate_vals, axis=-1)                     # mixtral renorm
+    return w, ids, jax.nn.softmax(logits, axis=-1)
+
+
+def held_gate(w: jax.Array, ids: jax.Array, cfg: MoEConfig) -> jax.Array:
+    """(..., top_k) weights and ids -> (..., held) gate of each held
+    expert: its weight where the token chose it, else zero."""
+    mine = cfg.first_expert + jnp.arange(held_count(cfg))
+    return jnp.sum(jnp.where(ids[..., None] == mine, w[..., None], 0.0),
+                   axis=-2)
+
+
+def held_experts(p: dict, x: jax.Array, gate: jax.Array, act: str,
+                 compute_dtype) -> jax.Array:
+    """sum_e gate[..., e] * expert_e(x) over the held experts, every token
+    through every one of them.  x: (T, d), gate: (T, held) -> (T, d)."""
+    xc = x.astype(compute_dtype)
+    g = jnp.einsum("td,edf->tef", xc, p["w_gate"].astype(compute_dtype))
+    u = jnp.einsum("td,edf->tef", xc, p["w_up"].astype(compute_dtype))
+    h = activation(act)(g) * u * gate[..., None].astype(compute_dtype)
+    return jnp.einsum("tef,efd->td", h, p["w_down"].astype(compute_dtype),
+                      preferred_element_type=jnp.float32)
 
 
 def capacity(tokens_per_row: int, cfg: MoEConfig) -> int:
@@ -103,6 +176,9 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, act: str, *, ctx,
     * EP (fallback): replicated buffer, slice own experts, zero-pad,
       psum — the honest replicated cost, also used by transport="psum".
     * TP: every rank runs all experts on its ffn shard; psum after w_down.
+    * A held share (``cfg.experts_held``): :func:`held_experts` over the
+      layer's own experts, plus the shared experts; no exchange, nothing
+      dropped.
     """
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
@@ -110,21 +186,20 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, act: str, *, ctx,
     xf = x.astype(compute_dtype)
     e_local = p["w_gate"].shape[0]
     f_local = p["w_gate"].shape[2]
-    ep_sharded = e_local < e
+    ep_sharded = e_local < held_count(cfg)
     tp_sharded = f_local < cfg.expert_ff
 
-    logits = jnp.einsum("bsd,de->bse", xf, p["router"]["w"].astype(compute_dtype))
-    logits = logits.astype(jnp.float32)
-    gates_all = jax.nn.softmax(logits, axis=-1)                    # (B,S,E)
-    gate_vals, expert_ids = jax.lax.top_k(logits, k)               # (B,S,k)
-    if k == 1:
-        # llama4-style: sigmoid gate (renorm-softmax of one logit is a
-        # constant 1 and would starve the router of gradient)
-        gate_w = jax.nn.sigmoid(gate_vals)
-    else:
-        gate_w = jax.nn.softmax(gate_vals, axis=-1)                # mixtral renorm
-
+    gate_w, expert_ids, gates_all = route(p, xf, cfg, compute_dtype)
     aux = load_balance_aux(gates_all, expert_ids, e, k)
+    if cfg.experts_held is not None:
+        y = held_experts(p, xf.reshape(b * s, d),
+                         held_gate(gate_w, expert_ids, cfg).reshape(b * s, -1),
+                         act, compute_dtype).reshape(b, s, d)
+        if "shared" in p:
+            from repro.models.common import glu_mlp
+
+            y = y + glu_mlp(p["shared"], xf, act, compute_dtype)
+        return y.astype(x.dtype), aux, jnp.zeros((), jnp.float32)
     drop_frac = dropped_fraction(expert_ids, e, cap)
 
     # ---- sort-based dispatch, vmapped over batch rows ----

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import (dense_init, embed, embed_init, glu_mlp,
@@ -29,7 +30,9 @@ def block_init(key, cfg: ModelConfig, i: int, dtype) -> dict:
     ks = jax.random.split(key, 4)
     p: dict = {"ln1": rmsnorm_init(cfg.d_model, dtype),
                "ln2": rmsnorm_init(cfg.d_model, dtype)}
-    if kind["mixer"] in ("attn", "hybrid"):
+    if kind["mixer"] == "attn" and cfg.attn.is_mla:
+        p["mla"] = mla_mod.mla_init(ks[0], cfg.attn, cfg.d_model, dtype=dtype)
+    elif kind["mixer"] in ("attn", "hybrid"):
         p["attn"] = attn_mod.attn_init(ks[0], cfg.attn, cfg.d_model, dtype=dtype)
     if kind["mixer"] in ("ssm", "hybrid"):
         p["ssm"] = ssm_mod.ssm_init(ks[1], cfg.ssm, cfg.d_model, dtype=dtype)
@@ -73,8 +76,14 @@ def block_apply(p: dict, x: jax.Array, cfg: ModelConfig, i: int, *, ctx,
     cdt = jnp.dtype(cfg.dtype)
     aux = jnp.zeros((), jnp.float32)
     drop = jnp.zeros((), jnp.float32)
-    h = ctx.fan_out(rmsnorm(p["ln1"], x, cfg.norm_eps))
-    if kind["mixer"] == "attn":
+    h = rmsnorm(p["ln1"], x, cfg.norm_eps)
+    if "mla" not in p:   # MLA weights stay whole: no model-axis boundary
+        h = ctx.fan_out(h)
+    if "mla" in p:
+        mix = mla_mod.mla_apply(p["mla"], h, cfg.attn, eps=cfg.norm_eps,
+                                positions=positions, compute_dtype=cdt,
+                                causal_skip=causal_skip)
+    elif kind["mixer"] == "attn":
         mix = attn_mod.attn_apply(p["attn"], h, cfg.attn,
                                   is_global=kind.get("attn_global", True),
                                   ctx=ctx, positions=positions,
@@ -181,7 +190,10 @@ def init_decode_state(cfg: ModelConfig, batch: int, seq_len: int,
     for i in range(cfg.num_layers):
         kind = cfg.layer_kind(i)
         st: dict = {}
-        if kind["mixer"] in ("attn", "hybrid"):
+        if kind["mixer"] == "attn" and cfg.attn.is_mla:
+            st["latent"] = mla_mod.init_cache(cfg.attn, batch, seq_len,
+                                              dtype=cache_dtype)
+        elif kind["mixer"] in ("attn", "hybrid"):
             st["kv"] = attn_mod.init_cache(cfg.attn, batch, seq_len,
                                            is_global=kind.get("attn_global",
                                                               kind["mixer"] == "attn"),
@@ -219,7 +231,11 @@ def decode_step(params: dict, token: jax.Array, state: list, pos: jax.Array,
         st = dict(state[i])
         h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
         clen = cache_len(cfg, i, seq_len) if seq_len else None
-        if kind["mixer"] == "attn":
+        if "mla" in bp:
+            mix, st["latent"] = mla_mod.mla_decode(
+                bp["mla"], h, cfg.attn, st["latent"], pos=pos,
+                eps=cfg.norm_eps, compute_dtype=cdt)
+        elif kind["mixer"] == "attn":
             mix, st["kv"] = attn_mod.attn_decode(
                 bp["attn"], h, cfg.attn, st["kv"],
                 is_global=kind.get("attn_global", True), ctx=ctx, pos=pos,

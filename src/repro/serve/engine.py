@@ -28,6 +28,12 @@ KV cache.  The design commitments, in paper terms:
 Admission, eviction and page recycling are host-side (numpy) and change no
 traced shape, so the step compiles once per ``(plan, arch)``.
 
+An arena of ``latent`` pages (multi-head latent attention, see
+:mod:`repro.serve.kv`) gets its own block: the queries are absorbed
+through ``W_UK`` before the ``mla_decode`` kernel scores each slot's live
+latent pages in place (the same page walk), ``W_UV`` and ``W_o`` follow,
+and an expert layer runs its held experts (:mod:`repro.models.moe`).
+
 Each part of the step runs under a ``jax.named_scope`` of
 :data:`STEP_SCOPES` (no layer index), which lands in the compiled
 instructions' ``op_name`` metadata; :meth:`PagedDecodeEngine.op_scopes`
@@ -52,6 +58,7 @@ from repro.configs.base import ModelConfig
 from repro.kernels import default_interpret
 from repro.kernels.flash_decode import ops as fd_ops
 from repro.kernels.flash_decode import ref as fd_ref
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models.attention import _merge_heads, _split_heads, padded_heads
 from repro.models.common import (apply_rope, dense, embed, glu_mlp, rmsnorm,
@@ -92,6 +99,17 @@ def predicted_wire_bytes_per_token(plan: KVArenaPlan, cfg: ModelConfig,
 # of more than one rank.
 STEP_SCOPES = ("embed", "qkv_proj", "kv_write", "kv_gather", "gqa_expand",
                "flash_decode", "attn_merge", "o_proj", "mlp", "lm_head")
+# The named scopes of a step over latent pages (multi-head latent
+# attention): ``mla_q`` the query and latent projections, RoPE, the latent
+# norm and the absorption through ``W_UK``; ``mla_decode`` the kernel (or,
+# on the ``ref`` path, the oracle after ``kv_gather``) and its combine;
+# ``mla_out`` ``W_UV`` and ``W_o``; a dense layer's MLP ``mlp``, an expert
+# layer's ``moe_router`` (with its norm), ``moe_experts`` (the held
+# experts) and ``moe_shared`` (the shared experts).
+LATENT_STEP_SCOPES = ("embed", "mla_q", "kv_write", "kv_gather", "mla_decode",
+                      "mla_out", "mlp", "moe_router", "moe_experts",
+                      "moe_shared", "lm_head")
+_SCOPES = frozenset(STEP_SCOPES + LATENT_STEP_SCOPES)
 _HEADER = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _OP_NAME = re.compile(r'\bop_name="([^"]*)"')
@@ -100,14 +118,15 @@ _REF = re.compile(r"%([\w.\-]+)")
 
 
 def _named_scope(op_name: str) -> str | None:
-    parts = [p for p in op_name.split("/") if p in STEP_SCOPES]
+    parts = [p for p in op_name.split("/") if p in _SCOPES]
     return parts[-1] if parts else None
 
 
 def instruction_scopes(hlo_text: str) -> dict[str, str]:
     """``{instruction name: scope}`` of a compiled module's text.
 
-    An instruction's scope is the innermost :data:`STEP_SCOPES` name among
+    An instruction's scope is the innermost :data:`STEP_SCOPES` (or
+    :data:`LATENT_STEP_SCOPES`) name among
     the ``/``-separated parts of its ``op_name``.  The compiler makes some
     instructions with no such name (fusions of a gather's pieces, layout
     copies, prefetches of weights); each of these takes, in this order,
@@ -212,6 +231,56 @@ def _gather_local_kv(pages, plan: KVArenaPlan, layer: int, table, rank):
     return dense(0), dense(plan.v_offset), tab
 
 
+def _write_token_latent(pages, plan: KVArenaPlan, layer: int, table,
+                        slot_len, slot_valid, c1, k1):
+    """Write this step's latent row ``c1`` (B, r) and RoPE key ``k1``
+    (B, dr) into each slot's current latent page (layout in
+    :mod:`repro.serve.kv`), on the arena's view as rows of ``lane``
+    elements: ``r / lane`` whole rows, and the key into its lanes of a
+    packed row (the row's other keys, other positions of the same slot,
+    are read and written back unchanged).  Invalid slots or unmapped
+    blocks write out of bounds, which the scatter drops."""
+    pt, lane, pack, dr = (plan.page_tokens, plan.lane, plan.rope_pack,
+                          plan.rope_dim)
+    nc, krows = plan.head_dim // lane, pt // pack
+    block = slot_len // pt
+    within = slot_len % pt
+    page = jnp.take_along_axis(table[:, :, layer], block[:, None],
+                               axis=1)[:, 0]                       # (B,)
+    ok = slot_valid & (page >= 0)
+    rows = pages.reshape(-1, lane)
+    n = rows.shape[0]
+    base = page * (plan.page_stride // lane)
+    cidx = base[:, None] + jnp.arange(nc)[None, :] * pt + within[:, None]
+    cidx = jnp.where(ok[:, None], cidx, n)
+    rows = rows.at[cidx].set(
+        c1.reshape(-1, nc, lane).astype(rows.dtype), mode="drop")
+    kidx = jnp.where(ok, base + nc * pt + within % krows, n)
+    old = jnp.take(rows, jnp.minimum(kidx, n - 1), axis=0)         # (B, lane)
+    mine = (jnp.arange(lane)[None, :] // dr) == (within // krows)[:, None]
+    new = jnp.where(mine, jnp.tile(k1, (1, pack)).astype(rows.dtype), old)
+    rows = rows.at[kidx].set(new, mode="drop")
+    return rows.reshape(-1)
+
+
+def _gather_local_latent(pages, plan: KVArenaPlan, layer: int, table, rank):
+    """This rank's chunk of the latent pages as dense ``c`` (B, L, r) and
+    ``k_pe`` (B, L, dr), plus its page-table slice (the ``ref`` path)."""
+    bpr, pt, stride = plan.blocks_per_rank, plan.page_tokens, plan.page_stride
+    lane, pack, dr, r = plan.lane, plan.rope_pack, plan.rope_dim, plan.head_dim
+    tab = lax.dynamic_slice_in_dim(table[:, :, layer], rank * bpr, bpr,
+                                   axis=1)                         # (B, bpr)
+    by_page = pages[:plan.n_kv_pages * stride].reshape(-1, stride)
+    blk = jnp.take(by_page, jnp.maximum(tab, 0), axis=0)   # (B, bpr, stride)
+    b, nc = blk.shape[0], r // lane
+    c = blk[:, :, :nc * pt * lane].reshape(b, bpr, nc, pt, lane) \
+        .transpose(0, 1, 3, 2, 4).reshape(b, bpr * pt, r)
+    k0 = nc * pt * lane
+    k = blk[:, :, k0:k0 + pt * dr].reshape(b, bpr, pt // pack, pack, dr) \
+        .transpose(0, 1, 3, 2, 4).reshape(b, bpr * pt, dr)
+    return c, k, tab
+
+
 def _local_valid(plan: KVArenaPlan, tab, slot_len, slot_valid, rank):
     """(B, L_local) mask: position exists (≤ current pos, incl. the token
     just written), its block is mapped, and the slot is live."""
@@ -253,6 +322,9 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
         raise ValueError(
             f"plan was laid out for model_parallel={plan.model_parallel} "
             f"but the mesh model axis is {r_mesh}; re-plan with this mesh")
+    if plan.kind == "latent":
+        return _build_latent_step(model, mesh, plan, ctx, attn_impl,
+                                  interpret, donate)
     if plan.page_stride % plan.head_dim or plan.total_elems % plan.head_dim:
         raise ValueError(
             f"KV pages must hold whole head_dim={plan.head_dim} rows "
@@ -351,6 +423,13 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
                 logits = dense(params["lm_head"], x, cdt)
             return logits[:, 0], pages
 
+    return _jit_step(fn, model, mesh, plan, donate)
+
+
+def _jit_step(fn, model, mesh: Mesh, plan: KVArenaPlan, donate: bool,
+              extra_out: tuple = ()):
+    """``fn`` as the jitted, shard-mapped step (weights replicated, the
+    arena donated); ``extra_out``: specs of outputs after the pages."""
     state_abs = {
         "pages": jax.ShapeDtypeStruct((plan.total_elems,), plan.layout.dtype),
         "page_table": jax.ShapeDtypeStruct(
@@ -358,16 +437,111 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
         "slot_len": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.int32),
         "slot_valid": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.bool_),
     }
-    sspecs = shard_rules.decode_state_specs(state_abs, cfg, mesh,
+    sspecs = shard_rules.decode_state_specs(state_abs, model.cfg, mesh,
                                             plan.max_seqs)
     pspecs = jax.tree.map(lambda _: P(), model.abstract_params())
     sharded = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(sspecs["pages"], pspecs, sspecs["page_table"], P(),
                   sspecs["slot_len"], sspecs["slot_valid"]),
-        out_specs=(P(), sspecs["pages"]), check_vma=False)
+        out_specs=(P(), sspecs["pages"]) + extra_out, check_vma=False)
     step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
     return step, pspecs, sspecs
+
+
+def _build_latent_step(model, mesh: Mesh, plan: KVArenaPlan, ctx,
+                       attn_impl: str, interpret: bool | None, donate: bool):
+    """The step over latent pages: multi-head latent attention in its
+    absorbed form (``q_nope`` through ``W_UK`` before the kernel, ``W_UV``
+    and ``W_o`` after it), a dense MLP or an expert layer told which
+    experts it holds (:func:`repro.models.moe.held_experts`).  Its third
+    output counts the step's (live token, expert) choices that land on
+    held experts, over all expert layers."""
+    cfg = model.cfg
+    a, moe = cfg.attn, cfg.moe
+    if plan.model_parallel > 1:
+        raise NotImplementedError(
+            "latent pages are served on one model rank (model axis of 1)")
+    cdt = jnp.dtype(cfg.dtype)
+    pt = plan.page_tokens
+    page_rows = plan.page_stride // plan.lane
+    sc = mla_mod.scale(a)
+    interpret = default_interpret() if interpret is None else interpret
+    if attn_impl == "kernel" and not interpret:
+        fd_ops.check_latent_tiling(pt, plan.lane, plan.head_dim,
+                                   plan.rope_pack, page_rows)
+
+    def attend(q_c, q_pe, pages, layer, table, slot_len, slot_valid):
+        if attn_impl == "kernel":
+            with jax.named_scope("mla_decode"):
+                acc, _, l = fd_ops.mla_decode_stats(
+                    q_c, q_pe,
+                    pages.reshape(plan.n_kv_pages, page_rows, plan.lane),
+                    table[:, :, layer], slot_len, slot_valid, 0,
+                    page_tokens=pt, rope_pack=plan.rope_pack, scale=sc,
+                    interpret=interpret)
+        else:
+            with jax.named_scope("kv_gather"):
+                c, k_pe, tab = _gather_local_latent(pages, plan, layer,
+                                                    table, 0)
+                valid = _local_valid(plan, tab, slot_len, slot_valid, 0)
+            with jax.named_scope("mla_decode"):
+                acc, _, l = mla_mod.latent_stats(q_c, q_pe, c, k_pe, valid,
+                                                 sc)
+        with jax.named_scope("mla_decode"):
+            return acc / jnp.maximum(l, 1e-30)
+
+    def fn(pages, params, table, token, slot_len, slot_valid):
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], token[:, None], cdt, ctx,
+                      cfg.vocab_size)
+        posb = slot_len[:, None]                       # per-slot position
+        held = jnp.zeros((), jnp.int32)
+        for i, bp in enumerate(params["blocks"]):
+            pa = bp["mla"]
+            with jax.named_scope("mla_q"):
+                h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
+                q_nope, q_pe = mla_mod.query(pa, h, a, posb, cdt)
+                q_c = mla_mod.absorb_query(pa, q_nope[:, :, 0], a, cdt)
+                c1, k1 = mla_mod.latent(pa, h, a, posb, cfg.norm_eps, cdt)
+            with jax.named_scope("kv_write"):
+                pages = _write_token_latent(pages, plan, i, table, slot_len,
+                                            slot_valid, c1[:, 0], k1[:, 0])
+            o_c = attend(q_c, q_pe[:, :, 0], pages, i, table, slot_len,
+                         slot_valid)
+            with jax.named_scope("mla_out"):
+                y = mla_mod.latent_out(pa, o_c, a, cdt)
+                x = x + y[:, None].astype(x.dtype)
+            if "mlp" in bp:
+                with jax.named_scope("mlp"):
+                    h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
+                    y = glu_mlp(bp["mlp"], h2, cfg.act, cdt)
+                    x = x + y.astype(x.dtype)
+                continue
+            pm = bp["moe"]
+            with jax.named_scope("moe_router"):
+                h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)[:, 0]
+                w, ids, _ = moe_mod.route(pm, h2, moe, cdt)
+                gate = moe_mod.held_gate(w, ids, moe)
+                mine = (ids >= moe.first_expert) & (
+                    ids < moe.first_expert + moe_mod.held_count(moe))
+                held = held + jnp.sum(mine & slot_valid[:, None],
+                                      dtype=jnp.int32)
+            with jax.named_scope("moe_experts"):
+                y = moe_mod.held_experts(pm, h2, gate, cfg.act, cdt)
+            if "shared" in pm:
+                with jax.named_scope("moe_shared"):
+                    y = y + glu_mlp(pm["shared"], h2, cfg.act, cdt)
+            x = x + y[:, None].astype(x.dtype)
+        with jax.named_scope("lm_head"):
+            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = unembed(params["embed"], x, cdt)
+            else:
+                logits = dense(params["lm_head"], x, cdt)
+            return logits[:, 0], pages, held
+
+    return _jit_step(fn, model, mesh, plan, donate, extra_out=(P(),))
 
 
 # ---------------------------------------------------------------------------
@@ -393,7 +567,8 @@ class PagedDecodeEngine:
                  attn_impl: str = "kernel", interpret: bool | None = None,
                  donate: bool = True, obs=None):
         self.model, self.mesh, self.plan = model, mesh, plan
-        self.obs = obs if obs is not None else NULL_OBS
+        self._routing = []    # latent steps' expert choices, not yet counted
+        self.obs = obs
         self.step, self.param_specs, self.state_specs = \
             build_paged_decode_step(model, mesh, plan, attn_impl=attn_impl,
                                     interpret=interpret, donate=donate)
@@ -408,6 +583,17 @@ class PagedDecodeEngine:
             device=NamedSharding(mesh, self.state_specs["pages"]))
         self.steps = 0
         self._args = None     # the step's abstract arguments, for op_scopes
+
+    @property
+    def obs(self):
+        return self._obs
+
+    @obs.setter
+    def obs(self, obs) -> None:
+        """Swap the telemetry sink; counts the last step still owes go to
+        the one being replaced."""
+        self._routing_counters(keep=0)
+        self._obs = obs if obs is not None else NULL_OBS
 
     # -- slot management (host side) ----------------------------------------
 
@@ -476,6 +662,23 @@ class PagedDecodeEngine:
         self.obs.counter("kv_blocks_read", int(live.sum()))
         self.obs.counter("kv_blocks_total", tab.size)
 
+    def _routing_counters(self, keep: int = 1) -> None:
+        """The expert choices of the steps not yet counted, but for the
+        newest ``keep``: ``moe_assignments`` counts each step's (live
+        token, expert) pairs over the expert layers,
+        ``moe_held_assignments`` those on experts this layer holds.  Read
+        two steps late, so that counting never waits for the device, also
+        where the caller dispatches a step before it reads the last."""
+        if len(self._routing) <= keep:
+            return
+        cfg = self.model.cfg
+        layers = sum(cfg.layer_kind(i)["mlp"] == "moe"
+                     for i in range(cfg.num_layers))
+        while len(self._routing) > keep:
+            live, held = self._routing.pop(0)
+            self.obs.counter("moe_assignments", live * cfg.moe.top_k * layers)
+            self.obs.counter("moe_held_assignments", int(held))
+
     def _ensure_block(self, slot: int) -> None:
         blk = int(self.slot_len[slot]) // self.plan.page_tokens
         if self.table.table[slot, blk, 0] < 0:
@@ -489,6 +692,7 @@ class PagedDecodeEngine:
         position, attend over its pages, return logits (B, vocab).
         Invalid slots' rows are garbage by contract."""
         obs = self.obs
+        self._routing_counters()
         with obs.span("serve.decode", step=self.steps):
             with obs.span("serve.pages"):
                 for s in np.nonzero(self.slot_valid)[0]:
@@ -507,7 +711,10 @@ class PagedDecodeEngine:
                 if self._args is None:
                     self._args = jax.tree.map(_abstract, args)
             with obs.span("serve.dispatch"), self.mesh:
-                logits, self.pages = self.step(*args)
+                out = self.step(*args)
+            logits, self.pages = out[0], out[1]
+            if len(out) > 2 and obs.enabled and self.model.cfg.moe:
+                self._routing.append((int(self.slot_valid.sum()), out[2]))
             self.slot_len[self.slot_valid] += 1
         self.steps += 1
         return logits

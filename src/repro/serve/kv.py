@@ -1,7 +1,15 @@
 """Paged KV cache: the serving generalisation of the huge-page arena.
 
-A **KV page** holds one layer's K and V blocks for ``page_tokens`` token
-positions of one sequence.  All pages are laid out in a single flat arena by
+A page holds one layer's attention state for ``page_tokens`` token
+positions of one sequence.  There are two page kinds, by the layers'
+attention (:func:`page_kind`):
+
+* ``kv`` (grouped-query attention): K and V per KV head;
+* ``latent`` (multi-head latent attention): one latent row per token, the
+  normed ``c`` (``kv_lora_rank`` values) and the roped shared key
+  ``k_pe`` (``qk_rope_head_dim``), read by every query head.
+
+All pages are laid out in a single flat arena by
 :func:`repro.mem.layout.plan_arena` — the same page-quantized placement the
 gradient :class:`~repro.mem.arena.CommArena` uses, so every page starts on a
 ``page_bytes`` boundary (the paper's 2 MiB huge-page granule) and the
@@ -11,9 +19,18 @@ threaded through the jitted decode step as a **donated** buffer, exactly
 like the training arena: no per-step transient KV allocations, XLA aliases
 input to output.
 
-In-page element layout (cache dtype, default bf16)::
+In-page element layout (cache dtype, default bf16).  A ``kv`` page::
 
     [ K: (Hkv, page_tokens, head_dim) ][ V: same ][ page padding ]
+
+A ``latent`` page, in rows of ``lane = min(128, kv_lora_rank)`` elements
+(one lane-dense row of the chip's arena view at Moonlight's 512 + 64)::
+
+    [ c[:, j*lane:(j+1)*lane] for j < kv_lora_rank / lane: (page_tokens, lane) each ]
+    [ k_pe, pack = lane / rope to a row: (page_tokens / pack, lane) ][ padding ]
+
+where token ``t``'s ``k_pe`` is in row ``t % (page_tokens / pack)``, lanes
+``[q * rope, (q + 1) * rope)`` with ``q = t // (page_tokens / pack)``.
 
 Host-side ownership is a free-list :class:`KVPageAllocator` plus a
 per-sequence :class:`PageTable` — ``table[slot, block, layer]`` is the page
@@ -40,32 +57,51 @@ from repro.configs.base import ModelConfig
 from repro.mem.layout import PAGE_BYTES, ArenaLayout, plan_arena
 
 
+PAGE_KINDS = ("kv", "latent")
+
+
+def page_kind(cfg: ModelConfig) -> str:
+    """``latent`` for multi-head latent attention, else ``kv``."""
+    return "latent" if cfg.attn is not None and cfg.attn.is_mla else "kv"
+
+
 def kv_page_payload_elems(cfg: ModelConfig, page_tokens: int) -> int:
-    """Used elements of one KV page: K + V for one layer's page_tokens."""
+    """Used elements of one page: K + V of every KV head (``kv``), or one
+    latent row and RoPE key a token (``latent``), for one layer's
+    ``page_tokens``."""
     a = cfg.attn
+    if page_kind(cfg) == "latent":
+        return page_tokens * (a.kv_lora_rank + a.qk_rope_head_dim)
     return 2 * a.num_kv_heads * page_tokens * a.head_dim
 
 
 def _require_pageable(cfg: ModelConfig) -> None:
-    """Paged decode covers decoder-only, all-global-attention transformers.
+    """Paged decode covers decoder-only transformers whose every layer is
+    global attention of one page kind: grouped-query attention (``kv``
+    pages: K and V per KV head) or multi-head latent attention (``latent``
+    pages: one latent row and RoPE key a token, shared by the heads).
 
     Rolling window/chunk caches reuse slots out of order (their validity
     mask depends on the wrap position), which a page table keyed by
     absolute block index cannot express; SSM/hybrid carry non-KV decode
-    state.  Every unsupported family fails loudly here, at plan time.
+    state.  Every unsupported kind fails loudly here, at plan time, and
+    names the kind it met.
     """
     if cfg.attn is None or cfg.family not in ("dense", "moe") \
             or cfg.frontend is not None or cfg.enc_layers:
         raise NotImplementedError(
-            f"paged KV serving is decoder-only (family={cfg.family!r}, "
-            f"frontend={cfg.frontend!r})")
+            f"paged serving holds kv or latent pages of decoder-only "
+            f"attention stacks; met family={cfg.family!r}, "
+            f"frontend={cfg.frontend!r}, encoder layers={cfg.enc_layers}")
     for i in range(cfg.num_layers):
         kind = cfg.layer_kind(i)
         if kind["mixer"] != "attn" or not kind.get("attn_global", True):
+            met = (kind["mixer"] if kind["mixer"] != "attn" else
+                   f"local attention (window={cfg.attn.window}, "
+                   f"chunk={cfg.attn.chunk})")
             raise NotImplementedError(
-                f"paged KV serving needs global attention at every layer; "
-                f"layer {i} is {kind['mixer']}/local (window={cfg.attn.window}, "
-                f"chunk={cfg.attn.chunk})")
+                f"paged serving holds kv or latent pages of global attention "
+                f"at every layer; layer {i} is {met}")
 
 
 @dataclass(frozen=True)
@@ -78,8 +114,10 @@ class KVArenaPlan:
     max_blocks: int              # page-table columns (model-axis padded)
     n_layers: int
     num_kv_heads: int
-    head_dim: int
+    head_dim: int                # kv: per head; latent: kv_lora_rank
     model_parallel: int          # model-axis size the block dim tiles
+    kind: str = "kv"             # page kind: "kv" | "latent"
+    rope_dim: int = 0            # latent: qk_rope_head_dim
 
     # -- shape ---------------------------------------------------------------
 
@@ -104,6 +142,16 @@ class KVArenaPlan:
     @property
     def v_offset(self) -> int:
         return self.num_kv_heads * self.page_tokens * self.head_dim
+
+    @property
+    def lane(self) -> int:
+        """Latent pages: elements in a row of the page layout."""
+        return min(128, self.head_dim)
+
+    @property
+    def rope_pack(self) -> int:
+        """Latent pages: ``k_pe`` rows held in one lane row."""
+        return self.lane // self.rope_dim
 
     @property
     def total_elems(self) -> int:
@@ -143,6 +191,7 @@ class KVArenaPlan:
             "num_kv_heads": self.num_kv_heads,
             "head_dim": self.head_dim,
             "model_parallel": self.model_parallel,
+            "kind": self.kind,
             "n_kv_pages": self.n_kv_pages,
             "page_stride": self.page_stride,
             "payload_elems": self.payload_elems,
@@ -182,11 +231,28 @@ def plan_kv_arena(cfg: ModelConfig, mesh: Mesh | None = None, *,
     payload = kv_page_payload_elems(cfg, page_tokens)
     layout = plan_arena([payload] * n_pages, page_bytes=page_bytes,
                         dtype=cache_dtype, channel_of=[0] * n_pages)
+    a = cfg.attn
+    if page_kind(cfg) == "latent":
+        plan = KVArenaPlan(layout=layout, page_tokens=page_tokens,
+                           max_seqs=max_seqs, max_blocks=max_blocks,
+                           n_layers=cfg.num_layers, num_kv_heads=1,
+                           head_dim=a.kv_lora_rank, model_parallel=mp,
+                           kind="latent", rope_dim=a.qk_rope_head_dim)
+        if plan.head_dim % plan.lane or plan.lane % plan.rope_dim \
+                or page_tokens % plan.rope_pack \
+                or plan.page_stride % plan.lane:
+            raise ValueError(
+                f"latent pages of {page_tokens} tokens hold rows of "
+                f"{plan.lane} elements: kv_lora_rank={a.kv_lora_rank} and "
+                f"the page stride {plan.page_stride} must be whole rows, "
+                f"and qk_rope_head_dim={a.qk_rope_head_dim} must divide a "
+                f"row and page_tokens / {plan.rope_pack} keys fill a row")
+        return plan
     return KVArenaPlan(layout=layout, page_tokens=page_tokens,
                        max_seqs=max_seqs, max_blocks=max_blocks,
                        n_layers=cfg.num_layers,
-                       num_kv_heads=cfg.attn.num_kv_heads,
-                       head_dim=cfg.attn.head_dim, model_parallel=mp)
+                       num_kv_heads=a.num_kv_heads,
+                       head_dim=a.head_dim, model_parallel=mp)
 
 
 class KVPageAllocator:

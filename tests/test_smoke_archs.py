@@ -57,11 +57,11 @@ def test_full_config_registered(arch):
 
 
 def test_assigned_cell_count():
-    """40 assigned cells = 34 runnable + 6 documented long_500k skips."""
+    """44 assigned cells = 37 runnable + 7 documented long_500k skips."""
     total = sum(4 for _ in ARCHS)
     runnable = sum(len(applicable_shapes(get_config(a))) for a in ARCHS)
-    assert total == 40
-    assert runnable == 34
+    assert total == 44
+    assert runnable == 37
 
 
 def test_arch_exact_hyperparams():
@@ -80,6 +80,10 @@ def test_arch_exact_hyperparams():
     assert get_config("llama4-maverick-400b-a17b").moe.num_experts == 128
     assert get_config("hymba-1.5b").attn.num_kv_heads == 5
     assert get_config("qwen2-7b").attn.qkv_bias is True
+    moon = get_config("moonlight-16b-a3b")
+    assert (moon.attn.kv_lora_rank, moon.attn.qk_rope_head_dim,
+            moon.moe.num_experts, moon.moe.top_k, moon.first_k_dense) == \
+        (512, 64, 64, 6, 1)
 
 
 def test_param_counts_in_range():
@@ -95,6 +99,7 @@ def test_param_counts_in_range():
         "minicpm-2b": (2.2e9, 3.3e9),
         "hymba-1.5b": (1.3e9, 2.1e9),
         "whisper-base": (0.05e9, 0.15e9),
+        "moonlight-16b-a3b": (15.5e9, 16.5e9),
     }
     for arch, (lo, hi) in expect.items():
         n = build_model(get_config(arch)).param_count()
