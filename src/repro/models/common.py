@@ -110,15 +110,20 @@ def embed_init(key, vocab: int, d: int, *, dtype=jnp.float32) -> dict:
 
 
 def embed(p: dict, tokens: jax.Array, compute_dtype, ctx, global_vocab: int) -> jax.Array:
-    """Vocab-parallel embedding: local table shard, masked take, psum."""
-    table = p["table"].astype(compute_dtype)
+    """Vocab-parallel embedding: local table shard, masked take, psum.
+
+    The rows are gathered at the table's stored dtype and cast after: a
+    cast of the table before the take would convert the whole table on
+    every call to read a few rows of it."""
+    table = p["table"]
     v_local = table.shape[0]
     if v_local == global_vocab:
-        return jnp.take(table, tokens, axis=0)
+        return jnp.take(table, tokens, axis=0).astype(compute_dtype)
     off = ctx.model_index() * v_local
     idx = tokens - off
     valid = (idx >= 0) & (idx < v_local)
-    out = jnp.take(table, jnp.clip(idx, 0, v_local - 1), axis=0)
+    out = jnp.take(table, jnp.clip(idx, 0, v_local - 1),
+                   axis=0).astype(compute_dtype)
     out = jnp.where(valid[..., None], out, 0)
     return ctx.psum(out)
 

@@ -393,9 +393,16 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
             with jax.named_scope("qkv_proj"):
                 h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
                 n_hq = pa["wq"]["w"].shape[1] // hd
-                q = _split_heads(dense(pa["wq"], h, cdt), n_hq)
-                k1 = _split_heads(dense(pa["wk"], h, cdt), hkv)
-                v1 = _split_heads(dense(pa["wv"], h, cdt), hkv)
+                # The barrier keeps the head split's layout from reaching
+                # back through each dot to its weight: without it the TPU
+                # compiler writes a converted, relaid-out copy of wq, wk
+                # and wv every step instead of reading them in place.
+                q, k1, v1 = lax.optimization_barrier(
+                    (dense(pa["wq"], h, cdt), dense(pa["wk"], h, cdt),
+                     dense(pa["wv"], h, cdt)))
+                q = _split_heads(q, n_hq)
+                k1 = _split_heads(k1, hkv)
+                v1 = _split_heads(v1, hkv)
                 q = apply_rope(q, posb, cfg.attn.rope_theta)
                 k1 = apply_rope(k1, posb, cfg.attn.rope_theta)
             with jax.named_scope("kv_write"):

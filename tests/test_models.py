@@ -189,3 +189,53 @@ def test_moe_drop_fraction_concentrated_routing():
     # balanced routing under the same capacity: nothing dropped
     bal = jnp.broadcast_to((jnp.arange(s) % e)[None, :, None], (2, s, k))
     assert float(moe_mod.dropped_fraction(bal, e, cap)) == 0.0
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_embed_gathers_rows_before_it_casts(sharded):
+    """An fp32 table read at bf16: ``embed`` returns exactly the rows of
+    the table cast whole, and converts no array of the table's shape (it
+    casts the gathered rows).  The vocab-parallel branch (a table shard
+    smaller than the vocabulary) still sums its masked rows at bf16."""
+    from jax.sharding import AxisType
+    from jax.sharding import PartitionSpec as P
+
+    from repro.models.common import embed
+    from repro.models.parallel import ParallelCtx
+
+    v, d = 96, 32
+    table = jax.random.normal(jax.random.PRNGKey(0), (v, d), jnp.float32)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (5, 1), 0, v)
+    want = table.astype(jnp.bfloat16)[tokens]
+    if sharded:
+        mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
+        ctx = ParallelCtx(model_axis="model")
+        fn = jax.shard_map(
+            lambda t, x: embed({"table": t}, x, jnp.bfloat16, ctx, 2 * v),
+            mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
+        off = (jnp.arange(5) % 2)[:, None]          # odd rows off the shard
+        want = jnp.where(off[..., None] == 0, want, 0)
+        tokens = tokens + v * off
+    else:
+        fn = lambda t, x: embed({"table": t}, x, jnp.bfloat16, SINGLE, v)  # noqa: E731
+    got = fn(table, tokens)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    eqns = list(_eqns(jax.make_jaxpr(fn)(table, tokens).jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "convert_element_type"
+                and e.invars[0].aval.shape == table.shape]
+    sums = [e for e in eqns if e.primitive.name == "psum"]
+    assert bool(sums) == sharded
+    assert {e.invars[0].aval.dtype for e in sums} <= {jnp.dtype(jnp.bfloat16)}

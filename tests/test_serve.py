@@ -348,7 +348,7 @@ def test_kernel_step_refuses_pages_the_chip_cannot_tile():
 # ---------------------------------------------------------------------------
 
 
-def _engine(attn_impl="ref", **plan_kw):
+def _engine(attn_impl="ref", dtype="float32", **plan_kw):
     import jax
     from jax.sharding import AxisType
     from repro.configs import reduced_config
@@ -357,7 +357,7 @@ def _engine(attn_impl="ref", **plan_kw):
 
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
-    model = build_model(reduced_config("llama3.2-1b"))
+    model = build_model(reduced_config("llama3.2-1b").with_(dtype=dtype))
     params = model.init(jax.random.PRNGKey(0))
     plan_kw.setdefault("page_tokens", 8)
     plan_kw.setdefault("page_bytes", 4096)
@@ -389,6 +389,63 @@ def test_paged_matches_contiguous_decode(rng, attn_impl):
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=2e-2, atol=2e-3,
             err_msg=f"step {t} ({attn_impl})")
+
+
+@pytest.mark.parametrize("attn_impl", ["ref", "kernel"])
+def test_dense_step_keeps_the_cast_then_split_values(rng, attn_impl,
+                                                     monkeypatch):
+    """fp32 weights at bf16 compute: the step (rows gathered before they
+    are cast, q/k/v behind a barrier before the head split) gives the
+    logits and pages of the formula it replaced (the table cast whole
+    before the take, the projections split straight away), bit for bit
+    over steps that cross a page; and the first step's layer-0 K/V rows
+    are ``_split_heads(dense(...))`` of the cast embedding, with RoPE."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.attention import _split_heads
+    from repro.models.common import apply_rope, dense, rmsnorm
+    from repro.serve import engine as engine_mod
+
+    model, params, eng = _engine(attn_impl=attn_impl, dtype="bfloat16")
+
+    def cast_then_take(p, tokens, compute_dtype, ctx, global_vocab):
+        return jnp.take(p["table"].astype(compute_dtype), tokens, axis=0)
+
+    _, _, old = _engine(attn_impl=attn_impl, dtype="bfloat16")
+    assert jax.tree.leaves(params)[0].dtype == jnp.float32
+    assert eng.pages.dtype == jnp.bfloat16
+    b = eng.plan.max_seqs
+    for s in range(b):
+        eng.admit(s)
+        old.admit(s)
+    toks = rng.randint(0, model.cfg.vocab_size, (10, b)).astype(np.int32)
+    for t in range(10):
+        got = eng.decode(params, toks[t])
+        with monkeypatch.context() as mp:   # the first call traces the step
+            mp.setattr(engine_mod, "embed", cast_then_take)
+            mp.setattr(engine_mod.lax, "optimization_barrier", lambda xs: xs)
+            want = old.decode(params, toks[t])
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        np.testing.assert_array_equal(np.asarray(eng.pages, np.float32),
+                                      np.asarray(old.pages, np.float32))
+        if t == 0:
+            cfg, pa = model.cfg, params["blocks"][0]["attn"]
+            x = jnp.take(params["embed"]["table"].astype(jnp.bfloat16),
+                         jnp.asarray(toks[0])[:, None], axis=0)
+            h = rmsnorm(params["blocks"][0]["ln1"], x, cfg.norm_eps)
+            pos = jnp.zeros((b, 1), jnp.int32)
+            hkv = cfg.attn.num_kv_heads
+            k, v, _ = engine_mod._gather_local_kv(
+                eng.pages, eng.plan, 0, jnp.asarray(eng.table.table), 0)
+            want_k = apply_rope(_split_heads(dense(pa["wk"], h, jnp.bfloat16),
+                                             hkv), pos, cfg.attn.rope_theta)
+            want_v = _split_heads(dense(pa["wv"], h, jnp.bfloat16), hkv)
+            np.testing.assert_array_equal(np.asarray(k[:, :, :1], np.float32),
+                                          np.asarray(want_k, np.float32))
+            np.testing.assert_array_equal(np.asarray(v[:, :, :1], np.float32),
+                                          np.asarray(want_v, np.float32))
 
 
 def test_engine_slot_lifecycle_and_page_recycling(rng):

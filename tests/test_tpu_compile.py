@@ -152,28 +152,11 @@ def test_ring_reduce_of_a_tiled_param_compiles_in_seconds(topo):
     assert time.perf_counter() - t0 < 60
 
 
-def test_paged_step_kernel_maps_to_the_flash_decode_scope(topo):
-    """The paged decode step compiled for a v5e keeps the kernel, and the
-    kernel's ``tpu_custom_call`` maps to the ``flash_decode`` scope: the
-    name a chip trace gives the kernel finds its scope."""
-    import dataclasses
-    import re
+def _compiled_step_text(model, mesh, plan) -> str:
+    """The paged decode step (kernel path) compiled for ``mesh``'s described
+    chip, as the compiler's text."""
+    from repro.serve.engine import build_paged_decode_step
 
-    from jax.sharding import Mesh
-
-    from repro.configs import reduced_config
-    from repro.models import build_model
-    from repro.serve import plan_kv_arena
-    from repro.serve.engine import build_paged_decode_step, instruction_scopes
-
-    base = reduced_config("llama3.2-1b")
-    cfg = base.with_(d_model=256, dtype="bfloat16",
-                     attn=dataclasses.replace(base.attn, num_heads=4,
-                                              num_kv_heads=2, head_dim=128))
-    model = build_model(cfg)
-    mesh = Mesh([[topo.devices[0]]], ("data", "model"))
-    plan = plan_kv_arena(cfg, mesh, page_tokens=128, page_bytes=2**16,
-                         max_seqs=8, max_seq_len=512)
     step, _, _ = build_paged_decode_step(model, mesh, plan,
                                          attn_impl="kernel", interpret=False)
     rep = NamedSharding(mesh, P())
@@ -187,7 +170,32 @@ def test_paged_step_kernel_maps_to_the_flash_decode_scope(topo):
                          model.abstract_params()),
             sds((s, plan.max_blocks, plan.n_layers), jnp.int32),
             sds((s,), jnp.int32), sds((s,), jnp.int32), sds((s,), jnp.bool_))
-    text = step.lower(*args).compile().as_text()
+    return step.lower(*args).compile().as_text()
+
+
+def test_paged_step_kernel_maps_to_the_flash_decode_scope(topo):
+    """The paged decode step compiled for a v5e keeps the kernel, and the
+    kernel's ``tpu_custom_call`` maps to the ``flash_decode`` scope: the
+    name a chip trace gives the kernel finds its scope."""
+    import dataclasses
+    import re
+
+    from jax.sharding import Mesh
+
+    from repro.configs import reduced_config
+    from repro.models import build_model
+    from repro.serve import plan_kv_arena
+    from repro.serve.engine import instruction_scopes
+
+    base = reduced_config("llama3.2-1b")
+    cfg = base.with_(d_model=256, dtype="bfloat16",
+                     attn=dataclasses.replace(base.attn, num_heads=4,
+                                              num_kv_heads=2, head_dim=128))
+    model = build_model(cfg)
+    mesh = Mesh([[topo.devices[0]]], ("data", "model"))
+    plan = plan_kv_arena(cfg, mesh, page_tokens=128, page_bytes=2**16,
+                         max_seqs=8, max_seq_len=512)
+    text = _compiled_step_text(model, mesh, plan)
     kernels = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
                          r'"tpu_custom_call"', text)
     assert len(kernels) == plan.n_layers
@@ -208,9 +216,7 @@ def test_latent_step_kernel_is_named_mla_decode(topo):
     from repro.configs import reduced_config
     from repro.models import build_model
     from repro.serve import plan_kv_arena
-    from repro.serve.engine import (LATENT_STEP_SCOPES,
-                                    build_paged_decode_step,
-                                    instruction_scopes)
+    from repro.serve.engine import LATENT_STEP_SCOPES, instruction_scopes
 
     base = reduced_config("moonlight-16b-a3b")
     cfg = base.with_(d_model=256, dtype="bfloat16", param_dtype="bfloat16",
@@ -221,20 +227,7 @@ def test_latent_step_kernel_is_named_mla_decode(topo):
     mesh = Mesh([[topo.devices[0]]], ("data", "model"))
     plan = plan_kv_arena(cfg, mesh, page_tokens=128, page_bytes=2**17,
                          max_seqs=8, max_seq_len=512)
-    step, _, _ = build_paged_decode_step(model, mesh, plan,
-                                         attn_impl="kernel", interpret=False)
-    rep = NamedSharding(mesh, P())
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
-
-    s = plan.max_seqs
-    args = (sds((plan.total_elems,), plan.layout.dtype),
-            jax.tree.map(lambda a: sds(a.shape, a.dtype),
-                         model.abstract_params()),
-            sds((s, plan.max_blocks, plan.n_layers), jnp.int32),
-            sds((s,), jnp.int32), sds((s,), jnp.int32), sds((s,), jnp.bool_))
-    text = step.lower(*args).compile().as_text()
+    text = _compiled_step_text(model, mesh, plan)
     kernels = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
                          r'"tpu_custom_call"', text)
     assert len(kernels) == plan.n_layers
@@ -242,3 +235,72 @@ def test_latent_step_kernel_is_named_mla_decode(topo):
     scopes = instruction_scopes(text)
     assert {scopes[k] for k in kernels} == {"mla_decode"}
     assert set(scopes.values()) == set(LATENT_STEP_SCOPES) - {"kv_gather"}
+
+
+def test_dense_paged_step_reads_each_weight_in_place(topo):
+    """The dense paged decode step at Phi-3-medium's published widths (two
+    of its layers, 48 slots, fp32 weights, bf16 compute) compiled for a
+    v5e writes no step-sized copy of a weight: every instruction of the
+    entry computation with as many elements as a weight is that weight's
+    parameter, a piece of its asynchronous prefetch (same dtype, no
+    conversion), or the dot or gather fusion that consumes it.  The
+    ``embed`` scope holds nothing of the table's size but the table: the
+    rows are gathered before they are cast."""
+    import math
+    import re
+
+    from jax.sharding import Mesh
+
+    from repro.configs import get_config
+    from repro.configs.base import AttnConfig
+    from repro.models import build_model
+    from repro.serve import plan_kv_arena
+    from repro.serve.engine import instruction_scopes
+    from repro.serve.kv import kv_page_payload_elems
+
+    cfg = get_config("phi3-medium-14b").with_(
+        num_layers=2, d_model=5120, d_ff=17920, vocab_size=32064,
+        attn=AttnConfig(num_heads=40, num_kv_heads=10, head_dim=128,
+                        rope_theta=1e4),
+        tie_embeddings=False, param_dtype="float32", dtype="bfloat16")
+    model = build_model(cfg)
+    mesh = Mesh([[topo.devices[0]]], ("data", "model"))
+    plan = plan_kv_arena(model.cfg, mesh, page_tokens=128,
+                         page_bytes=kv_page_payload_elems(model.cfg, 128) * 2,
+                         max_seqs=48, max_seq_len=1792)
+    text = _compiled_step_text(model, mesh, plan)
+    params = model.abstract_params()
+
+    weights = [a for a in jax.tree.leaves(params) if a.ndim == 2]
+    assert {a.dtype for a in weights} == {jnp.dtype("float32")}
+    sizes = {math.prod(a.shape) for a in weights}
+    table = math.prod(params["embed"]["table"].shape)
+    inst = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+)\[([\d,]*)\]"
+                      r"(?:\{[^}]*\})?\s+([\w\-]+)\(")
+    entry = text[text.index("\nENTRY"):]
+    copies, by_name = [], {}
+    for line in entry.splitlines()[1:]:
+        m = inst.match(line)
+        if m is None:
+            continue
+        name, dtype, dims, opcode = m.groups()
+        n = math.prod(int(x) for x in dims.split(",") if x)
+        by_name[name] = (n, opcode)
+        if n not in sizes:
+            continue
+        kind = re.search(r"\bkind=(\w+)", line)
+        target = re.search(r'custom_call_target="(\w+)"', line)
+        consumer = opcode == "fusion" and kind and \
+            kind.group(1) in ("kOutput", "kCustom")
+        prefetch = dtype == "f32" and (
+            opcode in ("parameter", "bitcast", "copy-start", "copy-done")
+            or (opcode == "custom-call" and target
+                and target.group(1) == "ConcatBitcast"))
+        if not (consumer or prefetch):
+            copies.append(line.strip()[:160])
+    assert copies == []
+    scopes = instruction_scopes(text)
+    in_embed = [k for k, (n, op) in by_name.items()
+                if scopes.get(k) == "embed" and n == table
+                and op != "parameter"]
+    assert in_embed == []
