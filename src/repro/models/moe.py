@@ -40,7 +40,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
-from repro.models.common import activation, dense_init, trunc_normal
+from repro.models.common import (activation, dense_init, glu_mlp,
+                                 glu_mlp_init, trunc_normal)
 
 
 def moe_init(key, cfg: MoEConfig, d_model: int, *, dtype=jnp.float32) -> dict:
@@ -54,8 +55,6 @@ def moe_init(key, cfg: MoEConfig, d_model: int, *, dtype=jnp.float32) -> dict:
         "w_down": trunc_normal(k4, (e, f, d_model), 1.0 / math.sqrt(f), dtype),
     }
     if cfg.shared_expert_ff:
-        from repro.models.common import glu_mlp_init
-
         p["shared"] = glu_mlp_init(k5, d_model, cfg.shared_expert_ff, dtype=dtype)
     if cfg.scoring == "sigmoid":
         p["score_bias"] = jnp.zeros((cfg.num_experts,), dtype)
@@ -101,14 +100,6 @@ def route(p: dict, x: jax.Array, cfg: MoEConfig, compute_dtype
     return w, ids, jax.nn.softmax(logits, axis=-1)
 
 
-def held_gate(w: jax.Array, ids: jax.Array, cfg: MoEConfig) -> jax.Array:
-    """(..., top_k) weights and ids -> (..., held) gate of each held
-    expert: its weight where the token chose it, else zero."""
-    mine = cfg.first_expert + jnp.arange(held_count(cfg))
-    return jnp.sum(jnp.where(ids[..., None] == mine, w[..., None], 0.0),
-                   axis=-2)
-
-
 def held_experts(p: dict, x: jax.Array, gate: jax.Array, act: str,
                  compute_dtype) -> jax.Array:
     """sum_e gate[..., e] * expert_e(x) over the held experts, every token
@@ -119,6 +110,33 @@ def held_experts(p: dict, x: jax.Array, gate: jax.Array, act: str,
     h = activation(act)(g) * u * gate[..., None].astype(compute_dtype)
     return jnp.einsum("tef,efd->td", h, p["w_down"].astype(compute_dtype),
                       preferred_element_type=jnp.float32)
+
+
+def held_moe(p: dict, x: jax.Array, cfg: MoEConfig, act: str, compute_dtype,
+             valid: jax.Array | None = None, held=0
+             ) -> tuple[jax.Array, jax.Array]:
+    """The layer's held share at tokens x (T, d): route over all experts
+    (scope ``moe_router``), :func:`held_experts` (``moe_experts``), plus
+    the shared experts (``moe_shared``).  -> ``(y (T, d), held)``, the
+    count ``held`` plus the (token, expert) choices on held experts of the
+    tokens ``valid`` (T,) marks (None: all)."""
+    with jax.named_scope("moe_router"):
+        w, ids, _ = route(p, x, cfg, compute_dtype)
+        # each held expert's weight where the token chose it, else zero
+        experts = cfg.first_expert + jnp.arange(held_count(cfg))
+        gate = jnp.sum(jnp.where(ids[..., None] == experts, w[..., None],
+                                 0.0), axis=-2)                  # (T, held)
+        mine = (ids >= cfg.first_expert) & (
+            ids < cfg.first_expert + held_count(cfg))
+        if valid is not None:
+            mine = mine & valid[:, None]
+        held = held + jnp.sum(mine, dtype=jnp.int32)
+    with jax.named_scope("moe_experts"):
+        y = held_experts(p, x, gate, act, compute_dtype)
+    if "shared" in p:
+        with jax.named_scope("moe_shared"):
+            y = y + glu_mlp(p["shared"], x, act, compute_dtype)
+    return y, held
 
 
 def capacity(tokens_per_row: int, cfg: MoEConfig) -> int:
@@ -176,9 +194,8 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, act: str, *, ctx,
     * EP (fallback): replicated buffer, slice own experts, zero-pad,
       psum — the honest replicated cost, also used by transport="psum".
     * TP: every rank runs all experts on its ffn shard; psum after w_down.
-    * A held share (``cfg.experts_held``): :func:`held_experts` over the
-      layer's own experts, plus the shared experts; no exchange, nothing
-      dropped.
+    * A held share (``cfg.experts_held``): :func:`held_moe`; no exchange,
+      nothing dropped.
     """
     b, s, d = x.shape
     e, k = cfg.num_experts, cfg.top_k
@@ -191,15 +208,10 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, act: str, *, ctx,
 
     gate_w, expert_ids, gates_all = route(p, xf, cfg, compute_dtype)
     aux = load_balance_aux(gates_all, expert_ids, e, k)
-    if cfg.experts_held is not None:
-        y = held_experts(p, xf.reshape(b * s, d),
-                         held_gate(gate_w, expert_ids, cfg).reshape(b * s, -1),
-                         act, compute_dtype).reshape(b, s, d)
-        if "shared" in p:
-            from repro.models.common import glu_mlp
-
-            y = y + glu_mlp(p["shared"], xf, act, compute_dtype)
-        return y.astype(x.dtype), aux, jnp.zeros((), jnp.float32)
+    if cfg.experts_held is not None:     # held_moe routes again
+        y, _ = held_moe(p, xf.reshape(b * s, d), cfg, act, compute_dtype)
+        return (y.reshape(b, s, d).astype(x.dtype), aux,
+                jnp.zeros((), jnp.float32))
     drop_frac = dropped_fraction(expert_ids, e, cap)
 
     # ---- sort-based dispatch, vmapped over batch rows ----
@@ -279,8 +291,6 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, act: str, *, ctx,
             y = ctx.psum(y)
 
     if "shared" in p:
-        from repro.models.common import glu_mlp
-
         xs = ctx.fan_out(xf) if p["shared"]["w_down"]["w"].shape[0] < \
             cfg.shared_expert_ff else xf
         y = y + glu_mlp(p["shared"], xs, act, compute_dtype, ctx,

@@ -28,11 +28,12 @@ KV cache.  The design commitments, in paper terms:
 Admission, eviction and page recycling are host-side (numpy) and change no
 traced shape, so the step compiles once per ``(plan, arch)``.
 
-An arena of ``latent`` pages (multi-head latent attention, see
-:mod:`repro.serve.kv`) gets its own block: the queries are absorbed
-through ``W_UK`` before the ``mla_decode`` kernel scores each slot's live
-latent pages in place (the same page walk), ``W_UV`` and ``W_o`` follow,
-and an expert layer runs its held experts (:mod:`repro.models.moe`).
+One step serves both page kinds of :mod:`repro.serve.kv`: the arena's
+kind picks, from :data:`_PAGE_KINDS`, the parts that differ.  ``latent``
+pages (multi-head latent attention) are scored by ``mla_decode`` with the
+same page walk, the queries absorbed through ``W_UK`` before it, ``W_UV``
+and ``W_o`` after.  Every expert layer runs its held experts
+(:func:`repro.models.moe.held_moe`).
 
 Each part of the step runs under a ``jax.named_scope`` of
 :data:`STEP_SCOPES` (no layer index), which lands in the compiled
@@ -44,6 +45,7 @@ host side runs under the ``serve.*`` spans of its ``obs``.
 from __future__ import annotations
 
 import collections
+import math
 import re
 
 import jax
@@ -93,10 +95,11 @@ def predicted_wire_bytes_per_token(plan: KVArenaPlan, cfg: ModelConfig,
     return plan.n_layers * per_layer * hops
 
 
-# The named scopes of the decode step, in the order a layer runs them.
-# ``kv_gather`` and ``gqa_expand`` exist only on the ``ref`` path (the
-# kernel reads the pages in place), ``attn_merge`` only with a model axis
-# of more than one rank.
+# The named scopes of a step over K/V pages, in the order a layer runs
+# them.  ``kv_gather`` and ``gqa_expand`` exist only on the ``ref`` path
+# (the kernel reads the pages in place), ``attn_merge`` only with a model
+# axis of more than one rank.  An expert layer, on either page kind, runs
+# the ``moe_*`` scopes of :data:`LATENT_STEP_SCOPES` in place of ``mlp``.
 STEP_SCOPES = ("embed", "qkv_proj", "kv_write", "kv_gather", "gqa_expand",
                "flash_decode", "attn_merge", "o_proj", "mlp", "lm_head")
 # The named scopes of a step over latent pages (multi-head latent
@@ -233,13 +236,14 @@ def _gather_local_kv(pages, plan: KVArenaPlan, layer: int, table, rank):
 
 def _write_token_latent(pages, plan: KVArenaPlan, layer: int, table,
                         slot_len, slot_valid, c1, k1):
-    """Write this step's latent row ``c1`` (B, r) and RoPE key ``k1``
-    (B, dr) into each slot's current latent page (layout in
+    """Write this step's latent row ``c1`` (B, 1, r) and RoPE key ``k1``
+    (B, 1, dr) into each slot's current latent page (layout in
     :mod:`repro.serve.kv`), on the arena's view as rows of ``lane``
     elements: ``r / lane`` whole rows, and the key into its lanes of a
     packed row (the row's other keys, other positions of the same slot,
     are read and written back unchanged).  Invalid slots or unmapped
     blocks write out of bounds, which the scatter drops."""
+    c1, k1 = c1[:, 0], k1[:, 0]
     pt, lane, pack, dr = (plan.page_tokens, plan.lane, plan.rope_pack,
                           plan.rope_dim)
     nc, krows = plan.head_dim // lane, pt // pack
@@ -293,38 +297,26 @@ def _local_valid(plan: KVArenaPlan, tab, slot_len, slot_valid, rank):
 
 
 # ---------------------------------------------------------------------------
-# the step
+# the step: one skeleton, and what each page kind plugs into it
 # ---------------------------------------------------------------------------
 
 
-def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
-                            attn_impl: str = "kernel",
-                            interpret: bool | None = None,
-                            donate: bool = True):
-    """Returns ``(step, param_specs, state_specs)`` with
-    ``step(pages, params, table, token, slot_len, slot_valid) ->
-    (logits (B, vocab), pages)``; ``pages`` is donated (argument 0).
+# A page kind's parts of the step, each under its own scopes.  ``mix_in(bp,
+# x, posb) -> (q, entry)``: the layer norm, the projections and RoPE;
+# ``write(pages, plan, layer, table, slot_len, slot_valid, *entry)`` puts
+# the token's cache entry into its page; ``kernel`` (the pages read in
+# place) or ``oracle`` (for ``attn_impl="ref"``: gathered dense, scored in
+# jnp) ``(q, pages, layer, table, slot_len, slot_valid) -> o`` attends;
+# ``mix_out(bp, x, o) -> x`` adds the output projection to the residual;
+# ``check_tiling()`` refuses pages the compiled kernel cannot tile.
+_PageKind = collections.namedtuple(
+    "_PageKind", "mix_in write kernel oracle mix_out check_tiling")
 
-    ``params`` must be the full (un-sharded) tree — the engine replicates
-    weights over the model axis by design (see module docstring).
-    ``attn_impl``: "kernel" scores the live pages in place with the Pallas
-    paged flash-decode kernel, "ref" gathers them dense and scores them
-    with the jnp oracle (same math and identical collective footprint; the
-    dry-run uses "ref" to keep compile times sane).
-    """
-    if attn_impl not in ("kernel", "ref"):
-        raise ValueError(f"attn_impl must be kernel|ref, got {attn_impl!r}")
-    cfg = model.cfg
-    ctx = make_ctx(mesh)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    r_mesh = sizes.get("model", 1)
-    if r_mesh != plan.model_parallel:
-        raise ValueError(
-            f"plan was laid out for model_parallel={plan.model_parallel} "
-            f"but the mesh model axis is {r_mesh}; re-plan with this mesh")
-    if plan.kind == "latent":
-        return _build_latent_step(model, mesh, plan, ctx, attn_impl,
-                                  interpret, donate)
+
+def _kv_kind(cfg: ModelConfig, mesh: Mesh, plan: KVArenaPlan, ctx,
+             interpret: bool) -> _PageKind:
+    """Pages of K/V rows: each model rank scores its columns of the page
+    table with the paged flash-decode kernel; ``attn_merge`` merges them."""
     if plan.page_stride % plan.head_dim or plan.total_elems % plan.head_dim:
         raise ValueError(
             f"KV pages must hold whole head_dim={plan.head_dim} rows "
@@ -339,38 +331,58 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
     hkv, hd = cfg.attn.num_kv_heads, cfg.attn.head_dim
     true_group = max(cfg.attn.num_heads // hkv, 1)
     bpr, page_rows = plan.blocks_per_rank, plan.page_stride // hd
-    interpret = default_interpret() if interpret is None else interpret
-    if attn_impl == "kernel" and not interpret:
-        fd_ops.check_paged_tiling(hkv, plan.page_tokens, hd,
-                                  plan.page_stride)
 
-    def attend(q, pages, layer, table, slot_len, slot_valid):
+    def mix_in(bp, x, posb):
+        pa = bp["attn"]
+        with jax.named_scope("qkv_proj"):
+            h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
+            n_hq = pa["wq"]["w"].shape[1] // hd
+            # The barrier keeps the head split's layout from reaching
+            # back through each dot to its weight: without it the TPU
+            # compiler writes a converted, relaid-out copy of wq, wk
+            # and wv every step instead of reading them in place.
+            q, k1, v1 = lax.optimization_barrier(
+                (dense(pa["wq"], h, cdt), dense(pa["wk"], h, cdt),
+                 dense(pa["wv"], h, cdt)))
+            q = _split_heads(q, n_hq)
+            k1 = _split_heads(k1, hkv)
+            v1 = _split_heads(v1, hkv)
+            q = apply_rope(q, posb, cfg.attn.rope_theta)
+            k1 = apply_rope(k1, posb, cfg.attn.rope_theta)
+            return q, (k1, v1)
+
+    def kernel(q, pages, layer, table, slot_len, slot_valid):
         rank = ctx.model_index()
-        if attn_impl == "kernel":
-            with jax.named_scope("flash_decode"):
-                tab = lax.dynamic_slice_in_dim(table[:, :, layer], rank * bpr,
-                                               bpr, axis=1)       # (B, bpr)
-                acc, m, l = fd_ops.paged_decode_stats(
-                    q, pages.reshape(plan.n_kv_pages, page_rows, hd), tab,
-                    slot_len, slot_valid, rank * bpr, num_kv_heads=hkv,
-                    page_tokens=plan.page_tokens, group=true_group,
-                    interpret=interpret)
-        else:
-            with jax.named_scope("kv_gather"):
-                k, v, tab = _gather_local_kv(pages, plan, layer, table, rank)
-                valid = _local_valid(plan, tab, slot_len, slot_valid, rank)
-            with jax.named_scope("gqa_expand"):
-                # true-group GQA map (padded q heads clip to the last kv
-                # head) — expand kv per q head so the oracle runs group-free
-                kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0,
-                                  hkv - 1)
-                k = jnp.take(k, kv_idx, axis=1)
-                v = jnp.take(v, kv_idx, axis=1)
-            with jax.named_scope("flash_decode"):
-                acc, m, l = fd_ref.decode_stats(q, k, v, valid)
+        with jax.named_scope("flash_decode"):
+            tab = lax.dynamic_slice_in_dim(table[:, :, layer], rank * bpr,
+                                           bpr, axis=1)           # (B, bpr)
+            return merge(q, fd_ops.paged_decode_stats(
+                q, pages.reshape(plan.n_kv_pages, page_rows, hd), tab,
+                slot_len, slot_valid, rank * bpr, num_kv_heads=hkv,
+                page_tokens=plan.page_tokens, group=true_group,
+                interpret=interpret))
+
+    def oracle(q, pages, layer, table, slot_len, slot_valid):
+        rank = ctx.model_index()
+        with jax.named_scope("kv_gather"):
+            k, v, tab = _gather_local_kv(pages, plan, layer, table, rank)
+            valid = _local_valid(plan, tab, slot_len, slot_valid, rank)
+        with jax.named_scope("gqa_expand"):
+            # true-group GQA map (padded q heads clip to the last kv
+            # head) — expand kv per q head so the oracle runs group-free
+            kv_idx = jnp.clip(jnp.arange(q.shape[1]) // true_group, 0,
+                              hkv - 1)
+            k = jnp.take(k, kv_idx, axis=1)
+            v = jnp.take(v, kv_idx, axis=1)
+        with jax.named_scope("flash_decode"):
+            return merge(q, fd_ref.decode_stats(q, k, v, valid))
+
+    def merge(q, stats):
+        """This rank's softmax statistics -> the attention output."""
         if r == 1:
             with jax.named_scope("flash_decode"):
-                return fd_ref.combine([(acc, m, l)]).astype(q.dtype)
+                return fd_ref.combine([stats]).astype(q.dtype)
+        acc, m, l = stats
         with jax.named_scope("attn_merge"):
             m_g = ctx.pmax(m)
             w = jnp.exp(m - m_g)
@@ -382,121 +394,101 @@ def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
             den = red[n_num:].reshape(l.shape)
             return (num / jnp.maximum(den, 1e-30)).astype(q.dtype)
 
-    def fn(pages, params, table, token, slot_len, slot_valid):
-        with jax.named_scope("embed"):
-            x = embed(params["embed"], token[:, None], cdt, ctx,
-                      cfg.vocab_size)
-        posb = slot_len[:, None]                       # per-slot position
-        for i, bp in enumerate(params["blocks"]):
-            kind = cfg.layer_kind(i)
-            pa = bp["attn"]
-            with jax.named_scope("qkv_proj"):
-                h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
-                n_hq = pa["wq"]["w"].shape[1] // hd
-                # The barrier keeps the head split's layout from reaching
-                # back through each dot to its weight: without it the TPU
-                # compiler writes a converted, relaid-out copy of wq, wk
-                # and wv every step instead of reading them in place.
-                q, k1, v1 = lax.optimization_barrier(
-                    (dense(pa["wq"], h, cdt), dense(pa["wk"], h, cdt),
-                     dense(pa["wv"], h, cdt)))
-                q = _split_heads(q, n_hq)
-                k1 = _split_heads(k1, hkv)
-                v1 = _split_heads(v1, hkv)
-                q = apply_rope(q, posb, cfg.attn.rope_theta)
-                k1 = apply_rope(k1, posb, cfg.attn.rope_theta)
-            with jax.named_scope("kv_write"):
-                pages = _write_token_kv(pages, plan, i, table, slot_len,
-                                        slot_valid, k1, v1)
-            o = attend(q, pages, i, table, slot_len, slot_valid)
-            with jax.named_scope("o_proj"):
-                x = x + dense(pa["wo"], _merge_heads(o), cdt).astype(x.dtype)
-            if "moe" in bp or "mlp" in bp:
-                with jax.named_scope("mlp"):
-                    h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
-                    if kind["mlp"] == "moe":
-                        y, _, _ = moe_mod.moe_apply(bp["moe"], h2, cfg.moe,
-                                                    cfg.act, ctx=ctx,
-                                                    compute_dtype=cdt)
-                    else:
-                        y = glu_mlp(bp["mlp"], h2, cfg.act, cdt, ctx,
-                                    cfg.d_ff)
-                    x = x + y.astype(x.dtype)
-        with jax.named_scope("lm_head"):
-            x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-            if cfg.tie_embeddings:
-                logits = unembed(params["embed"], x, cdt)
-            else:
-                logits = dense(params["lm_head"], x, cdt)
-            return logits[:, 0], pages
+    def mix_out(bp, x, o):
+        with jax.named_scope("o_proj"):
+            return x + dense(bp["attn"]["wo"], _merge_heads(o),
+                             cdt).astype(x.dtype)
 
-    return _jit_step(fn, model, mesh, plan, donate)
+    return _PageKind(mix_in, _write_token_kv, kernel, oracle, mix_out,
+                     lambda: fd_ops.check_paged_tiling(
+                         hkv, plan.page_tokens, hd, plan.page_stride))
 
 
-def _jit_step(fn, model, mesh: Mesh, plan: KVArenaPlan, donate: bool,
-              extra_out: tuple = ()):
-    """``fn`` as the jitted, shard-mapped step (weights replicated, the
-    arena donated); ``extra_out``: specs of outputs after the pages."""
-    state_abs = {
-        "pages": jax.ShapeDtypeStruct((plan.total_elems,), plan.layout.dtype),
-        "page_table": jax.ShapeDtypeStruct(
-            (plan.max_seqs, plan.max_blocks, plan.n_layers), jnp.int32),
-        "slot_len": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.int32),
-        "slot_valid": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.bool_),
-    }
-    sspecs = shard_rules.decode_state_specs(state_abs, model.cfg, mesh,
-                                            plan.max_seqs)
-    pspecs = jax.tree.map(lambda _: P(), model.abstract_params())
-    sharded = jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(sspecs["pages"], pspecs, sspecs["page_table"], P(),
-                  sspecs["slot_len"], sspecs["slot_valid"]),
-        out_specs=(P(), sspecs["pages"]) + extra_out, check_vma=False)
-    step = jax.jit(sharded, donate_argnums=(0,) if donate else ())
-    return step, pspecs, sspecs
-
-
-def _build_latent_step(model, mesh: Mesh, plan: KVArenaPlan, ctx,
-                       attn_impl: str, interpret: bool | None, donate: bool):
-    """The step over latent pages: multi-head latent attention in its
-    absorbed form (``q_nope`` through ``W_UK`` before the kernel, ``W_UV``
-    and ``W_o`` after it), a dense MLP or an expert layer told which
-    experts it holds (:func:`repro.models.moe.held_experts`).  Its third
-    output counts the step's (live token, expert) choices that land on
-    held experts, over all expert layers."""
-    cfg = model.cfg
-    a, moe = cfg.attn, cfg.moe
+def _latent_kind(cfg: ModelConfig, mesh: Mesh, plan: KVArenaPlan, ctx,
+                 interpret: bool) -> _PageKind:
+    """Pages of latent rows, scored by the ``mla_decode`` kernel in the
+    absorbed form, on one model rank."""
     if plan.model_parallel > 1:
         raise NotImplementedError(
             "latent pages are served on one model rank (model axis of 1)")
+    a = cfg.attn
     cdt = jnp.dtype(cfg.dtype)
-    pt = plan.page_tokens
-    page_rows = plan.page_stride // plan.lane
+    pt, lane = plan.page_tokens, plan.lane
+    page_rows = plan.page_stride // lane
     sc = mla_mod.scale(a)
-    interpret = default_interpret() if interpret is None else interpret
-    if attn_impl == "kernel" and not interpret:
-        fd_ops.check_latent_tiling(pt, plan.lane, plan.head_dim,
-                                   plan.rope_pack, page_rows)
 
-    def attend(q_c, q_pe, pages, layer, table, slot_len, slot_valid):
-        if attn_impl == "kernel":
-            with jax.named_scope("mla_decode"):
-                acc, _, l = fd_ops.mla_decode_stats(
-                    q_c, q_pe,
-                    pages.reshape(plan.n_kv_pages, page_rows, plan.lane),
-                    table[:, :, layer], slot_len, slot_valid, 0,
-                    page_tokens=pt, rope_pack=plan.rope_pack, scale=sc,
-                    interpret=interpret)
-        else:
-            with jax.named_scope("kv_gather"):
-                c, k_pe, tab = _gather_local_latent(pages, plan, layer,
-                                                    table, 0)
-                valid = _local_valid(plan, tab, slot_len, slot_valid, 0)
-            with jax.named_scope("mla_decode"):
-                acc, _, l = mla_mod.latent_stats(q_c, q_pe, c, k_pe, valid,
-                                                 sc)
+    def mix_in(bp, x, posb):
+        pa = bp["mla"]
+        with jax.named_scope("mla_q"):
+            h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
+            q_nope, q_pe = mla_mod.query(pa, h, a, posb, cdt)
+            q_c = mla_mod.absorb_query(pa, q_nope[:, :, 0], a, cdt)
+            return (q_c, q_pe[:, :, 0]), mla_mod.latent(pa, h, a, posb,
+                                                        cfg.norm_eps, cdt)
+
+    def kernel(q, pages, layer, table, slot_len, slot_valid):
         with jax.named_scope("mla_decode"):
+            acc, _, l = fd_ops.mla_decode_stats(
+                *q, pages.reshape(plan.n_kv_pages, page_rows, lane),
+                table[:, :, layer], slot_len, slot_valid, 0, page_tokens=pt,
+                rope_pack=plan.rope_pack, scale=sc, interpret=interpret)
             return acc / jnp.maximum(l, 1e-30)
+
+    def oracle(q, pages, layer, table, slot_len, slot_valid):
+        with jax.named_scope("kv_gather"):
+            c, k_pe, tab = _gather_local_latent(pages, plan, layer, table, 0)
+            valid = _local_valid(plan, tab, slot_len, slot_valid, 0)
+        with jax.named_scope("mla_decode"):
+            acc, _, l = mla_mod.latent_stats(*q, c, k_pe, valid, sc)
+            return acc / jnp.maximum(l, 1e-30)
+
+    def mix_out(bp, x, o):
+        with jax.named_scope("mla_out"):
+            y = mla_mod.latent_out(bp["mla"], o, a, cdt)
+            return x + y[:, None].astype(x.dtype)
+
+    return _PageKind(mix_in, _write_token_latent, kernel, oracle, mix_out,
+                     lambda: fd_ops.check_latent_tiling(
+                         pt, lane, plan.head_dim, plan.rope_pack, page_rows))
+
+
+_PAGE_KINDS = {"kv": _kv_kind, "latent": _latent_kind}
+
+
+def build_paged_decode_step(model, mesh: Mesh, plan: KVArenaPlan, *,
+                            attn_impl: str = "kernel",
+                            interpret: bool | None = None):
+    """Returns ``(step, param_specs, state_specs)`` with
+    ``step(pages, params, table, token, slot_len, slot_valid) ->
+    (logits (B, vocab), pages[, held])``; ``pages`` is donated (argument
+    0).  ``held``, there exactly when the model has expert layers, counts
+    the step's (live token, expert) choices that land on held experts,
+    over all expert layers.
+
+    ``params`` must be the full (un-sharded) tree — the engine replicates
+    weights over the model axis by design (see module docstring).
+    ``attn_impl``: "kernel" scores the live pages in place with the page
+    kind's Pallas kernel, "ref" gathers them dense and scores them with
+    the jnp oracle (same math and identical collective footprint; the
+    dry-run uses "ref" to keep compile times sane).
+    """
+    if attn_impl not in ("kernel", "ref"):
+        raise ValueError(f"attn_impl must be kernel|ref, got {attn_impl!r}")
+    cfg = model.cfg
+    ctx = make_ctx(mesh)
+    r_mesh = mesh.shape.get("model", 1)
+    if r_mesh != plan.model_parallel:
+        raise ValueError(
+            f"plan was laid out for model_parallel={plan.model_parallel} "
+            f"but the mesh model axis is {r_mesh}; re-plan with this mesh")
+    interpret = default_interpret() if interpret is None else interpret
+    kind = _PAGE_KINDS[plan.kind](cfg, mesh, plan, ctx, interpret)
+    if attn_impl == "kernel" and not interpret:
+        kind.check_tiling()
+    read = kind.kernel if attn_impl == "kernel" else kind.oracle
+    cdt = jnp.dtype(cfg.dtype)
+    has_experts = any(cfg.layer_kind(i)["mlp"] == "moe"
+                      for i in range(cfg.num_layers))
 
     def fn(pages, params, table, token, slot_len, slot_valid):
         with jax.named_scope("embed"):
@@ -505,50 +497,48 @@ def _build_latent_step(model, mesh: Mesh, plan: KVArenaPlan, ctx,
         posb = slot_len[:, None]                       # per-slot position
         held = jnp.zeros((), jnp.int32)
         for i, bp in enumerate(params["blocks"]):
-            pa = bp["mla"]
-            with jax.named_scope("mla_q"):
-                h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
-                q_nope, q_pe = mla_mod.query(pa, h, a, posb, cdt)
-                q_c = mla_mod.absorb_query(pa, q_nope[:, :, 0], a, cdt)
-                c1, k1 = mla_mod.latent(pa, h, a, posb, cfg.norm_eps, cdt)
+            q, entry = kind.mix_in(bp, x, posb)
             with jax.named_scope("kv_write"):
-                pages = _write_token_latent(pages, plan, i, table, slot_len,
-                                            slot_valid, c1[:, 0], k1[:, 0])
-            o_c = attend(q_c, q_pe[:, :, 0], pages, i, table, slot_len,
-                         slot_valid)
-            with jax.named_scope("mla_out"):
-                y = mla_mod.latent_out(pa, o_c, a, cdt)
+                pages = kind.write(pages, plan, i, table, slot_len,
+                                   slot_valid, *entry)
+            x = kind.mix_out(bp, x, read(q, pages, i, table, slot_len,
+                                         slot_valid))
+            if cfg.layer_kind(i)["mlp"] == "moe":
+                with jax.named_scope("moe_router"):
+                    h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)[:, 0]
+                y, held = moe_mod.held_moe(bp["moe"], h2, cfg.moe, cfg.act,
+                                           cdt, slot_valid, held)
                 x = x + y[:, None].astype(x.dtype)
-            if "mlp" in bp:
+            elif "mlp" in bp:
                 with jax.named_scope("mlp"):
                     h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)
-                    y = glu_mlp(bp["mlp"], h2, cfg.act, cdt)
+                    y = glu_mlp(bp["mlp"], h2, cfg.act, cdt, ctx, cfg.d_ff)
                     x = x + y.astype(x.dtype)
-                continue
-            pm = bp["moe"]
-            with jax.named_scope("moe_router"):
-                h2 = rmsnorm(bp["ln2"], x, cfg.norm_eps)[:, 0]
-                w, ids, _ = moe_mod.route(pm, h2, moe, cdt)
-                gate = moe_mod.held_gate(w, ids, moe)
-                mine = (ids >= moe.first_expert) & (
-                    ids < moe.first_expert + moe_mod.held_count(moe))
-                held = held + jnp.sum(mine & slot_valid[:, None],
-                                      dtype=jnp.int32)
-            with jax.named_scope("moe_experts"):
-                y = moe_mod.held_experts(pm, h2, gate, cfg.act, cdt)
-            if "shared" in pm:
-                with jax.named_scope("moe_shared"):
-                    y = y + glu_mlp(pm["shared"], h2, cfg.act, cdt)
-            x = x + y[:, None].astype(x.dtype)
         with jax.named_scope("lm_head"):
             x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
             if cfg.tie_embeddings:
                 logits = unembed(params["embed"], x, cdt)
             else:
                 logits = dense(params["lm_head"], x, cdt)
-            return logits[:, 0], pages, held
+            return (logits[:, 0], pages) + ((held,) if has_experts else ())
 
-    return _jit_step(fn, model, mesh, plan, donate, extra_out=(P(),))
+    state_abs = {
+        "pages": jax.ShapeDtypeStruct((plan.total_elems,), plan.layout.dtype),
+        "page_table": jax.ShapeDtypeStruct(
+            (plan.max_seqs, plan.max_blocks, plan.n_layers), jnp.int32),
+        "slot_len": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.int32),
+        "slot_valid": jax.ShapeDtypeStruct((plan.max_seqs,), jnp.bool_),
+    }
+    sspecs = shard_rules.decode_state_specs(state_abs, cfg, mesh,
+                                            plan.max_seqs)
+    pspecs = jax.tree.map(lambda _: P(), model.abstract_params())
+    sharded = jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=(sspecs["pages"], pspecs, sspecs["page_table"], P(),
+                  sspecs["slot_len"], sspecs["slot_valid"]),
+        out_specs=(P(), sspecs["pages"]) + ((P(),) if has_experts else ()),
+        check_vma=False)
+    return jax.jit(sharded, donate_argnums=(0,)), pspecs, sspecs
 
 
 # ---------------------------------------------------------------------------
@@ -572,13 +562,13 @@ class PagedDecodeEngine:
 
     def __init__(self, model, mesh: Mesh, plan: KVArenaPlan, *,
                  attn_impl: str = "kernel", interpret: bool | None = None,
-                 donate: bool = True, obs=None):
+                 obs=None):
         self.model, self.mesh, self.plan = model, mesh, plan
-        self._routing = []    # latent steps' expert choices, not yet counted
+        self._routing = []    # steps' expert choices, not yet counted
         self.obs = obs
         self.step, self.param_specs, self.state_specs = \
             build_paged_decode_step(model, mesh, plan, attn_impl=attn_impl,
-                                    interpret=interpret, donate=donate)
+                                    interpret=interpret)
         self.allocator = KVPageAllocator(plan.n_kv_pages)
         self.table = PageTable(plan.max_seqs, plan.max_blocks, plan.n_layers)
         self.slot_len = np.zeros((plan.max_seqs,), np.int32)
@@ -609,9 +599,7 @@ class PagedDecodeEngine:
 
     def pages_for(self, n_tokens: int) -> int:
         """Worst-case pages a sequence of ``n_tokens`` needs (all layers)."""
-        import math as _m
-
-        return _m.ceil(n_tokens / self.plan.page_tokens) * self.plan.n_layers
+        return math.ceil(n_tokens / self.plan.page_tokens) * self.plan.n_layers
 
     def can_admit(self, n_tokens: int) -> bool:
         return (bool(self.free_slots())
@@ -720,7 +708,7 @@ class PagedDecodeEngine:
             with obs.span("serve.dispatch"), self.mesh:
                 out = self.step(*args)
             logits, self.pages = out[0], out[1]
-            if len(out) > 2 and obs.enabled and self.model.cfg.moe:
+            if len(out) > 2 and obs.enabled:
                 self._routing.append((int(self.slot_valid.sum()), out[2]))
             self.slot_len[self.slot_valid] += 1
         self.steps += 1

@@ -348,7 +348,7 @@ def test_kernel_step_refuses_pages_the_chip_cannot_tile():
 # ---------------------------------------------------------------------------
 
 
-def _engine(attn_impl="ref", dtype="float32", **plan_kw):
+def _engine(attn_impl="ref", dtype="float32", cfg=None, **plan_kw):
     import jax
     from jax.sharding import AxisType
     from repro.configs import reduced_config
@@ -357,7 +357,8 @@ def _engine(attn_impl="ref", dtype="float32", **plan_kw):
 
     mesh = jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
-    model = build_model(reduced_config("llama3.2-1b").with_(dtype=dtype))
+    cfg = cfg or reduced_config("llama3.2-1b")
+    model = build_model(cfg.with_(dtype=dtype))
     params = model.init(jax.random.PRNGKey(0))
     plan_kw.setdefault("page_tokens", 8)
     plan_kw.setdefault("page_bytes", 4096)
@@ -369,13 +370,32 @@ def _engine(attn_impl="ref", dtype="float32", **plan_kw):
     return model, params, eng
 
 
-@pytest.mark.parametrize("attn_impl", ["ref", "kernel"])
-def test_paged_matches_contiguous_decode(rng, attn_impl):
+def _moe_on_kv_pages():
+    """Reduced Mixtral (an expert FFN in every layer) with its sliding
+    window lifted, so that its K/V rows page: the dense mixer with the
+    held-expert layer (all experts held)."""
+    import dataclasses
+
+    from repro.configs import reduced_config
+
+    cfg = reduced_config("mixtral-8x7b")
+    return cfg.with_(attn=dataclasses.replace(cfg.attn, window=None))
+
+
+@pytest.mark.parametrize("attn_impl, moe", [
+    pytest.param("ref", False, id="ref"),
+    pytest.param("kernel", False, id="kernel"),
+    pytest.param("ref", True, id="moe-ref"),
+    pytest.param("kernel", True, id="moe-kernel")])
+def test_paged_matches_contiguous_decode(rng, attn_impl, moe):
     """The tentpole numeric claim: paged flash-decode == the model's own
-    contiguous decode_step, token for token, across page boundaries."""
+    contiguous decode_step, token for token, across page boundaries.  With
+    expert layers the contiguous step dispatches by capacity, which at one
+    token a row drops nothing: the same sum as the held-expert form."""
     import jax.numpy as jnp
 
-    model, params, eng = _engine(attn_impl=attn_impl)
+    model, params, eng = _engine(attn_impl=attn_impl,
+                                 cfg=_moe_on_kv_pages() if moe else None)
     b, steps = eng.plan.max_seqs, 10           # crosses the 8-token page
     state = model.init_decode_state(b, 32)
     for s in range(b):
